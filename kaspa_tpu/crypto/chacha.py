@@ -11,9 +11,9 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 
+from kaspa_tpu.utils import nativebuild
 from kaspa_tpu.utils.sync import ranked_lock
 
 import numpy as np
@@ -21,7 +21,6 @@ import numpy as np
 _CONSTANTS = np.array([0x61707865, 0x3320646E, 0x79622D32, 0x6B206574], dtype=np.uint32)
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "..", "native", "hostcrypto", "hostcrypto.cc")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "..", "..", "native", "hostcrypto", "libhostcrypto.so")
 _LOCK = ranked_lock("chacha.build")
 _LIB = None
 _LIB_FAILED = False
@@ -36,17 +35,7 @@ def _native_lib():
         if _LIB is not None or _LIB_FAILED:
             return _LIB
         try:
-            if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC):
-                # atomic temp+rename so concurrent processes never load a
-                # half-written .so
-                tmp = _LIB_PATH + f".tmp{os.getpid()}"
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-                os.replace(tmp, _LIB_PATH)
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(nativebuild.build(_SRC, "hostcrypto", opt="-O3"))
             lib.chacha20_keystream_batch.argtypes = [
                 ctypes.c_char_p,
                 ctypes.c_uint64,

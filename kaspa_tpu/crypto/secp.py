@@ -44,7 +44,23 @@ _COLD_SPLITS = REGISTRY.counter_family(
     "secp_cold_bucket_splits", "kernel",
     help="batches split into warm-bucket sub-dispatches to dodge a cold jit compile",
 )
+# (kernel name, padded bucket, mesh width) shapes compiled in this process.
+# The mesh width is part of the key: mesh > 1 dispatches a different
+# (shard_map-wrapped) program per width, so a bucket that is warm at mesh 1
+# still pays a full compile — and needs the compile-tier watchdog deadline —
+# the first time it is dispatched under another width.
 _seen_shapes: set = set()
+
+
+def _shape_key(kernel_name: str, bucket: int) -> tuple:
+    from kaspa_tpu.ops import mesh
+
+    return (kernel_name, bucket, mesh.active_size())
+
+
+def _largest_warm_below(kernel_name: str, bucket: int) -> int | None:
+    width = _shape_key(kernel_name, bucket)[-1]
+    return max((bk for k, bk, w in _seen_shapes if k == kernel_name and w == width and bk < bucket), default=None)
 
 # thread-local escape hatch: pretrace_bucket() deliberately compiles a
 # cold bucket, so it must bypass the warm-bucket split
@@ -56,11 +72,10 @@ def _cold_split_enabled() -> bool:
     compiled is split into sub-dispatches at the largest already-warm
     bucket instead of paying the compile wall inline.  The verify-kernel
     jit cost grows superlinearly with batch width on the XLA formulation
-    (the wedge dossiers' recurring probe stall; ~3 min for bucket 16 on
-    CPU), so crossing into a cold bucket mid-pipeline can stall the
-    commit lock for minutes.  `KASPA_TPU_COLD_BUCKET_SPLIT=0` restores
-    pad-up-and-compile — bench sweeps that deliberately measure specific
-    bucket shapes need that."""
+    (~3 min for bucket 16 on CPU), so crossing into a cold bucket
+    mid-pipeline can stall the commit lock for minutes.
+    `KASPA_TPU_COLD_BUCKET_SPLIT=0` restores pad-up-and-compile — bench
+    sweeps that deliberately measure specific bucket shapes need that."""
     return os.environ.get("KASPA_TPU_COLD_BUCKET_SPLIT", "1") not in ("0", "off", "false")
 
 # aggregate-lane telemetry: one RLC multi-scalar check replaces a whole
@@ -88,6 +103,9 @@ _DEGRADED_DISPATCHES = REGISTRY.counter(
     "secp_degraded_dispatches", help="batches routed to the host degraded lane (breaker open / dispatch failed)"
 )
 _DEGRADED_JOBS = REGISTRY.counter("secp_degraded_jobs", help="verify jobs executed on the host degraded lane")
+# the other side of the same ledger: every job handed to a guarded dispatch
+# ends in exactly one of secp_device_jobs / secp_degraded_jobs
+_DEVICE_JOBS = REGISTRY.counter("secp_device_jobs", help="verify jobs answered by the device lane")
 
 W = bi.FP.W
 _CHALLENGE_MID = hashlib.sha256(
@@ -158,13 +176,10 @@ class _Batch:
         if n == 0:
             return np.zeros(0, dtype=bool)
         b = _bucket(n)
-        shape_key = (kernel.__name__, b)
+        shape_key = _shape_key(kernel.__name__, b)
         new_shape = shape_key not in _seen_shapes
         if new_shape and _cold_split_enabled() and not getattr(_force_tls, "on", False):
-            warm = max(
-                (bk for k, bk in _seen_shapes if k == kernel.__name__ and bk < b),
-                default=None,
-            )
+            warm = _largest_warm_below(kernel.__name__, b)
             if warm is not None:
                 _COLD_SPLITS.inc(kernel.__name__)
                 return self._run_split(kernel, warm)
@@ -231,7 +246,7 @@ class _Batch:
 def _dispatch_tier(kernel, n: int) -> str:
     """Watchdog tier: a never-seen (kernel, bucket) shape legitimately
     pays an XLA compile, so it gets the long deadline."""
-    return "dispatch" if (kernel.__name__, _bucket(n)) in _seen_shapes else "compile"
+    return "dispatch" if _shape_key(kernel.__name__, _bucket(n)) in _seen_shapes else "compile"
 
 
 def _run_guarded(batch: _Batch, kernel, items: list, host_verify) -> np.ndarray:
@@ -264,6 +279,7 @@ def _run_guarded(batch: _Batch, kernel, items: list, host_verify) -> np.ndarray:
             br.record_failure()
         else:
             br.record_success()
+            _DEVICE_JOBS.inc(n)
             return mask
     return _host_lane(batch, kernel.__name__, items, host_verify)
 
@@ -431,7 +447,7 @@ def _run_aggregate_shape(b: int, args) -> bool:
     on the first sight of a bucket, shape discarded if the compile dies."""
     from kaspa_tpu.ops.secp256k1 import aggregate as agg
 
-    shape_key = (_AGG_KERNEL_NAME, b)
+    shape_key = _shape_key(_AGG_KERNEL_NAME, b)
     new_shape = shape_key not in _seen_shapes
     if new_shape:
         _seen_shapes.add(shape_key)
@@ -463,7 +479,7 @@ def _aggregate_device_check(prep: _AggBatch, weights: list, rows: list, idxs: li
     br = device_breaker()
     if not br.allow():
         return None
-    tier = "dispatch" if (_AGG_KERNEL_NAME, b) in _seen_shapes else "compile"
+    tier = "dispatch" if _shape_key(_AGG_KERNEL_NAME, b) in _seen_shapes else "compile"
     try:
         with trace.span("secp.device_dispatch", kernel=_AGG_KERNEL_NAME, batch=n, bucket=b):
             ok = supervisor.run_supervised(
@@ -502,14 +518,11 @@ def _resolve_aggregate(prep, weights, rows, idxs, mask, items) -> None:
     # sound sub-aggregate), exactly like _Batch.run's cold-split
     b = _bucket(len(idxs))
     if (
-        (_AGG_KERNEL_NAME, b) not in _seen_shapes
+        _shape_key(_AGG_KERNEL_NAME, b) not in _seen_shapes
         and _cold_split_enabled()
         and not getattr(_force_tls, "on", False)
     ):
-        warm = max(
-            (bk for k, bk in _seen_shapes if k == _AGG_KERNEL_NAME and bk < b),
-            default=None,
-        )
+        warm = _largest_warm_below(_AGG_KERNEL_NAME, b)
         if warm is not None and warm < len(idxs):
             _COLD_SPLITS.inc(_AGG_KERNEL_NAME)
             for off in range(0, len(idxs), warm):
@@ -519,6 +532,7 @@ def _resolve_aggregate(prep, weights, rows, idxs, mask, items) -> None:
             return
     ok = _aggregate_device_check(prep, weights, rows, idxs)
     if ok is True:
+        _DEVICE_JOBS.inc(len(idxs))
         for i in idxs:
             mask[i] = True
         return
@@ -665,7 +679,7 @@ def _pretrace_aggregate_bucket(bucket: int) -> str:
     """Aggregate-family pretrace: compile the multi-scalar partials +
     finish kernels at one bucket shape with an all-zero (identity-summing)
     batch, under the compile-tier watchdog."""
-    if (_AGG_KERNEL_NAME, bucket) in _seen_shapes:
+    if _shape_key(_AGG_KERNEL_NAME, bucket) in _seen_shapes:
         return "warm"
     zeros32 = [_ZERO32] * bucket
     args = (
@@ -717,7 +731,7 @@ def pretrace_bucket(kernel_name: str, bucket: int) -> str:
     kernel = _PRETRACE_KERNELS.get(kernel_name)
     if kernel is None or bucket < 8:
         return f"error:unknown {kernel_name}/{bucket}"
-    if (kernel_name, bucket) in _seen_shapes:
+    if _shape_key(kernel_name, bucket) in _seen_shapes:
         return "warm"
     batch = _Batch()
     for _ in range(bucket):

@@ -158,12 +158,6 @@ def parse_args(argv=None) -> DaemonArgs:
         "to <appdir>/flight-*.json on demand, crash, or breaker-open "
         "(tools/trace_report.py --perfetto renders the dump)",
     )
-    p.add_argument(
-        "--bench-capture", action=argparse.BooleanOptionalAction, default=False,
-        help="re-probe the device on the periodic tick and capture a fresh "
-        "bench.py number the moment a trivial jit answers "
-        "(interval via KASPA_TPU_BENCH_RECHECK_S; results in <appdir>/BENCH_CAPTURE.json)",
-    )
     # consensus-parameter overrides (kaspad exposes these for testnets;
     # primarily for pruning/IBD integration tests at small scale)
     p.add_argument("--override-pruning-depth", type=int, default=None)
@@ -596,16 +590,6 @@ class Daemon:
             self.prom_text = prom.render()
 
         self.tick.register(10.0, sample_metrics)
-
-        # recurring-timer bench capture (ROADMAP item 1): re-probe the
-        # device on the metrics cadence, run the full bench the moment a
-        # trivial jit answers, keep the best number in the appdir
-        self.bench_capture = None
-        if getattr(args, "bench_capture", False):
-            from kaspa_tpu.node.bench_capture import BenchCapture
-
-            self.bench_capture = BenchCapture(args.appdir, logger=self.log)
-            self.tick.register(10.0, self.bench_capture.tick)
 
         def sample_rule_engine():
             with self._dispatch_lock:

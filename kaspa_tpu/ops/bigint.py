@@ -379,6 +379,18 @@ def is_odd(ctx: FieldCtx, x):
     return (canon(ctx, x)[..., 0] & 1) == 1
 
 
+def like_varying(c, like):
+    """Give the constant ``c`` the varying-manual-axes type of ``like``.
+
+    Under ``jax.shard_map`` a loop carry must enter with the type it leaves
+    with: a broadcast constant is replicated while anything computed from a
+    sharded operand is varying, so a constant that seeds a scan/fori carry
+    is cast here.  Outside shard_map ``like`` varies over nothing and ``c``
+    comes back untouched — the traced kernel is the same either way."""
+    vma = getattr(jax.typeof(like), "vma", None)
+    return jax.lax.pcast(c, tuple(vma), to="varying") if vma else c
+
+
 def exp_const(ctx: FieldCtx, x, e: int):
     """x**e mod m for a *static* python-int exponent (square-and-multiply).
 
@@ -387,7 +399,7 @@ def exp_const(ctx: FieldCtx, x, e: int):
     nbits = e.bit_length()
     bits = np.array([(e >> (nbits - 1 - i)) & 1 for i in range(nbits)], dtype=np.int32)
     bits_d = jnp.asarray(bits)
-    one = jnp.broadcast_to(jnp.asarray(ctx.one), x.shape).astype(jnp.int32)
+    one = like_varying(jnp.broadcast_to(jnp.asarray(ctx.one), x.shape).astype(jnp.int32), x)
 
     def body(i, acc):
         acc = sqr(ctx, acc)

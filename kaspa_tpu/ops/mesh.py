@@ -84,7 +84,7 @@ _SLICE_JOBS = REGISTRY.counter_family(
 
 _lock = ranked_lock("mesh.config")
 _configured: str | int | None = None  # raw spec, resolved lazily
-_active: int | None = None  # resolved mesh size (clamped to visible devices)
+_active: int | None = None  # resolved mesh size
 _grid: tuple[int, int] | None = None  # (slices, shards-per-slice) for "RxC" specs
 _slice_tls = threading.local()  # slice_lane() pin: route dispatches to one slice
 
@@ -107,15 +107,17 @@ def configure(spec: int | str | None) -> int:
 
     ``spec``: an int, a decimal string, ``"auto"`` (every visible device),
     an ``"RxC"`` grid (R slices of C devices — the 2-D hybrid mesh), or
-    None (fall back to the KASPA_TPU_MESH env var, default 1).  Sizes
-    above the visible device count clamp; <= 1 disables mesh dispatch.
+    None (fall back to the KASPA_TPU_MESH env var, default 1).  An
+    explicit size above the visible device count raises ValueError (only
+    ``auto`` adapts to what is visible) and leaves the previous
+    configuration in place; <= 1 disables mesh dispatch.
     """
     global _configured, _active, _grid
+    raw = spec if spec is not None else os.environ.get("KASPA_TPU_MESH", 1)
+    active, grid_ = _resolve(raw)
     with _lock:
-        _configured = spec if spec is not None else os.environ.get("KASPA_TPU_MESH", 1)
-        _active = None  # re-resolve on next use
-        _grid = None
-    return active_size()
+        _configured, _active, _grid = raw, active, grid_
+    return active
 
 
 def active_size() -> int:
@@ -156,11 +158,9 @@ def _resolve(spec: int | str) -> tuple[int, int | None]:
         spec = spec.strip().lower()
         if "x" in spec:
             r_s, _, c_s = spec.partition("x")
-            r, c = int(r_s or 1), int(c_s or 1)
-            # clamp the grid to the visible devices, preferring to keep the
-            # slice count (the fabric's unit of failover) over slice width
-            r = max(1, min(r, ndev))
-            c = max(1, min(c, ndev // r))
+            r, c = max(1, int(r_s or 1)), max(1, int(c_s or 1))
+            if r * c > ndev:
+                raise ValueError(f"mesh {r}x{c} needs {r * c} devices, {ndev} visible")
             if r <= 1:
                 return (c if c > 1 else 1), None
             return r * c, (r, c)
@@ -172,7 +172,11 @@ def _resolve(spec: int | str) -> tuple[int, int | None]:
         n = int(spec)
     if n <= 1:
         return 1, None
-    return min(n, ndev), None
+    if n > ndev:
+        # never clamp: a four-chip deployment that silently runs on one
+        # chip measures (and serves) something else than was asked for
+        raise ValueError(f"mesh {n} needs {n} devices, {ndev} visible")
+    return n, None
 
 
 @contextlib.contextmanager
@@ -299,7 +303,9 @@ def match_partition_rules(rules, tree: dict) -> dict:
 def constrain(x, name: str):
     """`with_sharding_constraint` under the registry's spec for ``name`` —
     a no-op on CPU or when no 2-D grid is configured (the SNIPPETS [3]
-    CPU-fallback contract), so call sites never need backend guards."""
+    CPU-fallback contract), so call sites never need backend guards.
+    Inside ``shard_map`` the mesh axes are manual and jax refuses the
+    constraint with ValueError: the value is already laid out, identity."""
     g = grid()
     if g is None:
         return x
@@ -307,14 +313,23 @@ def constrain(x, name: str):
 
     if jax.default_backend() == "cpu":
         return x
-    try:
-        from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding
 
+    try:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(_mesh2d(*g), partition_spec_for(name))
         )
-    except Exception:  # noqa: BLE001 - outside jit / mesh ctx: identity
+    except ValueError:  # manual (shard_map) axes: nothing to constrain
         return x
+
+
+def _sharded_jit(fn, mesh, in_specs, out_specs):
+    """The one place a kernel meets the mesh: ``jax.jit(jax.shard_map(...))``
+    with the varying-axes check left on, so a kernel whose loop carries do
+    not type under the mesh fails at trace time instead of miscompiling."""
+    import jax
+
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
 
 def _pad_rows(arr: np.ndarray, m: int) -> np.ndarray:
@@ -355,13 +370,9 @@ def _verify_entry(kind: str, n: int):
     """Cached shard_map-jitted verify kernel for one (kind, mesh size);
     in/out specs come from the partition-rule registry projected onto the
     1-D ("shard",) axis."""
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _VERIFY_ARG_NAMES)
     out_specs = partition_spec_for("mask", flat=True)
-    fn = shard_map(_verify_kernel(kind), mesh=_mesh(n), in_specs=in_specs, out_specs=out_specs)
-    return jax.jit(fn)
+    return _sharded_jit(_verify_kernel(kind), _mesh(n), in_specs, out_specs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -369,9 +380,6 @@ def _verify_entry_2d(kind: str, r: int, c: int):
     """Full-grid entry: batch axis sharded over ("slice", "shard") — the
     same per-device local shapes (and thus the same trace cost and
     bit-identical masks) as the 1-D entry of size r*c."""
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm) for nm in _VERIFY_ARG_NAMES)
     out_specs = partition_spec_for("mask")
 
@@ -380,23 +388,16 @@ def _verify_entry_2d(kind: str, r: int, c: int):
     def wrapped(*args):
         return constrain(kernel(*args), "mask")
 
-    fn = shard_map(wrapped, mesh=_mesh2d(r, c), in_specs=in_specs, out_specs=out_specs)
-    return jax.jit(fn)
+    return _sharded_jit(wrapped, _mesh2d(r, c), in_specs, out_specs)
 
 
 @functools.lru_cache(maxsize=None)
 def _verify_entry_slice(kind: str, r: int, c: int, idx: int):
     """Slice-pinned entry: the 1-D kernel over slice ``idx``'s devices, so
     concurrent fabric slice workers occupy disjoint hardware."""
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _VERIFY_ARG_NAMES)
     out_specs = partition_spec_for("mask", flat=True)
-    fn = shard_map(
-        _verify_kernel(kind), mesh=_slice_mesh(r, c, idx), in_specs=in_specs, out_specs=out_specs
-    )
-    return jax.jit(fn)
+    return _sharded_jit(_verify_kernel(kind), _slice_mesh(r, c, idx), in_specs, out_specs)
 
 
 def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np.ndarray:
@@ -468,44 +469,23 @@ def _agg_local_kernel():
 
 @functools.lru_cache(maxsize=None)
 def _agg_entry(n: int):
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _AGG_ARG_NAMES)
     out_spec = partition_spec_for("partials", flat=True)
-    fn = shard_map(
-        _agg_local_kernel(), mesh=_mesh(n), in_specs=in_specs,
-        out_specs=(out_spec, out_spec, out_spec),
-    )
-    return jax.jit(fn)
+    return _sharded_jit(_agg_local_kernel(), _mesh(n), in_specs, (out_spec,) * 3)
 
 
 @functools.lru_cache(maxsize=None)
 def _agg_entry_2d(r: int, c: int):
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm) for nm in _AGG_ARG_NAMES)
     out_spec = partition_spec_for("partials")
-    fn = shard_map(
-        _agg_local_kernel(), mesh=_mesh2d(r, c), in_specs=in_specs,
-        out_specs=(out_spec, out_spec, out_spec),
-    )
-    return jax.jit(fn)
+    return _sharded_jit(_agg_local_kernel(), _mesh2d(r, c), in_specs, (out_spec,) * 3)
 
 
 @functools.lru_cache(maxsize=None)
 def _agg_entry_slice(r: int, c: int, idx: int):
-    import jax
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _AGG_ARG_NAMES)
     out_spec = partition_spec_for("partials", flat=True)
-    fn = shard_map(
-        _agg_local_kernel(), mesh=_slice_mesh(r, c, idx), in_specs=in_specs,
-        out_specs=(out_spec, out_spec, out_spec),
-    )
-    return jax.jit(fn)
+    return _sharded_jit(_agg_local_kernel(), _slice_mesh(r, c, idx), in_specs, (out_spec,) * 3)
 
 
 def dispatch_aggregate_partials(pxn, pyn, rxn, ryn, c_digits, a_digits):
@@ -551,14 +531,9 @@ def dispatch_aggregate_partials(pxn, pyn, rxn, ryn, c_digits, a_digits):
 # --- muhash tree product ---------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
-def _tree_entry(n: int, levels: int):
-    """Cached shard_map-jitted local tree product: each shard reduces its
-    [bucket, 192] slice to one canonical U3072 element ([1, 192])."""
-    import jax
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+def _local_tree(levels: int):
+    """Per-shard tree product: a [2**levels, 192] slice -> one canonical
+    U3072 element ([1, 192])."""
     from kaspa_tpu.ops import bigint as bi
 
     F = bi.F3072
@@ -569,8 +544,16 @@ def _tree_entry(n: int, levels: int):
             x = bi.mul(F, x[:half], x[half:])
         return bi.canon(F, x[0])[None, :]
 
-    fn = shard_map(local_tree, mesh=_mesh(n), in_specs=P("shard", None), out_specs=P("shard", None))
-    return jax.jit(fn)
+    return local_tree
+
+
+@functools.lru_cache(maxsize=None)
+def _tree_entry(n: int, levels: int):
+    """Cached shard_map-jitted local tree product: each shard reduces its
+    [bucket, 192] slice to one canonical U3072 element ([1, 192])."""
+    from jax.sharding import PartitionSpec as P
+
+    return _sharded_jit(_local_tree(levels), _mesh(n), P("shard", None), P("shard", None))
 
 
 def dispatch_tree_product(elements: np.ndarray) -> int:
@@ -580,7 +563,7 @@ def dispatch_tree_product(elements: np.ndarray) -> int:
     partial product combines on host with one 3072-bit multiply.
     """
     from kaspa_tpu.ops import bigint as bi
-    from kaspa_tpu.ops.muhash_ops import BUCKETS
+    from kaspa_tpu.ops.muhash_ops import BUCKETS, DEVICE_DISPATCHES
 
     F = bi.F3072
     n = active_size()
@@ -603,6 +586,7 @@ def dispatch_tree_product(elements: np.ndarray) -> int:
         padded = np.tile(np.asarray(F.one, dtype=np.int32), (bucket * n, 1))
         padded[: chunk.shape[0]] = chunk
         partials = np.asarray(_tree_entry(n, bucket.bit_length() - 1)(padded))
+        DEVICE_DISPATCHES.inc(str(bucket))
         for row in partials:
             result = result * bi.limbs_to_int(row) % F.modulus
         _observe("muhash", take, bucket * n, n)

@@ -19,9 +19,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kaspa_tpu.observability import trace
+from kaspa_tpu.observability.core import REGISTRY
 from kaspa_tpu.ops import bigint as bi
 
 F = bi.F3072
+
+DEVICE_DISPATCHES = REGISTRY.counter_family(
+    "muhash_device_dispatches", "bucket",
+    help="tree-product dispatches answered by the device, by per-device bucket",
+)
 
 
 # Fixed batch buckets: one jit compile per bucket size (the 3072-bit mul
@@ -65,9 +72,11 @@ def batch_product_device(elements: np.ndarray) -> int:
         levels = bucket.bit_length() - 1
         padded = np.tile(np.asarray(F.one, dtype=np.int32), (bucket, 1))
         padded[: chunk.shape[0]] = chunk
-        out = _tree_product(jnp.asarray(padded), levels)
+        with trace.span("muhash.device_dispatch", bucket=bucket, elements=chunk.shape[0]):
+            out = np.asarray(_tree_product(jnp.asarray(padded), levels))
+        DEVICE_DISPATCHES.inc(str(bucket))
         _note_bucket(bucket)
-        result = result * bi.limbs_to_int(np.asarray(out)) % F.modulus
+        result = result * bi.limbs_to_int(out) % F.modulus
         pos += chunk.shape[0]
     return result
 

@@ -112,7 +112,7 @@ def _scan_reduce_lanes(p):
         y = jnp.concatenate([y, ident[1]], axis=0)
         z = jnp.concatenate([z, ident[2]], axis=0)
     xs = tuple(a.reshape(-1, g, *a.shape[1:]) for a in (x, y, z))
-    acc = pt.point_identity((g,) + x.shape[1:-1])
+    acc = pt.point_identity((g,) + x.shape[1:-1], like=x)
 
     def step(acc, lanes):
         return pt.point_add(acc, lanes), None

@@ -32,9 +32,13 @@ WINDOW = 4
 N_WINDOWS = 256 // WINDOW  # 64 windows of 4 bits, MSB-first
 
 
-def point_identity(shape_prefix):
+def point_identity(shape_prefix, like=None):
+    """(0 : 1 : 0) broadcast to ``shape_prefix``; ``like`` is the operand a
+    loop carry seeded with it will be combined with (bigint.like_varying)."""
     zero = jnp.zeros((*shape_prefix, FP.W), dtype=jnp.int32)
     one = jnp.broadcast_to(jnp.asarray(FP.one), zero.shape).astype(jnp.int32)
+    if like is not None:
+        zero, one = bi.like_varying(zero, like), bi.like_varying(one, like)
     return (zero, one, zero)
 
 
@@ -130,7 +134,7 @@ def _build_p_table(px, py):
     superlinearly with the op count (tens of seconds per bucket), while
     the rolled form traces one add and compiles flat.  Identical math,
     identical limbs out."""
-    one = jnp.broadcast_to(jnp.asarray(FP.one), px.shape).astype(jnp.int32)
+    one = bi.like_varying(jnp.broadcast_to(jnp.asarray(FP.one), px.shape).astype(jnp.int32), px)
     p1 = (px, py, one)
     ident = point_identity(px.shape[:-1])
 
@@ -165,7 +169,7 @@ def dual_scalar_mul_base(px, py, g_digits, p_digits):
     gtx = jnp.asarray(_GTAB_X)
     gty = jnp.asarray(_GTAB_Y)
 
-    r0 = point_identity(px.shape[:-1])
+    r0 = point_identity(px.shape[:-1], like=px)
 
     def body(w, r):
         for _ in range(WINDOW):

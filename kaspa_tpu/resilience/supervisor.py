@@ -1,8 +1,7 @@
 """Device-runtime supervision: the hang-proof verify plane.
 
 The breaker (`resilience/breaker.py`) counts *errors*; it is blind to
-*hangs* — and every bench round to date (BENCH_r01+) wedged exactly that
-way: a jit compile or device call that never returns, pinning whichever
+*hangs*: a jit compile or device call that never returns, pinning whichever
 thread dispatched it (the CoalescingDispatcher thread in production).
 This module closes that hole with three cooperating pieces:
 
@@ -31,8 +30,7 @@ commit lock, so a restart after a wedge comes back warm.  Honesty note,
 measured on this repo's kernels: the XLA disk cache removes the *compile*
 but not the *trace/lower* wall, and on the CPU backend executable
 deserialization costs about as much as compiling — so ``auto`` pretraces
-only on non-CPU backends, and the bench wedge dossier records measured
-warm-start seconds rather than assuming the cache is free.
+only on non-CPU backends.
 """
 
 from __future__ import annotations
@@ -349,8 +347,7 @@ def _read_manifest(path: str) -> list[dict]:
 def note_shape(kernel_name: str, bucket: int, family: str = "ladder") -> None:
     """Record a freshly compiled (kernel, bucket) shape in the manifest,
     keyed by the current mesh/backend/jax version plus the kernel family
-    ("ladder" | "aggregate" — so a pretrace warms the right kernels and a
-    wedge dossier names which family hung).  Write-through on new shapes
+    ("ladder" | "aggregate" — so a pretrace warms the right kernels).  Write-through on new shapes
     only (rare); never allowed to fail a dispatch."""
     try:
         path = manifest_path()
@@ -386,7 +383,7 @@ def load_warm_entries() -> list[dict]:
 def pretrace_warm(budget_s: float | None = None) -> list[dict]:
     """Pre-trace every matching manifest shape (smallest buckets first so
     a budget cut keeps the most common shapes warm).  Returns per-shape
-    timing — the measured warm-start jit cost the wedge dossier records."""
+    timing — the measured warm-start jit cost."""
     from kaspa_tpu.crypto import secp  # deferred: secp imports this module
 
     out: list[dict] = []

@@ -261,7 +261,7 @@ def _compile_stall_drill(seed: int, stall_delay_s: float, compile_deadline_s: fl
     from kaspa_tpu.crypto import eclib, secp
 
     bucket = 8
-    while ("schnorr_verify", bucket) in secp._seen_shapes:
+    while secp._shape_key("schnorr_verify", bucket) in secp._seen_shapes:
         bucket <<= 1
     count = bucket // 2 + 1  # pads to exactly `bucket`
     seckey = (seed * 2 + 1) % eclib.N or 1
@@ -288,7 +288,7 @@ def _compile_stall_drill(seed: int, stall_delay_s: float, compile_deadline_s: fl
     # (after stall_delay_s, well past our deadline) — wait for it so the
     # cold-shape assertion doesn't race the cleanup
     deadline = time.monotonic() + stall_delay_s + 5.0
-    while ("schnorr_verify", bucket) in secp._seen_shapes and time.monotonic() < deadline:
+    while secp._shape_key("schnorr_verify", bucket) in secp._seen_shapes and time.monotonic() < deadline:
         time.sleep(0.02)
     return {
         "bucket": bucket,
@@ -296,7 +296,7 @@ def _compile_stall_drill(seed: int, stall_delay_s: float, compile_deadline_s: fl
         "injected": len(events),
         "events": events,
         "all_valid": bool(mask.all()) and len(mask) == count,
-        "shape_left_cold": ("schnorr_verify", bucket) not in secp._seen_shapes,
+        "shape_left_cold": secp._shape_key("schnorr_verify", bucket) not in secp._seen_shapes,
     }
 
 

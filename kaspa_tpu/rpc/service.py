@@ -312,10 +312,15 @@ class RpcCoreService:
     def get_metrics(self) -> dict:
         from dataclasses import asdict
 
+        import jax
+
         sc = self.consensus.transaction_validator.sig_cache
         obs = observability_snapshot()
+        devs = jax.devices()
         return {
             "uptime_seconds": time.time() - self.start_time,
+            # what this node's verify/muhash kernels run on, as JAX reports it
+            "device": {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)},
             "block_count": self.api.get_block_count(),
             "tip_count": self.api.get_tips_len(),
             "mempool_size": len(self.mining.mempool),

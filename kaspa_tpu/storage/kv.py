@@ -6,7 +6,8 @@ engine (native/kvstore/kvstore.cc) provides crash-consistent CRC-framed
 atomic write batches over an append log with in-memory index; this module
 adds the typed prefixed-store access layer (registry.rs/access.rs shape).
 
-Builds the shared library on first use (g++, cached beside the source);
+Builds the shared library on first use (g++, cached beside the source
+under a name that carries the sources' digest — utils/nativebuild.py);
 a pure-python fallback engine keeps tests running without a toolchain.
 """
 
@@ -15,9 +16,9 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import subprocess
 import threading
 
+from kaspa_tpu.utils import nativebuild
 from kaspa_tpu.utils.sync import ranked_lock
 
 from kaspa_tpu.observability.core import REGISTRY
@@ -29,30 +30,14 @@ _JOURNAL_REPAIRS = REGISTRY.counter(
 _TORN_BYTES = REGISTRY.counter("kv_journal_torn_bytes", help="garbage bytes discarded by journal repair")
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native", "kvstore")
-_SRC = os.path.join(_NATIVE_DIR, "kvstore.cc")
-_HEADERS = (os.path.join(_NATIVE_DIR, "arena.h"),)
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libkvstore.so")
 _BUILD_LOCK = ranked_lock("storage.build")
 
 
-def _src_mtime() -> float:
-    return max(os.path.getmtime(f) for f in (_SRC, *_HEADERS) if os.path.exists(f))
-
-
 def _build_native():
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= _src_mtime():
-        return _LIB_PATH
     with _BUILD_LOCK:
-        if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= _src_mtime():
-            return _LIB_PATH
-        tmp = _LIB_PATH + f".tmp{os.getpid()}"
-        subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC],
-            check=True,
-            capture_output=True,
+        return nativebuild.build(
+            os.path.join(_NATIVE_DIR, "kvstore.cc"), "kvstore", deps=(os.path.join(_NATIVE_DIR, "arena.h"),)
         )
-        os.replace(tmp, _LIB_PATH)
-    return _LIB_PATH
 
 
 # keys/values are raw binary (embedded NULs are the norm for hashes), so the

@@ -172,3 +172,45 @@ def test_daemon_metrics_snapshot_over_wire(tmp_path):
         assert si["cpu_physical_cores"] >= 1 and si["version"]
     finally:
         daemon.stop()
+
+
+# --- jax_setup: the compile cache is placed from outside ---------------------
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["JAX_COMPILATION_CACHE_DIR", "in-checkout"])
+def test_jax_setup_cache_placement(tmp_path, from_env):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no directory in
+    code (JAX reads the variable itself) and cache_dir() — which the warm
+    manifest hangs off — returns it; unset, both are the one fixed
+    git-ignored directory in the checkout.  In a subprocess each, so the
+    session's own setup is not disturbed."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "outside")
+    code = (
+        "import json, jax\n"
+        "from kaspa_tpu.utils import jax_setup\n"
+        "from kaspa_tpu.resilience import supervisor\n"
+        "jax_setup.setup()\n"
+        "print(json.dumps({'config': jax.config.jax_compilation_cache_dir,"
+        " 'cache_dir': jax_setup.cache_dir(), 'manifest': supervisor.manifest_path()}))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=str(tmp_path),
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True, timeout=120,
+    )
+    assert proc.returncode == 0
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    want = str(tmp_path / "outside") if from_env else os.path.join(repo, ".jax_cache")
+    assert os.path.realpath(got["config"]) == os.path.realpath(want)
+    assert os.path.realpath(got["cache_dir"]) == os.path.realpath(want)
+    assert os.path.dirname(os.path.realpath(got["manifest"])) == os.path.realpath(want)
+    if from_env:
+        assert not (tmp_path / "outside").exists()  # set from outside, not created in code

@@ -331,7 +331,7 @@ def test_dispatch_async_works_with_coalescing_off():
 
 @pytest.mark.slow
 @pytest.mark.parametrize("mesh_n", [1, 8])
-def test_sim_replay_identical_coalesced_vs_legacy(mesh_n):
+def test_sim_replay_identical_coalesced_vs_legacy(mesh_n, on_mesh_devices):
     """Same simulated DAG, coalescing off vs on: sink + utxo_commitment
     must be byte-identical, on single-device and 8-way mesh dispatch."""
     from kaspa_tpu.ops import mesh
@@ -342,15 +342,16 @@ def test_sim_replay_identical_coalesced_vs_legacy(mesh_n):
 
     mesh.configure(mesh_n)
     try:
-        coalesce.configure(0)
-        _, legacy = replay(res)
-        sink_l = legacy.sink()
-        commit_l = legacy.multisets[sink_l].finalize().hex()
+        with on_mesh_devices(*(("schnorr",) if mesh_n > 1 else ())):
+            coalesce.configure(0)
+            _, legacy = replay(res)
+            sink_l = legacy.sink()
+            commit_l = legacy.multisets[sink_l].finalize().hex()
 
-        coalesce.configure(64)
-        _, co = replay(res)
-        sink_c = co.sink()
-        commit_c = co.multisets[sink_c].finalize().hex()
+            coalesce.configure(64)
+            _, co = replay(res)
+            sink_c = co.sink()
+            commit_c = co.multisets[sink_c].finalize().hex()
     finally:
         mesh.configure(1)
 

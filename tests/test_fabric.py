@@ -231,8 +231,11 @@ def test_mesh_2d_spec_parsing():
     assert mesh.configure("2x4") == 8
     assert mesh.grid() == (2, 4)
     assert mesh.slice_count() == 2 and mesh.slice_width() == 4
-    # grid clamping prefers keeping the slice count (the failover unit)
-    assert mesh.configure("4x4") == 8
+    # a grid larger than the visible devices is an error, never a clamp
+    with pytest.raises(ValueError, match="16 devices, 8 visible"):
+        mesh.configure("4x4")
+    assert mesh.grid() == (2, 4)
+    assert mesh.configure("4x2") == 8
     assert mesh.grid() == (4, 2)
     # a single slice degenerates to the 1-D mesh
     assert mesh.configure("1x8") == 8
@@ -266,7 +269,7 @@ def test_partition_rule_registry():
     assert specs["layer"]["bias"] == P()
 
 
-def test_schnorr_mask_identical_1d_vs_2x4_grid():
+def test_schnorr_mask_identical_1d_vs_2x4_grid(on_mesh_devices):
     """The full 2-D grid (both mesh axes, no slice pinning) must be
     bit-identical to single-device dispatch — same bucket-8 shape as the
     1-D mesh tests, so the grid entry's local computation is served by
@@ -277,7 +280,8 @@ def test_schnorr_mask_identical_1d_vs_2x4_grid():
     mesh.configure(1)
     m1 = np.asarray(secp.schnorr_verify_batch(items))
     mesh.configure("2x4")
-    m2d = np.asarray(secp.schnorr_verify_batch(items))
+    with on_mesh_devices("schnorr"):
+        m2d = np.asarray(secp.schnorr_verify_batch(items))
     assert m1.tolist() == m2d.tolist()
     assert not m1.all() and m1.any()
     snap = REGISTRY.snapshot()

@@ -30,7 +30,9 @@ def test_configure_resolution():
     assert mesh.configure(0) == 1  # <= 1 disables
     assert mesh.configure(8) == 8
     assert mesh.configure("auto") == 8  # conftest forces 8 host devices
-    assert mesh.configure(64) == 8  # clamps to visible devices
+    with pytest.raises(ValueError, match="64 devices, 8 visible"):
+        mesh.configure(64)  # an explicit size never clamps to what is visible
+    assert mesh.active_size() == 8  # ... and the failed call changed nothing
     assert mesh.configure("3") == 3
     state = REGISTRY.snapshot()["mesh"]
     assert state["size"] == 3 and state["configured"] == "3"
@@ -47,7 +49,7 @@ def _muhash_vals(n: int, seed: int = 0):
 
 
 @pytest.mark.parametrize("n", [0, 1, 7, 64, 200])
-def test_muhash_product_identical_across_mesh(n):
+def test_muhash_product_identical_across_mesh(n, on_mesh_devices):
     from kaspa_tpu.ops import muhash_ops as mo
 
     vals = _muhash_vals(n, seed=n)
@@ -56,11 +58,14 @@ def test_muhash_product_identical_across_mesh(n):
         oracle = oracle * v % mo.F.modulus
     mesh.configure(1)
     assert mo.batch_product_ints(vals) == oracle
+    sharded = ("muhash",) if n else ()  # the empty product dispatches nothing
     mesh.configure(8)
-    assert mo.batch_product_ints(vals) == oracle
+    with on_mesh_devices(*sharded):
+        assert mo.batch_product_ints(vals) == oracle
     # non-pow2 mesh: per-shard padding with the monoid identity
     mesh.configure(3)
-    assert mo.batch_product_ints(vals) == oracle
+    with on_mesh_devices(*sharded):
+        assert mo.batch_product_ints(vals) == oracle
 
 
 # --- batched signature verification ----------------------------------------
@@ -80,7 +85,7 @@ def _schnorr_items(n: int, corrupt_every: int = 4):
     return items
 
 
-def test_schnorr_mask_identical_mesh1_vs_mesh8():
+def test_schnorr_mask_identical_mesh1_vs_mesh8(on_mesh_devices):
     from kaspa_tpu.crypto import secp
 
     # 7 items -> bucket 8, 1 lane/shard on the 8-mesh.  Deliberately the same
@@ -91,21 +96,24 @@ def test_schnorr_mask_identical_mesh1_vs_mesh8():
     mesh.configure(1)
     m1 = np.asarray(secp.schnorr_verify_batch(items))
     mesh.configure(8)
-    m8 = np.asarray(secp.schnorr_verify_batch(items))
+    with on_mesh_devices("schnorr"):
+        m8 = np.asarray(secp.schnorr_verify_batch(items))
     assert m1.tolist() == m8.tolist()
     assert not m1.all() and m1.any()  # mixed validity actually exercised
 
 
-def test_dispatch_verify_padding_edges():
+def test_dispatch_verify_padding_edges(on_mesh_devices):
     """Direct mesh-layer edges: empty batch, single job (7 pad lanes on an
     8-mesh), and a batch not divisible by the shard count."""
     from kaspa_tpu.crypto import secp
 
     mesh.configure(8)
     assert secp.schnorr_verify_batch([]).shape == (0,)
-    single = np.asarray(secp.schnorr_verify_batch(_schnorr_items(1, corrupt_every=0)))
+    with on_mesh_devices("schnorr"):
+        single = np.asarray(secp.schnorr_verify_batch(_schnorr_items(1, corrupt_every=0)))
     assert single.tolist() == [True]
-    bad_single = np.asarray(secp.schnorr_verify_batch(_schnorr_items(1, corrupt_every=1)))
+    with on_mesh_devices("schnorr"):
+        bad_single = np.asarray(secp.schnorr_verify_batch(_schnorr_items(1, corrupt_every=1)))
     assert bad_single.tolist() == [False]
 
 
@@ -125,7 +133,7 @@ def test_mesh_metrics_surface():
     assert snap["mesh"]["size"] == 8
 
 
-def test_batch_checker_decisions_identical_mesh1_vs_mesh8():
+def test_batch_checker_decisions_identical_mesh1_vs_mesh8(on_mesh_devices):
     """The production path: BatchScriptChecker fast-path decisions must be
     bit-identical across mesh sizes (the acceptance criterion's unit-level
     form; the sim replay covers the full-block form)."""
@@ -177,6 +185,7 @@ def test_batch_checker_decisions_identical_mesh1_vs_mesh8():
     mesh.configure(1)
     r1 = run()
     mesh.configure(8)
-    r8 = run()
+    with on_mesh_devices("schnorr"):
+        r8 = run()
     assert r1 == r8
     assert any(v is not None for v in r1.values()) and any(v is None for v in r1.values())

@@ -1,6 +1,6 @@
 """Sharded verification of a real goref block batch on the CPU mesh.
 
-VERDICT r1 asked for multi-chip evidence beyond identical tiled lanes:
+The round-1 review asked for multi-chip evidence beyond identical tiled lanes:
 this replays a prefix of the golden tx DAG, captures the exact
 (pubkey, sighash, sig) triples the consensus validator dispatched, then
 re-runs them through the Schnorr kernel jitted over an 8-device mesh with

@@ -252,7 +252,7 @@ def test_virtual_batch_cap(monkeypatch):
 
 
 def test_relay_out_of_order_parks_on_inflight_parent():
-    """VERDICT r3 #3 'done' criterion: a relayed child whose parent is
+    """round-3 review item #3 'done' criterion: a relayed child whose parent is
     still IN FLIGHT inside the pipeline must park in the deps manager (not
     orphan out), and both must land — overlapped header/body/virtual
     processing across relay arrivals."""

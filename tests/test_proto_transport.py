@@ -123,7 +123,7 @@ def test_daemon_flag_selects_proto_wire(tmp_path):
     def spawn(name, rpc_port, p2p_port, connect=None):
         env = dict(os.environ)
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        env["KASPA_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         argv = [
             sys.executable, "-m", "kaspa_tpu.node",
             "--appdir", str(tmp_path / name),

@@ -1,21 +1,15 @@
-"""Round-evidence tooling: roundcheck artifact + bench probe/dossier helpers."""
+"""Round-evidence tooling: roundcheck artifact + bench probe / no-TPU behaviour."""
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import subprocess
 import sys
 
+import pytest
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(REPO_ROOT, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def test_roundcheck_writes_round_evidence(tmp_path):
@@ -104,63 +98,38 @@ def test_roundcheck_only_selector(tmp_path):
     assert bad.returncode != 0 and "unknown --only" in bad.stdout
 
 
-def test_bench_wedge_dossier_shape(tmp_path, monkeypatch):
-    bench = _load_bench()
-    monkeypatch.setenv("KASPA_TPU_BENCH_DOSSIER_DIR", str(tmp_path))
-    probe_log = [{"t": bench._utc_stamp(), "event": "session_probe_start", "timeout_s": 1}]
-    fallback = {"metric": bench.METRIC, "value": 123.4, "unit": bench.UNIT}
-    path = bench._write_wedge_dossier(probe_log, fallback)
-    assert os.path.dirname(path) == str(tmp_path)
-    dossier = json.loads(open(path).read())
-    assert dossier["reason"].startswith("device probe wedge")
-    assert dossier["probe_log"] == probe_log
-    assert dossier["cpu_fallback"]["value"] == 123.4
-    # timestamped filename: bench_wedge_<UTC>.json
-    assert os.path.basename(path).startswith("bench_wedge_20")
-
-
-def test_bench_cached_wedge_fast_fail(tmp_path, monkeypatch):
-    """A wedge dossier younger than the TTL short-circuits the probe +
-    retry spiral; FORCE_PROBE bypasses; a stale dossier is ignored."""
-    bench = _load_bench()
-    monkeypatch.setenv("KASPA_TPU_BENCH_DOSSIER_DIR", str(tmp_path))
-    monkeypatch.delenv("KASPA_TPU_BENCH_FORCE_PROBE", raising=False)
-
-    log: list = []
-    assert bench._cached_wedge(log) is None  # no dossier yet
-
-    dossier = tmp_path / "bench_wedge_20260805T000000Z.json"
-    dossier.write_text(json.dumps({"reason": "test", "cpu_fallback": {"value": 99.5}}))
-
-    hit = bench._cached_wedge(log)
-    assert hit is not None
-    path, doc = hit
-    assert path == str(dossier)
-    assert doc["cpu_fallback"]["value"] == 99.5
-    assert log and log[-1]["event"] == "cached_wedge_verdict"
-
-    # the recurring daemon capture forces a fresh probe to notice recovery
-    monkeypatch.setenv("KASPA_TPU_BENCH_FORCE_PROBE", "1")
-    assert bench._cached_wedge([]) is None
-    monkeypatch.delenv("KASPA_TPU_BENCH_FORCE_PROBE")
-
-    # outside the TTL the verdict is stale and the probe runs fresh
-    monkeypatch.setattr(bench, "WEDGE_TTL_S", -1.0)
-    assert bench._cached_wedge([]) is None
-
-
-def test_bench_spiral_exhaustion_writes_dossier(tmp_path, monkeypatch):
-    bench = _load_bench()
-    monkeypatch.setenv("KASPA_TPU_BENCH_DOSSIER_DIR", str(tmp_path))
-    path = bench._write_wedge_dossier(
-        [{"event": "attempt_spiral_exhausted"}], None,
-        reason="attempt spiral exhausted (probe answered, workload never finished)",
+def _run_bench(extra_env: dict, argv=()):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    env.update(extra_env)
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "bench.py"), *argv],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True, timeout=300,
     )
-    doc = json.loads(open(path).read())
-    assert doc["reason"].startswith("attempt spiral exhausted")
-    # and the fresh dossier is immediately visible to the fast-fail cache
-    monkeypatch.delenv("KASPA_TPU_BENCH_FORCE_PROBE", raising=False)
-    assert bench._cached_wedge([]) is not None
+
+
+@pytest.mark.parametrize(
+    "extra_env,argv",
+    [
+        ({"KASPA_TPU_BENCH_CHILD": "1", "KASPA_TPU_BENCH_B": "8"}, ()),  # the measuring child itself
+        ({}, ()),  # the jax-free parent, headline
+        ({}, ("--sweep",)),  # ... and the sweep
+    ],
+    ids=["child", "parent", "sweep"],
+)
+def test_bench_exits_nonzero_without_tpu(extra_env, argv):
+    """bench.py measures a TPU or nothing: on the CPU backend it exits
+    non-zero and no line it prints carries a value — there is no CPU lane
+    whose number could be read beside a device metric."""
+    proc = _run_bench(extra_env, argv)
+    assert proc.returncode != 0
+    lines = [json.loads(ln) for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    assert lines, "bench.py must say why it measured nothing"
+    for obj in lines:
+        assert "value" not in obj and "cpu_fallback_value" not in obj
+    last = lines[-1]
+    assert "tpu" in (last.get("error") or last.get("child_error") or "").lower()
 
 
 def test_bench_probe_mode_emits_json_line():
@@ -180,4 +149,4 @@ def test_bench_probe_mode_emits_json_line():
     line = [ln for ln in proc.stdout.strip().splitlines() if ln.startswith("{")][-1]
     obj = json.loads(line)
     assert obj["probe_ok"] is True and proc.returncode == 0
-    assert obj["platform"] == "cpu"
+    assert obj["platform"] == "cpu" and obj["device_platform"] == "cpu"

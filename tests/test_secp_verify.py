@@ -139,7 +139,7 @@ def test_cold_bucket_split(monkeypatch):
         return out
 
     fake_kernel.__name__ = "fake_kernel"
-    monkeypatch.setattr(secp, "_seen_shapes", {("fake_kernel", 8)})
+    monkeypatch.setattr(secp, "_seen_shapes", {("fake_kernel", 8, 1)})
     monkeypatch.delenv("KASPA_TPU_COLD_BUCKET_SPLIT", raising=False)
 
     batch = secp._Batch()
@@ -151,7 +151,7 @@ def test_cold_bucket_split(monkeypatch):
     mask = batch.run(fake_kernel)
     # two warm bucket-8 dispatches, bucket 16 never compiled
     assert calls == [8, 8]
-    assert ("fake_kernel", 16) not in secp._seen_shapes
+    assert ("fake_kernel", 16, 1) not in secp._seen_shapes
     assert mask.tolist() == [True] * 3 + [False] + [True] * 6
 
     # disabled: pad up into the cold bucket as before
@@ -162,5 +162,5 @@ def test_cold_bucket_split(monkeypatch):
         batch2.push(1, 2, 3, 4, 5)
     mask2 = batch2.run(fake_kernel)
     assert calls == [16]
-    assert ("fake_kernel", 16) in secp._seen_shapes
+    assert ("fake_kernel", 16, 1) in secp._seen_shapes
     assert mask2.tolist() == [True] * 10

@@ -110,7 +110,7 @@ def test_wallet_interactive_terminal(tmp_path):
         import os
 
         env = dict(os.environ)
-        env["KASPA_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         proc = subprocess.Popen(
             [sys.executable, "-m", "kaspa_tpu.wallet", "--rpc", addr, "--seed-file", str(seed), "repl"],

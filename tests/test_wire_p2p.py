@@ -22,7 +22,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _spawn_daemon(tmp_path, name, rpc_port, p2p_port, connect=None):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env["KASPA_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     argv = [
         sys.executable, "-m", "kaspa_tpu.node",
         "--appdir", str(tmp_path / name),

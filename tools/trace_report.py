@@ -16,7 +16,7 @@ additionally get a per-block critical-path table, and export to the
 Chrome trace-event format that ui.perfetto.dev / chrome://tracing load:
 
     python tools/trace_report.py /tmp/spans.jsonl
-    python tools/trace_report.py BENCH_r06.json --top 15
+    python tools/trace_report.py bench_line.json --top 15
     python tools/trace_report.py FLIGHT.json --critical-path
     python tools/trace_report.py FLIGHT.json --perfetto trace.json
 """
