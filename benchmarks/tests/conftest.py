@@ -1,0 +1,40 @@
+"""The benchmark's own tests run by hand, on the CPU, at toy sizes:
+
+    python -m pytest benchmarks/tests -q -p no:cacheprovider
+
+They hold JAX to the CPU before its first import; nothing they time or count
+is ever written under a device metric's name.
+"""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _jax_cache():
+    from kaspa_tpu.utils import jax_setup
+
+    jax_setup.setup()
+
+
+TOY_NETWORK = {"bps": 2, "delay_s": 1.0, "miners": 4}
+
+
+def toy_cell(mode: str, tx_per_block: int = 4, window_blocks: int = 24) -> tuple[dict, dict]:
+    """A toy traffic file and configuration: the same keys as the real ones,
+    sizes a CPU run can hold (one verify bucket, XLA ladder)."""
+    workload = {
+        "config": "toy", "mode": mode, "tx_per_block": tx_per_block, "tx_shape": "fanout-then-1to1",
+        "window_blocks": window_blocks, "spoiled_blocks": 2, "pool_factor": 3, "max_in_flight": 99,
+        "grace_seconds": 30, "sig_samples": 4, "pretrace": {"schnorr_verify": [8]}, "trace_seconds": 1.0,
+        "idle_gap_spans": ["txscript.dispatch_wait", "pipeline.virtual", "pipeline.body", "pipeline.header"],
+    }
+    config = {"name": "toy", "network": dict(TOY_NETWORK), "pipeline": {"coalesce": 64, "stage_workers": 2}}
+    return workload, config
