@@ -1361,28 +1361,33 @@ class Consensus:
                 self.params.finality_depth,
             )
         staged = []
-        for i, tx in enumerate(txs):
-            if i == 0:
-                continue  # coinbase
-            entries = []
-            missing = False
-            for inp in tx.inputs:
-                entry = utxo_view.get(inp.previous_outpoint)
-                if entry is None:
-                    missing = True
-                    break
-                entries.append(entry)
-            if missing:
-                continue
-            token = (token_tag, i) if shared else i
-            try:
-                fee = self.transaction_validator.validate_populated_transaction_and_get_fee(
-                    tx, entries, pov_daa_score, flags, checker=checker, token=token,
-                    seq_commit_accessor=accessor,
-                )
-            except TxRuleError:
-                continue
-            staged.append((token, tx, entries, fee))
+        # UTXO population, sighash and job staging of one merged block: what
+        # the virtual stage does for its scripts before the round trip
+        with trace.span("txscript.collect", txs=len(txs) - 1, speculative=shared) as sp:
+            jobs0 = checker.queued_jobs()
+            for i, tx in enumerate(txs):
+                if i == 0:
+                    continue  # coinbase
+                entries = []
+                missing = False
+                for inp in tx.inputs:
+                    entry = utxo_view.get(inp.previous_outpoint)
+                    if entry is None:
+                        missing = True
+                        break
+                    entries.append(entry)
+                if missing:
+                    continue
+                token = (token_tag, i) if shared else i
+                try:
+                    fee = self.transaction_validator.validate_populated_transaction_and_get_fee(
+                        tx, entries, pov_daa_score, flags, checker=checker, token=token,
+                        seq_commit_accessor=accessor,
+                    )
+                except TxRuleError:
+                    continue
+                staged.append((token, tx, entries, fee))
+            sp.set(jobs=checker.queued_jobs() - jobs0)
         if shared:
             return staged
         script_results = checker.dispatch()
@@ -1484,6 +1489,13 @@ class Consensus:
         """Reposition the materialized UTXO set along the selected chain."""
         if self.utxo_position == target:
             return
+        with trace.span("virtual.move_position") as sp:
+            unapplied, applied = self._walk_utxo_position(target)
+            sp.set(unapplied=unapplied, applied=applied)
+
+    def _walk_utxo_position(self, target: bytes) -> tuple[int, int]:
+        """Unapply chain diffs down to a chain ancestor of ``target``, apply
+        up to it; returns the chain blocks walked each way."""
         # walk current position down to a chain ancestor of target
         back_path = []
         cur = self.utxo_position
@@ -1507,3 +1519,4 @@ class Consensus:
             self.selected_chain.append((self.storage.ghostdag.get_blue_score(b), b))
         self.utxo_position = target
         self._persist_utxo_position()
+        return len(back_path), len(fwd_path)

@@ -25,6 +25,7 @@ import numpy as np
 from kaspa_tpu.crypto import chacha
 from kaspa_tpu.crypto import hashing as h
 from kaspa_tpu.observability import trace
+from kaspa_tpu.observability.core import REGISTRY
 
 ELEMENT_BYTE_SIZE = 384
 PRIME = 2**3072 - 1103717  # u3072.rs:22
@@ -55,6 +56,12 @@ def _digests(preimages: list[bytes]) -> np.ndarray:
 # padded 64-wide bucket isn't worth it below this).
 DEVICE_BATCH_THRESHOLD = 32
 
+# the other side of muhash_device_elements (ops/muhash_ops.py): which way a
+# commit's elements went
+_HOST_ELEMENTS = REGISTRY.counter(
+    "muhash_host_elements", help="field elements multiplied on the host (bulk product under DEVICE_BATCH_THRESHOLD)"
+)
+
 
 def elements_from_preimages(preimages: list[bytes]) -> list[int]:
     """Batch preimage -> field-element derivation (vectorised keystream)."""
@@ -75,11 +82,15 @@ def bulk_element_product(preimages: list[bytes], use_device: bool = True) -> int
     if use_device and len(preimages) >= DEVICE_BATCH_THRESHOLD:
         from kaspa_tpu.ops import muhash_ops
 
-        ks = chacha.keystream(_digests(preimages), ELEMENT_BYTE_SIZE)
-        limbs = ks.view(np.dtype("<u2")).astype(np.int32)  # [N, 192]
+        with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
+            ks = chacha.keystream(_digests(preimages), ELEMENT_BYTE_SIZE)
+            limbs = ks.view(np.dtype("<u2")).astype(np.int32)  # [N, 192]
         return muhash_ops.batch_product_device(limbs)
+    with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
+        elements = elements_from_preimages(preimages)
+    _HOST_ELEMENTS.inc(len(preimages))
     acc = 1
-    for e in elements_from_preimages(preimages):
+    for e in elements:
         acc = acc * e % PRIME
     return acc
 
@@ -171,10 +182,12 @@ class MuHash:
         with trace.span("muhash.commit", txs=len(items)):
             adds: list[bytes] = []
             removes: list[bytes] = []
-            for tx, entries, daa in items:
-                a, r = _tx_element_preimages(tx, entries, daa)
-                adds += a
-                removes += r
+            with trace.span("muhash.host_prepare", phase="preimages") as sp:
+                for tx, entries, daa in items:
+                    a, r = _tx_element_preimages(tx, entries, daa)
+                    adds += a
+                    removes += r
+                sp.set(elements=len(adds) + len(removes))
             if adds:
                 self.numerator = self.numerator * bulk_element_product(adds, use_device) % PRIME
             if removes:

@@ -28,15 +28,19 @@ from kaspa_tpu.resilience import supervisor
 from kaspa_tpu.resilience.breaker import HUNG, device_breaker
 from kaspa_tpu.resilience.faults import FAULTS
 
-# batch shape telemetry: occupancy is the fraction of padded device lanes
-# doing useful work, the quantity batch-verify throughput is dominated by
-# (committee-consensus signature studies measure exactly this); dispatched
+# batch shape telemetry: occupancy here is jobs over the `_bucket` width the
+# batch is padded to on the host — not the width of the program the device
+# launches (the Pallas ladder pads the bucket on to a multiple of 256:
+# `secp_device_lanes` in ops/secp256k1/verify.py counts that); dispatched
 # shapes proxy XLA recompiles — every new bucket is a fresh jit trace
 _BATCH_SIZE = REGISTRY.histogram("secp_batch_size", SIZE_BUCKETS, help="logical verify jobs per device batch")
 _OCCUPANCY = REGISTRY.histogram(
-    "secp_batch_occupancy_pct", PERCENT_BUCKETS, help="logical batch size / padded bucket size * 100"
+    "secp_batch_occupancy_pct", PERCENT_BUCKETS,
+    help="logical batch size / _bucket width * 100 (the launched width is secp_device_lanes)",
 )
-_PADDED_LANES = REGISTRY.counter("secp_padded_lanes", help="device lanes wasted on pad-to-bucket")
+_PADDED_LANES = REGISTRY.counter(
+    "secp_padded_lanes", help="lanes added by pad-to-bucket on the host (_bucket width - jobs; not the launched width)"
+)
 _NEW_SHAPES = REGISTRY.counter_family(
     "secp_dispatch_shapes", "kernel", help="distinct padded bucket sizes dispatched (jit recompile proxy)"
 )
@@ -189,17 +193,18 @@ class _Batch:
         if new_shape:
             _seen_shapes.add(shape_key)
             _NEW_SHAPES.inc(kernel.__name__)
-        ok = np.zeros(b, dtype=bool)
-        ok[:n] = self.ok
-        pad = [0] * (b - n)
-        args = (
-            _be32_to_limbs(self.px, b),
-            _be32_to_limbs(self.py, b),
-            _be32_to_limbs(self.rc, b),
-            self.d1 + pad,
-            self.d2 + pad,
-            ok,
-        )
+        with trace.span("secp.host_marshal", kernel=kernel.__name__, batch=n, lanes=b):
+            ok = np.zeros(b, dtype=bool)
+            ok[:n] = self.ok
+            pad = [0] * (b - n)
+            args = (
+                _be32_to_limbs(self.px, b),
+                _be32_to_limbs(self.py, b),
+                _be32_to_limbs(self.rc, b),
+                self.d1 + pad,
+                self.d2 + pad,
+                ok,
+            )
         if new_shape:
             # first dispatch of a (kernel, bucket) shape pays the XLA
             # trace+compile; surfacing it as a span is what lets a wedge
@@ -328,7 +333,9 @@ def schnorr_verify_batch(items) -> np.ndarray:
     False without occupying useful device lanes beyond padding).
     """
     items = list(items)
-    return _run_guarded(_build_schnorr_batch(items), schnorr_verify, items, eclib.schnorr_verify)
+    with trace.span("secp.host_prepare", kernel="schnorr_verify", jobs=len(items)):
+        batch = _build_schnorr_batch(items)
+    return _run_guarded(batch, schnorr_verify, items, eclib.schnorr_verify)
 
 
 # --- aggregated random-linear-combination verification ---------------------
@@ -471,7 +478,9 @@ def _aggregate_device_check(prep: _AggBatch, weights: list, rows: list, idxs: li
     FAULTS.fire("device.verify")
     FAULTS.fire("device.hang")
     n = len(idxs)
-    args, b = _aggregate_args(prep, weights, rows, idxs)
+    with trace.span("secp.host_marshal", kernel=_AGG_KERNEL_NAME, batch=n) as sp:
+        args, b = _aggregate_args(prep, weights, rows, idxs)
+        sp.set(lanes=b)
     _AGG_CHECKS.inc()
     _BATCH_SIZE.observe(n)
     _OCCUPANCY.observe(100.0 * n / b)
@@ -557,7 +566,7 @@ def schnorr_verify_batch_aggregate(items) -> np.ndarray:
     if n == 0:
         return np.zeros(0, dtype=bool)
     with trace.span("dispatch.aggregate", jobs=n):
-        with trace.span("secp.host_marshal", kernel=_AGG_KERNEL_NAME, batch=n):
+        with trace.span("secp.host_prepare", kernel=_AGG_KERNEL_NAME, jobs=n):
             prep = _build_schnorr_aggregate(items)
             weights = _aggregate_weights(items)
         _AGG_BATCHES.inc()
@@ -599,7 +608,9 @@ def _build_ecdsa_batch(items: list) -> _Batch:
 def ecdsa_verify_batch(items) -> np.ndarray:
     """items: iterable of (pubkey33, msg32, sig64_compact) -> bool mask."""
     items = list(items)
-    return _run_guarded(_build_ecdsa_batch(items), ecdsa_verify, items, eclib.ecdsa_verify)
+    with trace.span("secp.host_prepare", kernel="ecdsa_verify", jobs=len(items)):
+        batch = _build_ecdsa_batch(items)
+    return _run_guarded(batch, ecdsa_verify, items, eclib.ecdsa_verify)
 
 
 def verify_batch(kind: str, items) -> np.ndarray:

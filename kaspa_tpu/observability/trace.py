@@ -86,6 +86,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -151,6 +154,10 @@ class Span:
         """Handle for parenting work handed to another thread/queue."""
         return TraceContext(self.trace_id, self.span_id, self.path)
 
+    def set(self, **attrs) -> None:
+        """Attributes known only once the region has run (counts)."""
+        self.attrs.update(attrs)
+
 
 def _sink(rec: dict) -> None:
     cap = _capture
@@ -191,10 +198,13 @@ def record_span(
     SPAN_HIST.observe(name, (t1_ns - t0_ns) * 1e-9)
     if _capture is None and _flight_sink is None:
         return None
-    sid = _next_id()
     trace_id = parent.trace_id if parent is not None else None
     parent_id = parent.span_id if parent is not None else 0
     path = (parent.path + "/" + name) if parent is not None else name
+    return _record(name, trace_id, _next_id(), parent_id, path, t0_ns, t1_ns, attrs)
+
+
+def _record(name, trace_id, sid, parent_id, path, t0_ns, t1_ns, attrs) -> TraceContext:
     _sink(
         {
             "name": name,
@@ -212,6 +222,24 @@ def record_span(
         }
     )
     return TraceContext(trace_id, sid, path)
+
+
+def root_context(trace_id: str, name: str) -> TraceContext:
+    """Context of a root span that ``record_root`` will close later: work
+    handed across queues parents on it (and carries ``trace_id``) before
+    the root's extent is known.  An object only: no ring, no lock."""
+    return TraceContext(trace_id, _next_id(), name)
+
+
+def record_root(ctx: TraceContext, t0_ns: int, t1_ns: int, **attrs) -> None:
+    """Close the root span ``root_context`` opened, under the id its
+    children already name as their parent."""
+    if not _enabled:
+        return
+    t1_ns = max(t1_ns, t0_ns)
+    SPAN_HIST.observe(ctx.path, (t1_ns - t0_ns) * 1e-9)
+    if _capture is not None or _flight_sink is not None:
+        _record(ctx.path, ctx.trace_id, ctx.span_id, 0, ctx.path, t0_ns, t1_ns, attrs)
 
 
 def context() -> TraceContext | None:

@@ -452,9 +452,9 @@ class CoalescingDispatcher:
                     batch.append(group[i])
                     jobs += len(group[i].items)
                     i += 1
-                self._run_super_batch(kind, batch, jobs, now)
+                self._run_super_batch(kind, batch, jobs, now, reason)
 
-    def _run_super_batch(self, kind: str, batch: list[_Chunk], jobs: int, now: float) -> None:
+    def _run_super_batch(self, kind: str, batch: list[_Chunk], jobs: int, now: float, reason: str) -> None:
         from kaspa_tpu.crypto import secp  # deferred: keeps import DAG acyclic
 
         _COALESCE_DEPTH.observe(len(batch))
@@ -476,25 +476,28 @@ class CoalescingDispatcher:
             t1 = perf_counter_ns()
         except Exception as e:  # noqa: BLE001 - surfaced on every waiting ticket
             t1 = perf_counter_ns()
-            self._fan_back(kind, batch, jobs, sid, t1, t1, error=type(e).__name__)
+            self._fan_back(kind, batch, jobs, sid, t1, t1, reason, error=type(e).__name__)
             for c in batch:
                 self._finish(c, None, e)
             return
-        self._fan_back(kind, batch, jobs, sid, t0, t1)
+        self._fan_back(kind, batch, jobs, sid, t0, t1, reason)
         pos = 0
         for c in batch:
             self._finish(c, mask[pos : pos + len(c.items)], None)
             pos += len(c.items)
 
-    def _fan_back(self, kind: str, batch: list[_Chunk], jobs: int, sid: int, t0: int, t1: int, **extra) -> None:
+    def _fan_back(
+        self, kind: str, batch: list[_Chunk], jobs: int, sid: int, t0: int, t1: int, reason: str, **extra
+    ) -> None:
         """Fan the single device dispatch back into each submitting block's
-        trace: a retroactive ``wait.dispatch`` (enqueue -> kernel start)
-        plus a ``dispatch.device`` child covering the device interval,
-        stamped with a shared super_id so Perfetto can correlate them."""
+        trace: a retroactive ``wait.dispatch`` (enqueue -> kernel start,
+        with what flushed the queue: nudge / age / size / drain) plus a
+        ``dispatch.device`` child covering the device interval, stamped
+        with a shared super_id so Perfetto can correlate them."""
         for c in batch:
             if c.ctx is None:
                 continue
-            trace.record_span("wait.dispatch", c.ctx, c.enqueued_ns, t0)
+            trace.record_span("wait.dispatch", c.ctx, c.enqueued_ns, t0, reason=reason)
             trace.record_span(
                 "dispatch.device", c.ctx, t0, t1,
                 kind=kind, jobs=len(c.items), super_jobs=jobs,

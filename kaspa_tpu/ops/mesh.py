@@ -53,6 +53,7 @@ from kaspa_tpu.utils.sync import ranked_lock
 
 import numpy as np
 
+from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import PERCENT_BUCKETS, REGISTRY, SIZE_BUCKETS
 
 # --- per-shard observability ----------------------------------------------
@@ -400,6 +401,19 @@ def _verify_entry_slice(kind: str, r: int, c: int, idx: int):
     return _sharded_jit(_verify_kernel(kind), _slice_mesh(r, c, idx), in_specs, out_specs)
 
 
+def _verify_shards() -> int:
+    """Shards this thread's verify dispatch runs over: the pinned slice's
+    width inside ``slice_lane``, else the whole mesh."""
+    g = _grid
+    return g[1] if g is not None and getattr(_slice_tls, "idx", None) is not None else active_size()
+
+
+def padded_lanes(b: int) -> int:
+    """Rows a verify batch of ``b`` is padded to: a shard multiple."""
+    n = _verify_shards()
+    return -(-b // n) * n
+
+
 def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np.ndarray:
     """Batch-dim sharded verify: pads to a shard multiple, dispatches the
     cached shard_map entry, unpads the mask.  Pad lanes carry zeroed limbs
@@ -408,26 +422,28 @@ def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np
     With a 2-D grid configured, a thread inside ``slice_lane(i)`` runs on
     slice i's devices only; unpinned threads shard over the full grid.
     """
+    import jax
+
     from kaspa_tpu.resilience.faults import FAULTS
 
     # mesh-specific fault point (a single wedged shard kills the whole
     # shard_map dispatch); propagates into the device breaker like any
     # other dispatch failure
     FAULTS.fire("device.mesh.dispatch")
-    total = active_size()
+    n = _verify_shards()
     g = _grid
     pin = getattr(_slice_tls, "idx", None) if g else None
     if g is None:
-        n, entry = total, _verify_entry(kind, total)
+        entry = _verify_entry(kind, n)
     elif pin is not None:
-        n, entry = g[1], _verify_entry_slice(kind, g[0], g[1], pin)
+        entry = _verify_entry_slice(kind, g[0], g[1], pin)
     else:
-        n, entry = total, _verify_entry_2d(kind, g[0], g[1])
+        entry = _verify_entry_2d(kind, g[0], g[1])
     px = np.asarray(px)
     b = px.shape[0]
     if b == 0:
         return np.zeros(0, dtype=bool)
-    m = -(-b // n) * n  # ceil to shard multiple
+    m = -(-b // n) * n  # ceil to shard multiple (padded_lanes)
     args = (
         _pad_rows(px, m),
         _pad_rows(py, m),
@@ -436,12 +452,18 @@ def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np
         _pad_rows(d2_digits, m),
         _pad_rows(np.asarray(valid_in, dtype=bool), m),
     )
-    mask = np.asarray(entry(*args))
+    kernel = f"{kind}_mesh"
+    with trace.span("secp.device_call", kernel=kernel, lanes=m):
+        out = entry(*args)
+        out.copy_to_host_async()  # queued behind the kernel, as np.asarray alone would
+        jax.block_until_ready(out)
+    with trace.span("secp.readback", kernel=kernel):
+        mask = np.asarray(out)[:b]
     _observe(kind, b, m, n)
     if pin is not None:
         _SLICE_DISPATCHES.inc(str(pin))
         _SLICE_JOBS.inc(str(pin), b)
-    return mask[:b]
+    return mask
 
 
 # --- aggregate RLC window partials -----------------------------------------
@@ -563,7 +585,7 @@ def dispatch_tree_product(elements: np.ndarray) -> int:
     partial product combines on host with one 3072-bit multiply.
     """
     from kaspa_tpu.ops import bigint as bi
-    from kaspa_tpu.ops.muhash_ops import BUCKETS, DEVICE_DISPATCHES
+    from kaspa_tpu.ops.muhash_ops import BUCKETS, DEVICE_DISPATCHES, DEVICE_ELEMENTS
 
     F = bi.F3072
     n = active_size()
@@ -587,6 +609,7 @@ def dispatch_tree_product(elements: np.ndarray) -> int:
         padded[: chunk.shape[0]] = chunk
         partials = np.asarray(_tree_entry(n, bucket.bit_length() - 1)(padded))
         DEVICE_DISPATCHES.inc(str(bucket))
+        DEVICE_ELEMENTS.inc(take)
         for row in partials:
             result = result * bi.limbs_to_int(row) % F.modulus
         _observe("muhash", take, bucket * n, n)

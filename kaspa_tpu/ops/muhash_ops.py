@@ -31,6 +31,13 @@ DEVICE_DISPATCHES = REGISTRY.counter_family(
 )
 
 
+# the useful elements of those dispatches: over bucket x dispatches this is
+# the tree's lane occupancy, and the unit of work its roofline share counts
+DEVICE_ELEMENTS = REGISTRY.counter(
+    "muhash_device_elements", help="field elements multiplied by the device tree product (padding not counted)"
+)
+
+
 # Fixed batch buckets: one jit compile per bucket size (the 3072-bit mul
 # body is large, so unbounded shape-polymorphism would hammer compile time).
 BUCKETS = (64, 1024)
@@ -38,10 +45,11 @@ BUCKETS = (64, 1024)
 
 @functools.partial(jax.jit, static_argnames=("levels",))
 def _tree_product(x, levels: int):
-    for _ in range(levels):
-        half = x.shape[0] // 2
-        x = bi.mul(F, x[:half], x[half:])
-    return bi.canon(F, x[0])
+    with jax.named_scope("muhash_tree_product"):
+        for _ in range(levels):
+            half = x.shape[0] // 2
+            x = bi.mul(F, x[:half], x[half:])
+        return bi.canon(F, x[0])
 
 
 def batch_product_device(elements: np.ndarray) -> int:
@@ -70,11 +78,13 @@ def batch_product_device(elements: np.ndarray) -> int:
         bucket = fitting[-1] if fitting else BUCKETS[0]
         chunk = elements[pos : pos + min(bucket, remaining)]
         levels = bucket.bit_length() - 1
-        padded = np.tile(np.asarray(F.one, dtype=np.int32), (bucket, 1))
-        padded[: chunk.shape[0]] = chunk
+        with trace.span("muhash.host_prepare", phase="pad", elements=chunk.shape[0]):
+            padded = np.tile(np.asarray(F.one, dtype=np.int32), (bucket, 1))
+            padded[: chunk.shape[0]] = chunk
         with trace.span("muhash.device_dispatch", bucket=bucket, elements=chunk.shape[0]):
             out = np.asarray(_tree_product(jnp.asarray(padded), levels))
         DEVICE_DISPATCHES.inc(str(bucket))
+        DEVICE_ELEMENTS.inc(chunk.shape[0])
         _note_bucket(bucket)
         result = result * bi.limbs_to_int(out) % F.modulus
         pos += chunk.shape[0]

@@ -37,6 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kaspa_tpu.observability import trace
 from kaspa_tpu.ops import bigint as bi
 
 W8 = 32  # 8-bit limbs per 256-bit element
@@ -49,6 +50,16 @@ _C_P = (1 << 256) - SECP_P  # 2**32 + 977
 _C_N = (1 << 256) - SECP_N
 
 B3 = 21  # 3*b for y^2 = x^3 + 7
+
+
+def launched_lanes(b: int) -> int:
+    """Lanes of the program a batch of ``b`` launches: whole BLK blocks."""
+    return -(-b // BLK) * BLK
+
+
+def _kernel_name(ecdsa: bool, glv: bool) -> str:
+    """The Mosaic call's name on the device (profiler events, HLO metadata)."""
+    return "secp256k1_ladder_" + ("ecdsa" if ecdsa else "schnorr") + ("_glv" if glv else "")
 
 
 def _c_digits(c: int) -> tuple[int, ...]:
@@ -636,6 +647,7 @@ def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
             pltpu.VMEM((16, W8, BLK), jnp.int32),
         ],
         interpret=interpret,
+        name=_kernel_name(ecdsa, glv=False),
     )
     jitted = jax.jit(call)
 
@@ -699,6 +711,7 @@ def _build_call(n_padded: int, ecdsa: bool, interpret: bool):
             pltpu.VMEM((16, W8, BLK), jnp.int32),  # tabz
         ],
         interpret=interpret,
+        name=_kernel_name(ecdsa, glv=True),
     )
     jitted = jax.jit(call)
 
@@ -766,25 +779,34 @@ def verify_batch_pallas(px, py, r_canon, s_scalars, e_scalars, valid_in, *, ecds
     if glv is None:
         glv = bool(os.environ.get("KASPA_TPU_GLV"))
     b = np.asarray(px).shape[0]
-    n = -(-b // BLK) * BLK
-    px8 = _pad_lanes(_to_radix8_T(px), n)
-    py8 = _pad_lanes(_to_radix8_T(py), n)
-    rc8 = _pad_lanes(_to_radix8_T(r_canon), n)
-    vin = _pad_lanes(np.broadcast_to(np.asarray(valid_in, dtype=np.int32), (8, b)).copy(), n)
-    if glv:
-        g1, g2, gs = _glv_digits(s_scalars)
-        p1, p2, ps = _glv_digits(e_scalars)
-        sgn = np.broadcast_to((gs | (ps << 2)).astype(np.int32), (8, b)).copy()
-        out = np.asarray(
-            _build_call(n, ecdsa, interpret)(
+    n = launched_lanes(b)
+    kernel = ("ecdsa" if ecdsa else "schnorr") + ("_pallas_glv" if glv else "_pallas")
+    with trace.span("secp.host_marshal", kernel=kernel, batch=b, lanes=n):
+        px8 = _pad_lanes(_to_radix8_T(px), n)
+        py8 = _pad_lanes(_to_radix8_T(py), n)
+        rc8 = _pad_lanes(_to_radix8_T(r_canon), n)
+        vin = _pad_lanes(np.broadcast_to(np.asarray(valid_in, dtype=np.int32), (8, b)).copy(), n)
+        if glv:
+            g1, g2, gs = _glv_digits(s_scalars)
+            p1, p2, ps = _glv_digits(e_scalars)
+            sgn = np.broadcast_to((gs | (ps << 2)).astype(np.int32), (8, b)).copy()
+            args = (
                 px8, py8, rc8,
                 _pad_lanes(g1, n), _pad_lanes(g2, n),
                 _pad_lanes(p1, n), _pad_lanes(p2, n),
                 _pad_lanes(sgn, n), vin,
             )
-        )
-    else:
-        sd = _pad_lanes(_full_digits(s_scalars), n)
-        ed = _pad_lanes(_full_digits(e_scalars), n)
-        out = np.asarray(_build_call_plain(n, ecdsa, interpret)(px8, py8, rc8, sd, ed, vin))
-    return out[0, :b].astype(bool)
+        else:
+            sd = _pad_lanes(_full_digits(s_scalars), n)
+            ed = _pad_lanes(_full_digits(e_scalars), n)
+            args = (px8, py8, rc8, sd, ed, vin)
+    # transfer in, launch and the kernel itself, to the ready output; the
+    # copy back is queued behind the kernel at once, as a bare np.asarray
+    # would queue it, so splitting the wait costs no extra round trip
+    with trace.span("secp.device_call", kernel=kernel, lanes=n):
+        call = (_build_call if glv else _build_call_plain)(n, ecdsa, interpret)
+        out = call(*args)
+        out.copy_to_host_async()
+        jax.block_until_ready(out)
+    with trace.span("secp.readback", kernel=kernel):
+        return np.asarray(out)[0, :b].astype(bool)

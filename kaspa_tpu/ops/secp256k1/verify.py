@@ -104,6 +104,12 @@ _DEVICE_DISPATCHES = REGISTRY.counter_family(
 _DEVICE_BUCKETS = REGISTRY.counter_family(
     "secp_device_buckets", "bucket", help="verify batches answered by the device, by padded bucket width"
 )
+# the width the device actually ran, which the bucket understates on the
+# Pallas path (whole 256-lane blocks): secp_device_jobs over this is the
+# occupancy of the launched program
+_DEVICE_LANES = REGISTRY.counter(
+    "secp_device_lanes", help="lanes of the verify programs the device ran (bucket padded on to the launched width)"
+)
 
 
 def _verify(kind: str, px, py, rc, k1, k2, valid_in) -> np.ndarray:
@@ -124,30 +130,39 @@ def _verify(kind: str, px, py, rc, k1, k2, valid_in) -> np.ndarray:
     n_mesh = mesh.active_size()
     b = np.asarray(px).shape[0]
     if n_mesh == 1 and _use_pallas():
-        from kaspa_tpu.ops.secp256k1.ladder_pallas import verify_batch_pallas
+        from kaspa_tpu.ops.secp256k1.ladder_pallas import launched_lanes, verify_batch_pallas
 
         kernel = f"{kind}_pallas"
+        lanes = launched_lanes(b)
         with trace.span("secp.device_dispatch", kernel=kernel, batch=b):
             mask = verify_batch_pallas(px, py, rc, k1, k2, valid_in, ecdsa=kind == "ecdsa")
     else:
         # host marshal vs device dispatch split: when throughput collapses,
         # this localizes the stall to python packing or the XLA round trip
-        with trace.span("secp.host_marshal", kernel=kind, batch=b):
+        with trace.span("secp.host_marshal", kernel=kind, batch=b, lanes=b):
             d1 = _scalars_to_digits(k1, b)
             d2 = _scalars_to_digits(k2, b)
         if n_mesh > 1:
             # mesh > 1 rides the portable XLA formulation sharded over the
             # device mesh (the fused Mosaic ladder stays the single-chip path)
             kernel = f"{kind}_mesh"
+            lanes = mesh.padded_lanes(b)
             with trace.span("secp.device_dispatch", kernel=kernel, batch=b, mesh=n_mesh):
                 mask = mesh.dispatch_verify(kind, px, py, rc, d1, d2, valid_in)
         else:
             kernel = kind
+            lanes = b
             xla_kernel = schnorr_verify_kernel if kind == "schnorr" else ecdsa_verify_kernel
             with trace.span("secp.device_dispatch", kernel=kernel, batch=b):
-                mask = np.asarray(xla_kernel(px, py, rc, d1, d2, valid_in))
+                with trace.span("secp.device_call", kernel=kernel, lanes=lanes):
+                    out = xla_kernel(px, py, rc, d1, d2, valid_in)
+                    out.copy_to_host_async()  # queued behind the kernel, as np.asarray alone would
+                    jax.block_until_ready(out)
+                with trace.span("secp.readback", kernel=kernel):
+                    mask = np.asarray(out)
     _DEVICE_DISPATCHES.inc(kernel)
     _DEVICE_BUCKETS.inc(str(b))
+    _DEVICE_LANES.inc(lanes)
     return mask
 
 

@@ -62,7 +62,7 @@ class _Task:
     header_only: bool
     future: Future
     enqueue_ns: int = 0  # set at submit / virtual hand-off for queue-wait spans
-    ctx: object = None  # flight-recorder root TraceContext (None when off)
+    ctx: object = None  # root TraceContext: the flight recorder's, or the capture log's (None with no sink)
 
 
 class ConsensusPipeline:
@@ -110,12 +110,24 @@ class ConsensusPipeline:
         # flight recorder: the block's trace starts at intake and is sealed
         # when the future resolves (after virtual absorption); a duplicate
         # submission re-joins the existing open trace
-        task.ctx = flight.begin(block.hash) if flight.enabled() else None
+        recorder = flight.enabled()
+        if recorder:
+            task.ctx = flight.begin(block.hash)
+        elif trace.sinks_active():
+            # span capture without the recorder: the block's spans share its
+            # hash as their trace id, under a root that closes at resolution
+            # (recorded before _on_done, so an idle pipeline has all its roots)
+            task.ctx = trace.root_context(block.hash.hex(), "pipeline.block")
+            fut.add_done_callback(
+                lambda f, ctx=task.ctx, t0=task.enqueue_ns: trace.record_root(
+                    ctx, t0, perf_counter_ns(), status="error" if f.exception() else str(f.result())
+                )
+            )
         _SUBMITTED.inc()
         with self._idle_mu:
             self._inflight += 1
         fut.add_done_callback(self._on_done)
-        if task.ctx is not None:
+        if recorder and task.ctx is not None:
             fut.add_done_callback(
                 lambda f, h=block.hash: flight.end(h, "error" if f.exception() else "ok")
             )

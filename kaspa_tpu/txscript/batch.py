@@ -299,6 +299,10 @@ class BatchScriptChecker:
 
         self._jobs.append(_Job(kind, pubkey, msg, sig, cache_key, cb))
 
+    def queued_jobs(self) -> int:
+        """Signature jobs staged for the device lane since the last dispatch."""
+        return len(self._jobs)
+
     def _effective_workers(self, jobs: int) -> int:
         w = self.fallback_workers if self.fallback_workers is not None else _default_fallback_workers()
         return min(w, jobs)
