@@ -163,7 +163,6 @@ def _child_ecdsa_main(obs_fn) -> None:
     import numpy as np
 
     from kaspa_tpu.crypto import eclib
-    from kaspa_tpu.ops import bigint as bi
     from kaspa_tpu.ops import mesh
     from kaspa_tpu.ops.secp256k1.verify import ecdsa_verify
 
@@ -184,9 +183,12 @@ def _child_ecdsa_main(obs_fn) -> None:
         expect[i] = False
 
     half_n = eclib.N // 2
-    px = np.zeros((B, 16), np.int32)
-    py = np.zeros((B, 16), np.int32)
-    rc = np.zeros((B, 16), np.int32)
+    # byte columns, as secp._Batch hands them over: the backend (pallas or
+    # XLA) derives its own layout — the e2e path includes that marshalling
+    zero32 = bytes(32)
+    px = [zero32] * B
+    py = [zero32] * B
+    rc = [zero32] * B
     u1 = [0] * B
     u2 = [0] * B
     ok = np.zeros(B, dtype=bool)
@@ -199,9 +201,9 @@ def _child_ecdsa_main(obs_fn) -> None:
             continue
         z = int.from_bytes(msg, "big") % eclib.N
         si = pow(s, -1, eclib.N)
-        px[i] = bi.int_to_limbs(x, 16)
-        py[i] = bi.int_to_limbs(y, 16)
-        rc[i] = bi.int_to_limbs(r, 16)
+        px[i] = x.to_bytes(32, "big")
+        py[i] = y.to_bytes(32, "big")
+        rc[i] = r.to_bytes(32, "big")
         u1[i] = z * si % eclib.N
         u2[i] = r * si % eclib.N
         ok[i] = True
@@ -491,7 +493,6 @@ def _child_main() -> None:
 
     from kaspa_tpu.crypto import eclib
     from kaspa_tpu.crypto.secp import schnorr_challenge
-    from kaspa_tpu.ops import bigint as bi
     from kaspa_tpu.ops.secp256k1.verify import schnorr_verify
 
     from kaspa_tpu.sim import sigbatch
@@ -510,17 +511,12 @@ def _child_main() -> None:
         sigs[i] = sigs[i][:j] + bytes([sigs[i][j] ^ (1 + rng.randrange(255))]) + sigs[i][j + 1 :]
         expect[i] = False
 
-    px = np.stack([bi.int_to_limbs(t[0][0], 16) for t in triples]).astype(np.int32)
+    # byte columns, as secp._Batch hands them over: the backend (pallas or
+    # XLA) derives its own layout — the e2e path includes that marshalling
+    px = [t[0][0].to_bytes(32, "big") for t in triples]
     # lifted pubkey (even y): negate odd-y points host-side like secp.py does
-    py = np.stack(
-        [
-            bi.int_to_limbs(t[0][1] if t[0][1] % 2 == 0 else eclib.P - t[0][1], 16)
-            for t in triples
-        ]
-    ).astype(np.int32)
-    rc = np.stack([bi.int_to_limbs(int.from_bytes(s[:32], "big"), 16) for s in sigs]).astype(np.int32)
-    # scalars stay python ints: the backend (pallas or XLA) derives its own
-    # window-digit layout — the e2e path includes that host marshalling
+    py = [(t[0][1] if t[0][1] % 2 == 0 else eclib.P - t[0][1]).to_bytes(32, "big") for t in triples]
+    rc = [s[:32] for s in sigs]
     s_ints = [int.from_bytes(s[32:], "big") % eclib.N for s in sigs]
     e_ints = [schnorr_challenge(s[:32], t[1], t[2]) for s, t in zip(sigs, triples)]
     # host-side encoding validity: r must be a canonical field element and

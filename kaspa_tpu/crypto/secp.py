@@ -21,9 +21,8 @@ import numpy as np
 from kaspa_tpu.crypto import eclib
 from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import PERCENT_BUCKETS, REGISTRY, SIZE_BUCKETS
-from kaspa_tpu.ops import bigint as bi
 from kaspa_tpu.ops.secp256k1 import points as pt
-from kaspa_tpu.ops.secp256k1.verify import _scalars_to_digits, ecdsa_verify, schnorr_verify
+from kaspa_tpu.ops.secp256k1.verify import _be32_to_limbs, _scalars_to_digits, ecdsa_verify, schnorr_verify
 from kaspa_tpu.resilience import supervisor
 from kaspa_tpu.resilience.breaker import HUNG, device_breaker
 from kaspa_tpu.resilience.faults import FAULTS
@@ -111,7 +110,6 @@ _DEGRADED_JOBS = REGISTRY.counter("secp_degraded_jobs", help="verify jobs execut
 # ends in exactly one of secp_device_jobs / secp_degraded_jobs
 _DEVICE_JOBS = REGISTRY.counter("secp_device_jobs", help="verify jobs answered by the device lane")
 
-W = bi.FP.W
 _CHALLENGE_MID = hashlib.sha256(
     hashlib.sha256(b"BIP0340/challenge").digest() * 2
 )  # pre-tagged sha256 state
@@ -134,22 +132,15 @@ def schnorr_challenge(r32: bytes, px32: bytes, msg32: bytes) -> int:
 _ZERO32 = b"\x00" * 32
 
 
-def _be32_to_limbs(col, b):
-    """[N x 32-byte big-endian] -> [bucket, 16] int32 LE 16-bit limbs (vectorised)."""
-    out = np.zeros((b, W), np.int32)
-    if col:
-        arr = np.frombuffer(b"".join(col), dtype=np.uint8).reshape(len(col), 32)
-        out[: len(col)] = arr[:, ::-1].copy().view("<u2").astype(np.int32)
-    return out
-
-
 @dataclass
 class _Batch:
-    """Marshals verification jobs into the device batch layout.
+    """Collects verification jobs as byte columns and scalars, one entry a
+    job, and hands them to the kernel entry at a bucket width.
 
-    The host-side "pinned buffer" packing is numpy-vectorised: 32-byte
-    big-endian field elements -> int32 limb / window-digit arrays without
-    per-item python loops (the host half of the FFI batch boundary).
+    What layout the device wants is the entry's business
+    (ops/secp256k1/verify.py marshals for the lane it takes: one packed
+    byte array for the fused ladder, limb / window-digit arrays for the
+    XLA and mesh ladders), numpy-vectorised there without per-item loops.
     """
 
     px: list = field(default_factory=list)  # 32B BE x-coordinates
@@ -193,18 +184,10 @@ class _Batch:
         if new_shape:
             _seen_shapes.add(shape_key)
             _NEW_SHAPES.inc(kernel.__name__)
-        with trace.span("secp.host_marshal", kernel=kernel.__name__, batch=n, lanes=b):
-            ok = np.zeros(b, dtype=bool)
-            ok[:n] = self.ok
-            pad = [0] * (b - n)
-            args = (
-                _be32_to_limbs(self.px, b),
-                _be32_to_limbs(self.py, b),
-                _be32_to_limbs(self.rc, b),
-                self.d1 + pad,
-                self.d2 + pad,
-                ok,
-            )
+        # the flags carry the bucket width; the columns stay one entry a job
+        ok = np.zeros(b, dtype=bool)
+        ok[:n] = self.ok
+        args = (self.px, self.py, self.rc, self.d1, self.d2, ok)
         if new_shape:
             # first dispatch of a (kernel, bucket) shape pays the XLA
             # trace+compile; surfacing it as a span is what lets a wedge

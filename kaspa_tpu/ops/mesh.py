@@ -453,7 +453,7 @@ def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np
         _pad_rows(np.asarray(valid_in, dtype=bool), m),
     )
     kernel = f"{kind}_mesh"
-    with trace.span("secp.device_call", kernel=kernel, lanes=m):
+    with trace.span("secp.device_call", kernel=kernel, lanes=m, bytes=sum(a.nbytes for a in args)):
         out = entry(*args)
         out.copy_to_host_async()  # queued behind the kernel, as np.asarray alone would
         jax.block_until_ready(out)

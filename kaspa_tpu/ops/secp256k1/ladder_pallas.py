@@ -21,6 +21,13 @@ Layout choices, dictated by TPU tiling:
 - Complete Renes-Costello-Batina point formulas (same as points.py) — no
   data-dependent branches, which is exactly what Mosaic wants.
 
+What crosses the host/device boundary on the 64-window ladder: one
+``uint8 [LANE_BYTES, lanes]`` array up (per lane the five 32-byte big-endian
+fields as the wire has them and the valid byte; `pack_lanes`), one ``[lanes]``
+mask down.  The G tables and the moduli are constants of the compiled
+program; radix-2**8 limbs, window digits and the valid rows are laid out
+inside the jit, ahead of the kernel (`unpack_lanes`).
+
 Replaces the hot loop of libsecp256k1 batch verification used by the
 reference's parallel script checks
 (consensus/src/processes/transaction_validator/tx_validation_in_utxo_context.rs:206-223,
@@ -614,6 +621,41 @@ def _verify_kernel_plain(
     out_ref[:] = jnp.broadcast_to(ok.astype(jnp.int32), (8, lanes))
 
 
+# one lane of the array a plain call takes: px | py | rc | k1 | k2, each the
+# 32 big-endian bytes the wire has, then the valid byte
+LANE_BYTES = 5 * 32 + 1
+
+
+def pack_lanes(px, py, rc, k1, k2, valid_in, lanes: int) -> np.ndarray:
+    """Host: a batch's byte columns -> the one ``uint8 [LANE_BYTES, lanes]``
+    array a plain call takes (the lane axis last, as the kernel has it).
+
+    px/py/rc: n 32-byte big-endian strings each; k1/k2: n scalars, python
+    ints or canonical 32-byte strings (the schnorr s column's wire form);
+    valid_in: at least n flags.  Lanes from n on stay zero, valid byte too.
+    """
+    n = len(px)
+    buf = np.zeros((LANE_BYTES, lanes), np.uint8)
+    if n:
+        raw = b"".join(
+            [*px, *py, *rc, *(k if type(k) is bytes else k.to_bytes(32, "big") for ks in (k1, k2) for k in ks)]
+        )
+        buf[:-1].reshape(5, 32, lanes)[:, :, :n] = np.frombuffer(raw, np.uint8).reshape(5, n, 32).transpose(0, 2, 1)
+        buf[-1, :n] = valid_in[:n]
+    return buf
+
+
+def unpack_lanes(packed):
+    """Device (traced inside the jit): ``uint8 [LANE_BYTES, n]`` -> the
+    kernel's six operands: px/py/rc as [32, n] radix-2**8 limbs (LSB
+    first), k1/k2 as [64, n] MSB-first 4-bit digits, valid as [8, n]."""
+    n = packed.shape[1]
+    x = packed.astype(jnp.int32)
+    px, py, rc, k1, k2 = (x[32 * i : 32 * i + 32] for i in range(5))
+    d1, d2 = (jnp.stack([k >> 4, k & 0x0F], axis=1).reshape(64, n) for k in (k1, k2))
+    return px[::-1], py[::-1], rc[::-1], d1, d2, jnp.broadcast_to(x[-1:], (8, n))
+
+
 @functools.lru_cache(maxsize=None)
 def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
     grid = n_padded // BLK
@@ -649,27 +691,12 @@ def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
         interpret=interpret,
         name=_kernel_name(ecdsa, glv=False),
     )
-    jitted = jax.jit(call)
 
-    def run(px8, py8, rc8, sd, ed, vin):
-        return jitted(
-            jnp.asarray(_GTAB8_X), jnp.asarray(_GTAB8_Y), jnp.asarray(_MP8),
-            jnp.asarray(_MN8), px8, py8, rc8, sd, ed, vin,
-        )
+    @jax.jit
+    def run(packed):
+        return call(_GTAB8_X, _GTAB8_Y, _MP8, _MN8, *unpack_lanes(packed))[0]
 
     return run
-
-
-def _full_digits(scalars) -> np.ndarray:
-    """Host: scalars (ints, or canonical 32-byte BE strings — the schnorr
-    s column's wire form) -> [64, B] MSB-first 4-bit digits (transposed)."""
-    b = len(scalars)
-    raw = b"".join([k if type(k) is bytes else int(k).to_bytes(32, "big") for k in scalars])
-    arr = np.frombuffer(raw, dtype=np.uint8).reshape(b, 32)
-    dig = np.empty((b, 64), np.uint8)
-    dig[:, 0::2] = arr >> 4
-    dig[:, 1::2] = arr & 0x0F
-    return dig.astype(np.int32).T.copy()
 
 
 @functools.lru_cache(maxsize=None)
@@ -713,25 +740,20 @@ def _build_call(n_padded: int, ecdsa: bool, interpret: bool):
         interpret=interpret,
         name=_kernel_name(ecdsa, glv=True),
     )
-    jitted = jax.jit(call)
 
+    @jax.jit
     def run(px8, py8, rc8, g1, g2, p1, p2, sgn, vin):
-        return jitted(
-            jnp.asarray(_GTAB8_X), jnp.asarray(_GTAB8_XB), jnp.asarray(_GTAB8_Y),
-            jnp.asarray(_MP8), jnp.asarray(_MN8), jnp.asarray(_BETA8),
+        return call(
+            _GTAB8_X, _GTAB8_XB, _GTAB8_Y, _MP8, _MN8, _BETA8,
             px8, py8, rc8, g1, g2, p1, p2, sgn, vin,
-        )
+        )[0]
 
     return run
 
 
-def _to_radix8_T(limbs16: np.ndarray) -> np.ndarray:
-    """Host: [B, 16] canonical 2**16-radix limbs -> [32, B] radix-2**8."""
-    a = np.asarray(limbs16, dtype=np.int32)
-    out = np.empty((W8, a.shape[0]), dtype=np.int32)
-    out[0::2] = (a & 0xFF).T
-    out[1::2] = (a >> 8).T
-    return out
+def _radix8_T(col) -> np.ndarray:
+    """Host: B 32-byte big-endian strings -> [32, B] radix-2**8 limbs, LSB first."""
+    return np.frombuffer(b"".join(col), np.uint8).reshape(len(col), 32)[:, ::-1].T.astype(np.int32)
 
 
 def _pad_lanes(x: np.ndarray, n: int) -> np.ndarray:
@@ -763,50 +785,46 @@ def _glv_digits(scalars) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     return digs[:, 0].T.copy(), digs[:, 1].T.copy(), signs
 
 
-def verify_batch_pallas(px, py, r_canon, s_scalars, e_scalars, valid_in, *, ecdsa: bool, interpret: bool = False, glv: bool | None = None):
+def verify_batch_pallas(px, py, rc, k1, k2, valid_in, *, ecdsa: bool, interpret: bool = False, glv: bool | None = None):
     """Fused-Pallas batched verification.
 
-    px/py/r_canon: [B, 16] canonical 2**16-radix limb arrays (same host
-    marshalling as the XLA kernels); s_scalars/e_scalars: python-int scalars
-    (s/e for Schnorr, u1/u2 for ECDSA); valid_in: [B] bool.  -> [B] bool.
+    px/py/rc: the batch's 32-byte big-endian columns, one string a job (rc
+    the canonical target: r, or r mod n for ECDSA); k1/k2: one scalar a job,
+    python ints or canonical 32-byte strings (s/e for Schnorr, u1/u2 for
+    ECDSA); valid_in: [B] bool, B >= jobs the width the batch is counted at
+    (lanes from the last job on are padding).
+    -> ([B] bool mask, the number of host arrays handed to the device).
 
-    Two kernels: the 64-window dual-scalar ladder (default) and the GLV
-    quad-stream 33-window ladder (opt-in via KASPA_TPU_GLV=1 or glv=True
-    until it has run on a TPU).
+    Two kernels: the 64-window dual-scalar ladder (default: one packed
+    array up, `pack_lanes`) and the GLV quad-stream 33-window ladder (opt-in
+    via KASPA_TPU_GLV=1 or glv=True until it has run on a TPU; its signed
+    digit split is host bigint work, so it still takes nine arrays).
     """
     import os
 
     if glv is None:
         glv = bool(os.environ.get("KASPA_TPU_GLV"))
-    b = np.asarray(px).shape[0]
+    b = len(valid_in)
     n = launched_lanes(b)
     kernel = ("ecdsa" if ecdsa else "schnorr") + ("_pallas_glv" if glv else "_pallas")
     with trace.span("secp.host_marshal", kernel=kernel, batch=b, lanes=n):
-        px8 = _pad_lanes(_to_radix8_T(px), n)
-        py8 = _pad_lanes(_to_radix8_T(py), n)
-        rc8 = _pad_lanes(_to_radix8_T(r_canon), n)
-        vin = _pad_lanes(np.broadcast_to(np.asarray(valid_in, dtype=np.int32), (8, b)).copy(), n)
         if glv:
-            g1, g2, gs = _glv_digits(s_scalars)
-            p1, p2, ps = _glv_digits(e_scalars)
-            sgn = np.broadcast_to((gs | (ps << 2)).astype(np.int32), (8, b)).copy()
-            args = (
-                px8, py8, rc8,
-                _pad_lanes(g1, n), _pad_lanes(g2, n),
-                _pad_lanes(p1, n), _pad_lanes(p2, n),
-                _pad_lanes(sgn, n), vin,
+            g1, g2, gs = _glv_digits(k1)
+            p1, p2, ps = _glv_digits(k2)
+            rows8 = lambda v: np.broadcast_to(np.asarray(v, dtype=np.int32), (8, len(v)))  # noqa: E731
+            args = tuple(
+                _pad_lanes(a, n)
+                for a in (_radix8_T(px), _radix8_T(py), _radix8_T(rc), g1, g2, p1, p2, rows8(gs | (ps << 2)), rows8(valid_in))
             )
         else:
-            sd = _pad_lanes(_full_digits(s_scalars), n)
-            ed = _pad_lanes(_full_digits(e_scalars), n)
-            args = (px8, py8, rc8, sd, ed, vin)
+            args = (pack_lanes(px, py, rc, k1, k2, valid_in, n),)
     # transfer in, launch and the kernel itself, to the ready output; the
     # copy back is queued behind the kernel at once, as a bare np.asarray
     # would queue it, so splitting the wait costs no extra round trip
-    with trace.span("secp.device_call", kernel=kernel, lanes=n):
+    with trace.span("secp.device_call", kernel=kernel, lanes=n, bytes=sum(a.nbytes for a in args)):
         call = (_build_call if glv else _build_call_plain)(n, ecdsa, interpret)
         out = call(*args)
         out.copy_to_host_async()
         jax.block_until_ready(out)
     with trace.span("secp.readback", kernel=kernel):
-        return np.asarray(out)[0, :b].astype(bool)
+        return np.asarray(out)[:b].astype(bool), len(args)

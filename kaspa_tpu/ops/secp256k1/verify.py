@@ -1,10 +1,16 @@
 """Jitted batched Schnorr/ECDSA verification kernels (device side).
 
 The host (kaspa_tpu/crypto/secp.py) parses/validates encodings, lifts
-pubkeys to affine coordinates, computes challenge scalars, and extracts
-4-bit window digits; the device does the heavy dual-scalar ladder and the
-final affine checks, returning a validity bitmask — the layout prescribed
-by the north star (BASELINE.json): triples in, bitmask out.
+pubkeys to affine coordinates and computes challenge scalars, and hands the
+batch over as byte columns; the device does the heavy dual-scalar ladder and
+the final affine checks, returning a validity bitmask — the layout
+prescribed by the north star (BASELINE.json): triples in, bitmask out.
+
+`_verify` marshals for the lane it takes.  The fused Pallas ladder (a TPU,
+mesh 1) gets the columns as one packed byte array and lays limbs and window
+digits out on the device (ladder_pallas.pack_lanes / unpack_lanes).  The XLA
+ladder (CPU, KASPA_TPU_NO_PALLAS) and the mesh ladder get `marshal_limbs`:
+[B, 16] 2**16-radix limbs and [B, 64] 4-bit window digits built on the host.
 """
 
 from __future__ import annotations
@@ -69,6 +75,15 @@ def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _be32_to_limbs(col, b):
+    """[N x 32-byte big-endian] -> [bucket, 16] int32 LE 16-bit limbs (vectorised)."""
+    out = np.zeros((b, FP.W), np.int32)
+    if col:
+        arr = np.frombuffer(b"".join(col), dtype=np.uint8).reshape(len(col), 32)
+        out[: len(col)] = arr[:, ::-1].copy().view("<u2").astype(np.int32)
+    return out
+
+
 def _scalars_to_digits(ks, b: int) -> np.ndarray:
     """Host: scalars -> [b, 64] MSB-first 4-bit window digits (padded).
 
@@ -93,6 +108,20 @@ def _scalars_to_digits(ks, b: int) -> np.ndarray:
     return out
 
 
+def marshal_limbs(px, py, rc, k1, k2, valid_in) -> tuple:
+    """Host marshal of the XLA and mesh ladders: byte columns and scalars of
+    the jobs -> the kernels' six arrays at the width of ``valid_in``."""
+    b = len(valid_in)
+    return (
+        _be32_to_limbs(px, b),
+        _be32_to_limbs(py, b),
+        _be32_to_limbs(rc, b),
+        _scalars_to_digits(k1, b),
+        _scalars_to_digits(k2, b),
+        valid_in,
+    )
+
+
 # what the device actually answered: a batch counts here only after its
 # mask came back, so "the chip did the work" is readable from the registry
 # (the degraded host lane in crypto/secp.py never passes through here)
@@ -110,13 +139,20 @@ _DEVICE_BUCKETS = REGISTRY.counter_family(
 _DEVICE_LANES = REGISTRY.counter(
     "secp_device_lanes", help="lanes of the verify programs the device ran (bucket padded on to the launched width)"
 )
+# 1 a call on the fused Pallas ladder (the packed lanes), 6 on the XLA and
+# mesh ladders: over secp_device_dispatches it says which marshal engaged
+_DEVICE_UPLOADS = REGISTRY.counter(
+    "secp_device_uploads", help="host arrays handed to the device by verify calls it answered"
+)
 
 
 def _verify(kind: str, px, py, rc, k1, k2, valid_in) -> np.ndarray:
     """Backend-dispatching batched verify shared by both signature kinds.
 
-    px/py/rc: [B, 16] limb arrays; k1/k2: scalar sequences already reduced
-    mod n (s/e for Schnorr, u1/u2 for ECDSA); valid_in: [B] bool.
+    px/py/rc: the jobs' 32-byte big-endian columns (one string a job); k1/k2:
+    one scalar a job, already reduced mod n (s/e for Schnorr, u1/u2 for
+    ECDSA; python ints or canonical 32-byte strings); valid_in: [B] bool at
+    the bucket width B >= jobs.  -> [B] bool.
     """
     # raise/wedge/slow the whole batch here — above every backend path, so
     # the breaker in crypto/secp.py sees the failure whichever way it routes
@@ -128,34 +164,34 @@ def _verify(kind: str, px, py, rc, k1, k2, valid_in) -> np.ndarray:
     from kaspa_tpu.ops import mesh
 
     n_mesh = mesh.active_size()
-    b = np.asarray(px).shape[0]
+    b = len(valid_in)
     if n_mesh == 1 and _use_pallas():
         from kaspa_tpu.ops.secp256k1.ladder_pallas import launched_lanes, verify_batch_pallas
 
         kernel = f"{kind}_pallas"
         lanes = launched_lanes(b)
         with trace.span("secp.device_dispatch", kernel=kernel, batch=b):
-            mask = verify_batch_pallas(px, py, rc, k1, k2, valid_in, ecdsa=kind == "ecdsa")
+            mask, uploads = verify_batch_pallas(px, py, rc, k1, k2, valid_in, ecdsa=kind == "ecdsa")
     else:
         # host marshal vs device dispatch split: when throughput collapses,
         # this localizes the stall to python packing or the XLA round trip
         with trace.span("secp.host_marshal", kernel=kind, batch=b, lanes=b):
-            d1 = _scalars_to_digits(k1, b)
-            d2 = _scalars_to_digits(k2, b)
+            args = marshal_limbs(px, py, rc, k1, k2, valid_in)
+        uploads = len(args)
         if n_mesh > 1:
             # mesh > 1 rides the portable XLA formulation sharded over the
             # device mesh (the fused Mosaic ladder stays the single-chip path)
             kernel = f"{kind}_mesh"
             lanes = mesh.padded_lanes(b)
             with trace.span("secp.device_dispatch", kernel=kernel, batch=b, mesh=n_mesh):
-                mask = mesh.dispatch_verify(kind, px, py, rc, d1, d2, valid_in)
+                mask = mesh.dispatch_verify(kind, *args)
         else:
             kernel = kind
             lanes = b
             xla_kernel = schnorr_verify_kernel if kind == "schnorr" else ecdsa_verify_kernel
             with trace.span("secp.device_dispatch", kernel=kernel, batch=b):
-                with trace.span("secp.device_call", kernel=kernel, lanes=lanes):
-                    out = xla_kernel(px, py, rc, d1, d2, valid_in)
+                with trace.span("secp.device_call", kernel=kernel, lanes=lanes, bytes=sum(a.nbytes for a in args)):
+                    out = xla_kernel(*args)
                     out.copy_to_host_async()  # queued behind the kernel, as np.asarray alone would
                     jax.block_until_ready(out)
                 with trace.span("secp.readback", kernel=kernel):
@@ -163,16 +199,17 @@ def _verify(kind: str, px, py, rc, k1, k2, valid_in) -> np.ndarray:
     _DEVICE_DISPATCHES.inc(kernel)
     _DEVICE_BUCKETS.inc(str(b))
     _DEVICE_LANES.inc(lanes)
+    _DEVICE_UPLOADS.inc(uploads)
     return mask
 
 
 def schnorr_verify(px, py, r_canon, s_scalars, e_scalars, valid_in) -> np.ndarray:
-    """Batched Schnorr verify (see _verify): s/e scalars, r canonical mod p."""
+    """Batched Schnorr verify (see _verify): byte columns, s/e scalars, r canonical mod p."""
     return _verify("schnorr", px, py, r_canon, s_scalars, e_scalars, valid_in)
 
 
 def ecdsa_verify(px, py, r_n_canon, u1_scalars, u2_scalars, valid_in) -> np.ndarray:
-    """Batched ECDSA verify (see _verify): u1/u2 scalars, r canonical mod n."""
+    """Batched ECDSA verify (see _verify): byte columns, u1/u2 scalars, r canonical mod n."""
     return _verify("ecdsa", px, py, r_n_canon, u1_scalars, u2_scalars, valid_in)
 
 

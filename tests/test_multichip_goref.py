@@ -56,18 +56,15 @@ def test_goref_block_batch_sharded_over_mesh(monkeypatch):
     mesh = Mesh(devices, axis_names=("batch",))
 
     def sharded_verify(px, py, rc, s_scalars, e_scalars, valid_in):
-        b = np.asarray(px).shape[0]
-        assert b % 8 == 0  # secp buckets are powers of two >= 8
-        from kaspa_tpu.ops.secp256k1.verify import _scalars_to_digits
+        assert len(valid_in) % 8 == 0  # secp buckets are powers of two >= 8
+        from kaspa_tpu.ops.secp256k1.verify import marshal_limbs
 
-        sdig = _scalars_to_digits(s_scalars, b)
-        edig = _scalars_to_digits(e_scalars, b)
         lane = NamedSharding(mesh, P("batch", None))
         flat = NamedSharding(mesh, P("batch"))
         args = [
             jax.device_put(np.asarray(a), s)
             for a, s in zip(
-                (px, py, rc, sdig, edig, np.asarray(valid_in)),
+                marshal_limbs(px, py, rc, s_scalars, e_scalars, valid_in),
                 (lane, lane, lane, lane, lane, flat),
             )
         ]

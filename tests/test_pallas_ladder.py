@@ -15,7 +15,6 @@ import pytest
 
 from kaspa_tpu.crypto import eclib
 from kaspa_tpu.crypto.secp import schnorr_challenge
-from kaspa_tpu.ops import bigint as bi
 from kaspa_tpu.ops.secp256k1 import points as pt
 from kaspa_tpu.ops.secp256k1.ladder_pallas import verify_batch_pallas
 
@@ -31,8 +30,9 @@ def keys():
     return sk
 
 
-def _limbs(vals):
-    return np.stack([bi.int_to_limbs(v, 16) for v in vals]).astype(np.int32)
+def _col(vals):
+    """The byte column secp._Batch holds: one 32-byte big-endian string a job."""
+    return [v.to_bytes(32, "big") for v in vals]
 
 
 @pytest.mark.parametrize("glv", [False, True])
@@ -50,17 +50,17 @@ def test_schnorr_pallas_interpret(keys, glv):
     sigs[6] = bytes([sigs[6][0] ^ 1]) + sigs[6][1:]
     expect[6] = False
 
-    px = _limbs([p[0] for p in pks])
-    py = _limbs([p[1] for p in pks])
-    rc = _limbs([int.from_bytes(s[:32], "big") for s in sigs])
+    px = _col([p[0] for p in pks])
+    py = _col([p[1] for p in pks])
+    rc = _col([int.from_bytes(s[:32], "big") for s in sigs])
     sd = [int.from_bytes(s[32:], "big") for s in sigs]
     ed = [schnorr_challenge(s[:32], pubs[i], msgs[i]) for i, s in enumerate(sigs)]
     ok = np.ones(B, dtype=bool)
     ok[3] = False  # host-side encoding rejection must mask through
     expect[3] = False
 
-    mask = verify_batch_pallas(px, py, rc, sd, ed, ok, ecdsa=False, interpret=True, glv=glv)
-    assert mask.tolist() == expect
+    mask, uploads = verify_batch_pallas(px, py, rc, sd, ed, ok, ecdsa=False, interpret=True, glv=glv)
+    assert mask.tolist() == expect and uploads == (9 if glv else 1)
 
     # oracle cross-check on the uncorrupted lanes
     for i in (0, 2, 4, 7):
@@ -84,13 +84,13 @@ def test_ecdsa_pallas_interpret(keys):
         u1.append(z * si % eclib.N)
         u2.append(r * si % eclib.N)
 
-    px = _limbs([p[0] for p in pks])
-    py = _limbs([p[1] for p in pks])
-    rn = _limbs([r % eclib.N for r, _ in rs])
+    px = _col([p[0] for p in pks])
+    py = _col([p[1] for p in pks])
+    rn = _col([r % eclib.N for r, _ in rs])
     ok = np.ones(B, dtype=bool)
 
-    mask = verify_batch_pallas(px, py, rn, u1, u2, ok, ecdsa=True, interpret=True)
-    assert mask.tolist() == expect
+    mask, uploads = verify_batch_pallas(px, py, rn, u1, u2, ok, ecdsa=True, interpret=True)
+    assert mask.tolist() == expect and uploads == 1
 
 
 def test_glv_split_identity():

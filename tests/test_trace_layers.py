@@ -88,12 +88,13 @@ def test_device_call_and_readback_once_per_device_dispatch(replay):
         inner = [s for s in calls + reads if s["thread"] == d["thread"] and _inside(s, d)]
         assert sorted(s["name"] for s in inner) == ["secp.device_call", "secp.readback"]
         call = next(s for s in inner if s["name"] == "secp.device_call")
-        assert call["attrs"] == {"kernel": "schnorr", "lanes": d["attrs"]["batch"]}
-    # two marshal phases a batch: _Batch.run's limb packing (outside the
-    # dispatch span) and the digit layout of the lane that runs
-    assert len(marshals) == 2 * len(dispatches)
-    assert all({"kernel", "batch", "lanes"} <= set(m["attrs"]) for m in marshals)
-    assert not any(_inside(m, d) for m in marshals if m["attrs"]["kernel"] == "schnorr_verify" for d in dispatches)
+        b = d["attrs"]["batch"]
+        # three [b,16] limb planes, two [b,64] digit planes (int32), b flags
+        assert call["attrs"] == {"kernel": "schnorr", "lanes": b, "bytes": (3 * 16 + 2 * 64) * 4 * b + b}
+    # one marshal a batch, by the lane that runs it: _Batch.run hands its
+    # byte columns over and packs nothing itself
+    assert len(marshals) == len(dispatches)
+    assert all(m["attrs"]["kernel"] == "schnorr" and {"batch", "lanes"} <= set(m["attrs"]) for m in marshals)
 
 
 def test_wait_dispatch_names_what_flushed_the_queue(replay):

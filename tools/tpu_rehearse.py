@@ -50,24 +50,23 @@ def _shape(shape, dtype, sharding):
 
 def pallas_ladder(topo, kind: str, n_padded: int, glv: bool = False):
     """Lower + compile the fused Mosaic ladder for one described chip."""
-    import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from kaspa_tpu.ops.secp256k1 import ladder_pallas as lp
 
     chip = SingleDeviceSharding(topo.devices[0])
-    i32 = lambda *s: _shape(s, jnp.int32, chip)  # noqa: E731
-    limbs, valid = i32(lp.W8, n_padded), i32(8, n_padded)
     if glv:
         run = lp._build_call(n_padded, kind == "ecdsa", False)
-        dig = i32(lp.N_WIN, n_padded)
+        i32 = lambda *s: _shape(s, jnp.int32, chip)  # noqa: E731
+        limbs, dig, valid = i32(lp.W8, n_padded), i32(lp.N_WIN, n_padded), i32(8, n_padded)
         args = (limbs, limbs, limbs, dig, dig, dig, dig, valid, valid)
     else:
+        # the one packed byte array of a plain call; limbs and digits are
+        # laid out inside the program (ladder_pallas.unpack_lanes)
         run = lp._build_call_plain(n_padded, kind == "ecdsa", False)
-        dig = i32(64, n_padded)
-        args = (limbs, limbs, limbs, dig, dig, valid)
-    return jax.jit(run).lower(*args).compile()
+        args = (_shape((lp.LANE_BYTES, n_padded), jnp.uint8, chip),)
+    return run.lower(*args).compile()
 
 
 def muhash_tree(topo, bucket: int):
