@@ -1,6 +1,6 @@
 """The benchmark's DAG generator: ``kaspa_tpu.sim.simulator.simulate`` copied
-with four named edits (ISSUE 25), so that blocks fill to a cell's
-``tx_per_block`` and a few-thousand-block DAG builds inside set-up.
+with six named edits (ISSUE 25; the fifth and sixth ISSUE 28), so that blocks fill to
+a cell's ``tx_per_block`` and a few-thousand-block DAG builds inside set-up.
 
 1. Signer: per miner a fixed key and a running nonce point (``k += 1``,
    ``R += G``): a valid BIP340 signature costs one point addition, not a
@@ -17,6 +17,28 @@ with four named edits (ISSUE 25), so that blocks fill to a cell's
    by honest blocks, its spoiled spend is never accepted and the DAG goes on.
 4. A tip frontier per miner and an output pool per miner replace the scans
    over every mined block and the whole UTXO view.
+5. ``own_blocks_delayed`` (a key of the configuration's ``network``; absent =
+   false): a miner's own block reaches it after ``delay`` like anyone else's,
+   as in simpa's network, where one aggregated miner therefore builds a DAG
+   about ``delay * bps`` blocks wide.  Without it a miner sees its own block
+   at once and one miner builds a chain.  The rule holds from the moment the
+   shape is steady: the fan-out before it stays a chain, because a coinbase
+   output exists on one selected chain only and the wide DAG's tips follow
+   some 16 interleaved chains that do not meet for hundreds of blocks (with
+   the rule on from genesis over half of the pool descended from coinbases
+   the final chain never had, and was refused).  The blocks of the first two
+   delays after the switch, while the DAG widens, count as ramp.  The rule
+   draws nothing from the ``rng``: with the key absent every seed's DAG is
+   what it was.
+6. ``gap_stratum_blocks`` (a key of the traffic file; absent = 0 = every gap
+   drawn from the ``rng`` as before): each miner's mining gaps come in strata
+   of that many: the stratum's exponential quantiles, scaled to span exactly
+   the time the rate gives them, in an order shuffled by the seed.  Every
+   seed then has the same set of arrivals in another order, and with a
+   stratum of ``delay * bps`` blocks every delay holds about as many blocks:
+   the width of ``own_blocks_delayed``'s DAG no longer follows the seed
+   (drawn freely, mean parents read 12.7-15.7 over a 150-block window and the
+   rate followed them).  The shuffle has an ``rng`` of its own.
 
 The consensus the DAG is built against runs in order with coalescing off: the
 build *is* the in-order run of the program, and records the sink after every
@@ -28,6 +50,7 @@ from __future__ import annotations
 
 import heapq
 import importlib
+import math
 import random
 import time
 from collections import deque
@@ -52,6 +75,8 @@ class DagSpec:
     pool_factor: int = 3
     sig_samples: int = 24
     coinbase_maturity: int | None = None  # None: what simnet_params gives
+    own_blocks_delayed: bool = False  # simpa's network: a miner's own block reaches it after `delay` too
+    gap_stratum_blocks: int = 0  # > 0: mining gaps are seed-shuffled strata of that many exponential quantiles
 
 
 @dataclass
@@ -156,6 +181,26 @@ class MassBudget:
         return True
 
 
+def gap_source(spec: DagSpec, rng: random.Random, lam: float, midx: int):
+    """The next mining gap of one miner, as a function.  Without strata: the
+    shared ``rng``'s next exponential draw, as ``simulate()`` has it."""
+    n = spec.gap_stratum_blocks
+    if not n:
+        return lambda: rng.expovariate(lam)
+    quantiles = [-math.log(1.0 - (j + 0.5) / n) for j in range(n)]
+    scale = n / (lam * sum(quantiles))  # a stratum spans exactly n / lam seconds
+    order = random.Random((spec.seed ^ 0x6A95) + midx)
+    pending: list = []
+
+    def draw() -> float:
+        if not pending:
+            pending.extend(q * scale for q in quantiles)
+            order.shuffle(pending)
+        return pending.pop()
+
+    return draw
+
+
 def build(spec: DagSpec, log=None) -> Dag:
     """Build the DAG against one authoritative in-order consensus."""
     from kaspa_tpu.consensus.consensus import Consensus
@@ -177,8 +222,9 @@ def build(spec: DagSpec, log=None) -> Dag:
     events = []
     seq = 0
     lam = spec.bps / spec.miners
+    gaps = [gap_source(spec, rng, lam, m.idx) for m in miners]
     for m in miners:
-        events.append((rng.expovariate(lam), seq, m.idx))
+        events.append((gaps[m.idx](), seq, m.idx))
         seq += 1
     heapq.heapify(events)
 
@@ -192,6 +238,7 @@ def build(spec: DagSpec, log=None) -> Dag:
     window_start = 0
     spoil_at: list = []  # window positions still to spoil, ascending
     total_txs = discarded = stalled = 0
+    wide_since = None  # own_blocks_delayed: the mining time from which the rule has held without a break
 
     while len(blocks) - window_start < spec.window_blocks:
         if len(blocks) - spec.window_blocks > MAX_RAMP_BLOCKS:
@@ -200,11 +247,17 @@ def build(spec: DagSpec, log=None) -> Dag:
             )
         vtime, _, midx = heapq.heappop(events)
         miner = miners[midx]
-        # blocks of other miners propagate after `delay`; mining times only
-        # grow, so one index per miner walks the list once
+        delayed = spec.own_blocks_delayed and shape.steady
+        if not delayed:
+            wide_since = None
+        elif wide_since is None:
+            wide_since = vtime
+        # blocks propagate after `delay` (a miner's own only where the
+        # deployment says so); mining times only grow, so one index per miner
+        # walks the list once
         i = arrival[midx]
         while i < len(blocks) and vtimes[i] + spec.delay <= vtime:
-            if owners[i] != midx:
+            if delayed or owners[i] != midx:
                 miner.learn(blocks[i].hash, parent_lists[i])
             i += 1
         arrival[midx] = i
@@ -244,7 +297,7 @@ def build(spec: DagSpec, log=None) -> Dag:
             stalled += 1
             if stalled > 200:
                 raise RuntimeError(f"{stalled} blocks in a row could not be filled at block {len(blocks)}")
-            heapq.heappush(events, (vtime + rng.expovariate(lam), seq, midx))
+            heapq.heappush(events, (vtime + gaps[midx](), seq, midx))
             seq += 1
             continue
         status = consensus.validate_and_insert_block(block)
@@ -259,7 +312,9 @@ def build(spec: DagSpec, log=None) -> Dag:
                 spoiled[h] = {"txid": tx.id(), "cls": cls, "index": len(blocks)}
                 spoiled_hashes.add(h)
         shape.mined(miner, block, made, len(blocks))
-        steady_block = shape.is_window_block(block, made)
+        steady_block = shape.is_window_block(block, made) and (
+            not spec.own_blocks_delayed or (wide_since is not None and vtime >= wide_since + 2 * spec.delay)
+        )
         if not steady_block:
             window_start = len(blocks) + 1
             # spoiled blocks sit at seeded places in the first quarter of the
@@ -277,8 +332,9 @@ def build(spec: DagSpec, log=None) -> Dag:
         miner.prev_spoiled = spoil_cls is not None
         if spoil_cls is None:
             miner.parent_history.append(tuple(parents))
-        miner.learn(h, parents)  # a miner sees its own block at once
-        heapq.heappush(events, (vtime + rng.expovariate(lam), seq, midx))
+        if not delayed:
+            miner.learn(h, parents)  # a miner sees its own block at once
+        heapq.heappush(events, (vtime + gaps[midx](), seq, midx))
         seq += 1
         if log is not None and len(blocks) % 100 == 0:
             log(f"dag: {len(blocks)} blocks, window from {window_start}, pools {[len(m.pool) for m in miners]}, {time.perf_counter() - t_start:.1f} s")
@@ -299,6 +355,8 @@ def build(spec: DagSpec, log=None) -> Dag:
         "widest_block_txs": max(len(b.transactions) - 1 for b in blocks),
         "spoiled_blocks": sorted(s["index"] for s in spoiled.values()),
         "window_vtime_s": vtimes[-1] - vtimes[window_start],
+        "miners": spec.miners,
+        "mean_window_parents": sum(len(p) for p in parent_lists[window_start:]) / len(window),
         "ghostdag_k": params.ghostdag_k,
         "max_block_parents": params.max_block_parents,
         "mergeset_size_limit": params.mergeset_size_limit,

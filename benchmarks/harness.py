@@ -114,7 +114,8 @@ def build_dag(workload: dict, config: dict, seed: int, log):
         tx_per_block=int(workload["tx_per_block"]), window_blocks=int(workload["window_blocks"]), seed=seed,
         tx_shape=workload["tx_shape"], spoiled_blocks=int(workload.get("spoiled_blocks", 0)),
         pool_factor=int(workload.get("pool_factor", 3)), sig_samples=int(workload.get("sig_samples", 24)),
-        coinbase_maturity=net.get("coinbase_maturity"),
+        coinbase_maturity=net.get("coinbase_maturity"), own_blocks_delayed=bool(net.get("own_blocks_delayed", False)),
+        gap_stratum_blocks=int(workload.get("gap_stratum_blocks", 0)),
     )
     dag = dagmod.build(spec, log=log)
     _check_network(config, dag.params)

@@ -31,6 +31,23 @@ def _random_dag(seed: int, n: int, width: int):
     return genesis, blocks
 
 
+def _wide_dag(seed: int, n: int, width: int):
+    """[(hash, parents)] as one miner with delayed own blocks builds it: every
+    block points at *all* the tips of the DAG as it stood ``width`` blocks
+    ago (at most 16), so anticones are ``width`` blocks wide throughout."""
+    rng = random.Random(seed)
+    genesis = b"\x01" + bytes(31)
+    blocks, tips_then = [], [[genesis]]
+    tips = {genesis}
+    for i in range(n):
+        parents = tips_then[max(0, len(tips_then) - width)][:16]
+        h = rng.randbytes(32)
+        blocks.append((h, parents))
+        tips = (tips - set(parents)) | {h}
+        tips_then.append(sorted(tips))
+    return genesis, blocks
+
+
 def _program_ghostdag(genesis, blocks, k):
     from kaspa_tpu.consensus.model.header import Header
     from kaspa_tpu.consensus.processes.ghostdag import GhostdagManager
@@ -72,9 +89,12 @@ def _disagreements(gd, program, blocks):
     return wrong
 
 
-@pytest.mark.parametrize("seed,k,width", [(1, 2, 5), (2, 3, 8), (3, 1, 4), (4, 18, 6)])
-def test_ghostdag_equals_the_program_on_random_dags(seed, k, width):
-    genesis, blocks = _random_dag(seed, 400, width)
+@pytest.mark.parametrize("seed,k,width,make", [
+    (1, 2, 5, _random_dag), (2, 3, 8, _random_dag), (3, 1, 4, _random_dag), (4, 18, 6, _random_dag),
+    (5, 2, 6, _wide_dag), (6, 3, 8, _wide_dag),  # simpa's shape; no block is red at its own k=102
+])
+def test_ghostdag_equals_the_program_on_random_dags(seed, k, width, make):
+    genesis, blocks = make(seed, 400, width)
     as_blocks = [SimpleNamespace(hash=h, header=SimpleNamespace(bits=BITS, direct_parents=lambda p=p: p)) for h, p in blocks]
     gd = reference.Ghostdag(as_blocks, genesis, BITS, k)
     program = _program_ghostdag(genesis, blocks, k)

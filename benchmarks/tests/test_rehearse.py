@@ -11,7 +11,7 @@ import sys
 import pytest
 
 from benchmarks import harness
-from benchmarks.tests.conftest import ROOT, toy_cell
+from benchmarks.tests.conftest import ROOT, TOY_WIDE, toy_cell
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
@@ -50,6 +50,21 @@ def test_catchup_end_to_end(bench):
     assert set(out["metrics"]) == allowed == {"catchup_blocks_per_s", "setup_s"}
     heads = {ln.split(" ", 1)[0] for ln in lines}
     assert {"pretrace", "dag", "setup", "window", "counters", "compile_cache_in_window"} <= heads
+
+
+def test_wide_catchup_end_to_end(bench):
+    """The wide toy cell (one miner, own blocks delayed): the same entry, mode
+    and comparison; every count 0 though the virtual reorganises."""
+    cell, lines = "simpa-8bps.catchup-200tpb", []
+    out = _run(bench, cell, "catchup", False, lines, **TOY_WIDE)
+    allowed = _check_line(out, bench, cell, False)
+    assert set(out["metrics"]) == allowed == {"catchup_blocks_per_s", "setup_s"}
+    assert all(v == [0, 0] for v in out["checks"].values()), out["checks"]
+    facts = json.loads(next(ln for ln in lines if ln.startswith("dag ")).split(" ", 1)[1])
+    assert facts["miners"] == 1 and facts["mean_window_parents"] > 2
+    # the traffic file's gap strata reach the generator: 39 gaps at 4 blocks/s, whole 1 s strata but for the ends
+    assert abs(facts["window_vtime_s"] - 39 / 4) <= 1.0
+    assert not any(ln.startswith("second pass") for ln in lines)
 
 
 def test_paced_traced(bench):

@@ -10,7 +10,7 @@ import os
 import pytest
 
 from benchmarks import harness
-from benchmarks.tests.conftest import ROOT
+from benchmarks.tests.conftest import ROOT, TOY_WIDE
 from benchmarks.tests.test_rehearse import _check_line, _run
 
 READS_ON_CPU = {"span_sum", "span_uncovered", "counter_ratio", "harness_value"}
@@ -21,13 +21,14 @@ def bench():
     return harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
 
 
-@pytest.mark.parametrize("cell, mode", [
-    ("crescendo-10bps.catchup-10tpb", "catchup"),
-    ("crescendo-10bps.paced-10tpb", "paced"),
+@pytest.mark.parametrize("cell, mode, kw", [
+    ("crescendo-10bps.catchup-10tpb", "catchup", {}),
+    ("crescendo-10bps.paced-10tpb", "paced", {}),
+    ("simpa-8bps.catchup-200tpb", "catchup", TOY_WIDE),
 ])
-def test_span_and_counter_metrics_read_a_number(bench, cell, mode):
+def test_span_and_counter_metrics_read_a_number(bench, cell, mode, kw):
     lines = []
-    out = _run(bench, cell, mode, True, lines)
+    out = _run(bench, cell, mode, True, lines, **kw)
     _check_line(out, bench, cell, True)
     expected = set()
     for m in bench["per_layer"]:
@@ -46,3 +47,5 @@ def test_span_and_counter_metrics_read_a_number(bench, cell, mode):
         assert v["virtual_uncovered_ms_per_block.catchup"] <= v["pipeline_virtual_ms_per_block.catchup"]
         assert v["verify_padded_lane_occupancy_pct"] <= v["verify_lane_occupancy_pct"]
         assert 0 < v["muhash_lane_occupancy_pct"] <= 100
+        # the price of a reorganisation (ROADMAP S3) is read in both catch-up cells
+        assert 0 < v["virtual_move_position_ms_per_block.catchup"] <= v["pipeline_virtual_ms_per_block.catchup"]
