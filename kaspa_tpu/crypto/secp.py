@@ -109,6 +109,9 @@ _DEGRADED_JOBS = REGISTRY.counter("secp_degraded_jobs", help="verify jobs execut
 # the other side of the same ledger: every job handed to a guarded dispatch
 # ends in exactly one of secp_device_jobs / secp_degraded_jobs
 _DEVICE_JOBS = REGISTRY.counter("secp_device_jobs", help="verify jobs answered by the device lane")
+_DEVICE_ECDSA_JOBS = REGISTRY.counter(
+    "secp_device_ecdsa_jobs", help="of secp_device_jobs, those the ECDSA ladder answered (secp_device_jobs stays the total)"
+)
 
 _CHALLENGE_MID = hashlib.sha256(
     hashlib.sha256(b"BIP0340/challenge").digest() * 2
@@ -268,6 +271,8 @@ def _run_guarded(batch: _Batch, kernel, items: list, host_verify) -> np.ndarray:
         else:
             br.record_success()
             _DEVICE_JOBS.inc(n)
+            if kernel.__name__ == "ecdsa_verify":
+                _DEVICE_ECDSA_JOBS.inc(n)
             return mask
     return _host_lane(batch, kernel.__name__, items, host_verify)
 

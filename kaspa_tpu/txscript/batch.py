@@ -10,9 +10,23 @@ checks of an entire block/mergeset are *collected* into one device batch:
                      overlapped with the host-VM fallback lane
     resolve phase  : validity bitmask mapped back to per-input results
 
-Consensus equivalence: only canonical standard P2PK spends take the batch
-path; anything else routes to the host VM (txscript.vm) — same acceptance
-decisions as running the reference's engine per input.
+Consensus equivalence: three script forms take the batch path, chosen by what
+the script is: canonical P2PK Schnorr, canonical P2PK ECDSA, and a P2SH spend
+whose redeem script is the canonical m-of-n multisig
+(``standard.parse_multisig_redeem``) behind a push-only signature script of
+exactly m 65-byte signatures.  Anything else routes to the host VM
+(txscript.vm) — same acceptance decisions as running the reference's engine
+per input.
+
+The multisig lane: signatures follow key order, so signature i can only match
+keys i .. i + (n - m); those m * (n - m + 1) candidate (signature, key) pairs
+ask the signature cache and then join the device batch as ordinary ``schnorr``
+/ ``ecdsa`` jobs.  At resolve every answer goes into the signature cache and
+the engine's key-order walk is replayed over them.  The lane only ever
+*accepts*: an input whose walk does not end in success (or would charge more
+sig ops than the input committed, or meets a key that is no curve point) is
+run through the host VM, which finds the device's answers in the cache and
+supplies the verdict and the message.
 
 The VM fallback lane is *deferred and parallel*: nonstandard inputs are
 queued at collect time and executed at dispatch on a bounded thread pool,
@@ -20,13 +34,15 @@ concurrently with the device batches (the device dispatch releases the GIL
 while XLA runs, so a multisig/P2SH-heavy block no longer serializes the
 fallback work behind — or in front of — the device lane).  Failure
 precedence matches the serial path exactly: VM failures apply first, in
-collect order, then device-batch failures in queue order, so the
+collect order (a multisig input the VM re-ran holds the place it was
+collected in), then device-batch failures in queue order, so the
 (token -> first error) mapping is bit-identical to serial execution.
 """
 
 from __future__ import annotations
 
 import functools
+import hashlib
 import os
 import threading
 
@@ -36,12 +52,13 @@ from dataclasses import dataclass
 from time import perf_counter_ns
 
 from kaspa_tpu.consensus import hashing as chash
-from kaspa_tpu.crypto import secp
+from kaspa_tpu.crypto import eclib, secp
 from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY, SIZE_BUCKETS
 from kaspa_tpu.resilience.faults import FAULTS, FaultInjected
 from kaspa_tpu.txscript import standard
 from kaspa_tpu.txscript.caches import SigCache
+from kaspa_tpu.txscript.vm import MAX_SCRIPT_ELEMENT_SIZE
 
 # fast-path vs fallback mix: a fallback-heavy workload starves the device
 # batch, which is the first thing to check when occupancy drops
@@ -50,6 +67,14 @@ _SIGCACHE_SKIPS = REGISTRY.counter("txscript_batch_sigcache_skips", help="jobs a
 _VM_FALLBACKS = REGISTRY.counter("txscript_vm_fallbacks", help="inputs routed to the host VM instead of the batch")
 _FALLBACK_BATCH = REGISTRY.histogram(
     "txscript_fallback_batch_size", SIZE_BUCKETS, help="deferred VM fallback jobs per dispatch"
+)
+_P2SH_INPUTS = REGISTRY.counter_family("txscript_p2sh_inputs", "lane", help="pay-to-script-hash inputs by the lane that took them (batch | vm)")
+_MULTISIG_INPUTS = REGISTRY.counter("txscript_multisig_inputs", help="canonical m-of-n multisig inputs that took the batch path")
+_MULTISIG_PAIRS = REGISTRY.counter(
+    "txscript_multisig_pairs", help="candidate (signature, key) pairs of batch-path multisig inputs, cache-answered ones included"
+)
+_MULTISIG_RERUNS = REGISTRY.counter(
+    "txscript_multisig_vm_reruns", help="batch-path multisig inputs the walk did not accept, re-run through the host VM"
 )
 _VM_RETRIES = REGISTRY.counter(
     "txscript_vm_fault_retries", help="VM fallback jobs retried after an injected transient fault"
@@ -106,6 +131,45 @@ class _FallbackJob:
     # VM execution (and its queue wait) to the owning block's trace
     ctx: object = None
     enqueued_ns: int = 0
+    seq: int = 0  # place among the checker's VM-lane inputs, in collect order
+
+
+@dataclass
+class _MultisigInput:
+    """One batch-path m-of-n input: the answers to its candidate pairs arrive
+    from the cache at collect and from the device at resolve."""
+
+    vm: _FallbackJob  # the same input through the host VM, run only if the walk does not accept
+    m: int
+    keys: list
+    ecdsa: bool
+    sig_op_limit: int
+    answers: dict  # (signature index, key index) -> bool
+
+    def accepted(self) -> bool:
+        """The engine's key-order walk (vm._op_checkmultisig_impl) over the
+        answers.  False wherever the engine would fail *or raise*."""
+        n, key_pos = len(self.keys), 0
+        for sig_idx in range(self.m):
+            while True:
+                if n - key_pos < self.m - sig_idx or key_pos >= self.sig_op_limit:
+                    return False  # fewer keys than signatures left, or the next check exceeds the commit
+                key = self.keys[key_pos]
+                key_pos += 1
+                if self.answers[(sig_idx, key_pos - 1)]:
+                    break
+                # the engine raises on a key that is no curve point before it
+                # looks at the signature; the device just says False
+                if not _is_curve_point(key, self.ecdsa):
+                    return False
+        return True
+
+
+@functools.lru_cache(maxsize=4096)
+def _is_curve_point(key: bytes, ecdsa: bool) -> bool:
+    """A modular square root a key: remembered, because a wallet's keys come
+    back with every input it spends."""
+    return (eclib.parse_compressed(key) if ecdsa else eclib.lift_x(int.from_bytes(key, "big"))) is not None
 
 
 def _run_fallback(job: _FallbackJob) -> Exception | None:
@@ -210,6 +274,7 @@ class BatchScriptChecker:
         self.traffic_class = traffic_class
         self._jobs: list[_Job] = []
         self._fallbacks: list[_FallbackJob] = []
+        self._multisigs: list[_MultisigInput] = []
         self._results: dict[int, Exception | None] = {}
 
     def collect_tx(self, token: int, tx, utxo_entries, reused=None, pov_daa_score=None, seq_commit_accessor=None) -> None:
@@ -266,30 +331,65 @@ class BatchScriptChecker:
             # dispatch, concurrently with the device batches)
             if self.vm_fallback is None:
                 raise ScriptCheckError(f"unsupported script class {cls.value} (VM fallback not wired)", i)
-            _VM_FALLBACKS.inc()
-            self._fallbacks.append(
-                _FallbackJob(
-                    token,
-                    i,
-                    functools.partial(
-                        self.vm_fallback, tx, utxo_entries, i, reused, pov_daa_score,
-                        seq_commit_accessor=seq_commit_accessor,
-                    ),
-                    ctx=trace.context(),
-                    enqueued_ns=perf_counter_ns(),
-                )
+            vm_job = _FallbackJob(
+                token,
+                i,
+                functools.partial(
+                    self.vm_fallback, tx, utxo_entries, i, reused, pov_daa_score,
+                    seq_commit_accessor=seq_commit_accessor,
+                ),
+                ctx=trace.context(),
+                seq=len(self._fallbacks) + len(self._multisigs),
             )
+            if cls == standard.ScriptClass.SCRIPT_HASH:
+                multisig = self._collect_multisig(tx, utxo_entries, i, inp, entry, reused, vm_job)
+                _P2SH_INPUTS.inc("vm" if multisig is None else "batch")
+                if multisig is not None:
+                    self._multisigs.append(multisig)
+                    return
+            _VM_FALLBACKS.inc()
+            vm_job.enqueued_ns = perf_counter_ns()  # it waits for a pool thread from here
+            self._fallbacks.append(vm_job)
+
+    def _collect_multisig(self, tx, utxo_entries, i, inp, entry, reused, vm_job) -> _MultisigInput | None:
+        """The batch-path form of a P2SH input, its candidate pairs queued, or
+        None: whatever is not the canonical spend of a canonical m-of-n redeem
+        script is the host VM's, as it always was."""
+        limit = inp.compute_commit.sig_op_count()
+        pushes = standard.parse_canonical_pushes(inp.signature_script)
+        if limit is None or not pushes:
+            return None
+        redeem, blobs = pushes[-1], pushes[:-1]
+        if len(redeem) > MAX_SCRIPT_ELEMENT_SIZE or hashlib.blake2b(redeem, digest_size=32).digest() != entry.script_public_key.script[2:34]:
+            return None
+        parsed = standard.parse_multisig_redeem(redeem)
+        if parsed is None:
+            return None
+        m, keys, ecdsa = parsed
+        if len(blobs) != m or any(len(b) != 65 or b[64] not in chash.ALLOWED_SIG_HASH_TYPES for b in blobs):
+            return None
+        kind, sighash = ("ecdsa", chash.calc_ecdsa_signature_hash) if ecdsa else ("schnorr", chash.calc_schnorr_signature_hash)
+        multisig = _MultisigInput(vm_job, m, keys, ecdsa, limit, {})
+        msgs: dict = {}  # one sighash per hash type of this input
+        for sig_idx, blob in enumerate(blobs):
+            sig, hash_type = blob[:64], blob[64]
+            if hash_type not in msgs:
+                msgs[hash_type] = sighash(tx, utxo_entries, i, hash_type, reused)
+            # signatures follow key order: signature sig_idx can only match keys sig_idx .. sig_idx + (n - m)
+            for key_idx in range(sig_idx, sig_idx + len(keys) - m + 1):
+                self._queue_pair(kind, keys[key_idx], msgs[hash_type], sig, multisig.answers, (sig_idx, key_idx))
+        _MULTISIG_INPUTS.inc()
+        _MULTISIG_PAIRS.inc(m * (len(keys) - m + 1))
+        return multisig
+
+    def _queue_pair(self, kind, pubkey, msg, sig, answers: dict, slot) -> None:
+        """One candidate pair of a multisig input: its answer lands in
+        ``answers[slot]``, from the cache now or from the device at resolve."""
+        cached = self._ask(kind, pubkey, msg, sig, lambda ok, _fail: answers.__setitem__(slot, ok))
+        if cached is not None:
+            answers[slot] = cached
 
     def _queue(self, token, kind, pubkey, msg, sig, input_index):
-        cache_key = (kind, sig, msg, pubkey)
-        cached = self.sig_cache.get(cache_key)
-        if cached is not None:
-            _SIGCACHE_SKIPS.inc()
-            if not cached:
-                self._fail(token, ScriptCheckError("invalid signature (cached)", input_index))
-            return
-        _JOBS.inc(kind)
-
         # `fail` is supplied at resolve time: dispatch_async detaches the
         # results dict into its handle, so the callback must not close over
         # the checker's (reusable) live state
@@ -297,11 +397,28 @@ class BatchScriptChecker:
             if not ok:
                 fail(token, ScriptCheckError("invalid signature", input_index))
 
-        self._jobs.append(_Job(kind, pubkey, msg, sig, cache_key, cb))
+        if self._ask(kind, pubkey, msg, sig, cb) is False:
+            self._fail(token, ScriptCheckError("invalid signature (cached)", input_index))
+
+    def _ask(self, kind, pubkey, msg, sig, callback) -> bool | None:
+        """The signature cache's answer to one check, or None once the check
+        is queued as a device job that ends in ``callback(ok, fail)``."""
+        cache_key = (kind, sig, msg, pubkey)
+        cached = self.sig_cache.get(cache_key)
+        if cached is not None:
+            _SIGCACHE_SKIPS.inc()
+            return cached
+        _JOBS.inc(kind)
+        self._jobs.append(_Job(kind, pubkey, msg, sig, cache_key, callback))
+        return None
 
     def queued_jobs(self) -> int:
         """Signature jobs staged for the device lane since the last dispatch."""
         return len(self._jobs)
+
+    def queued_multisig_inputs(self) -> int:
+        """Multisig inputs that took the batch path since the last dispatch."""
+        return len(self._multisigs)
 
     def _effective_workers(self, jobs: int) -> int:
         w = self.fallback_workers if self.fallback_workers is not None else _default_fallback_workers()
@@ -322,6 +439,7 @@ class BatchScriptChecker:
         ``result()`` yields the same token -> first-error mapping — and
         the same failure precedence — as the synchronous path."""
         fallbacks, self._fallbacks = self._fallbacks, []
+        multisigs, self._multisigs = self._multisigs, []
         jobs, self._jobs = self._jobs, []
         results, self._results = self._results, {}
 
@@ -353,15 +471,16 @@ class BatchScriptChecker:
                 tickets["ecdsa"] = engine.submit(
                     f"{prefix}ecdsa", [(j.pubkey, j.msg, j.sig) for j in ecdsa]
                 )
-        return DispatchHandle(self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results)
+        return DispatchHandle(self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs)
 
 
 class DispatchHandle:
     """In-flight dispatch: owns the detached jobs/results of one round."""
 
-    def __init__(self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results):
+    def __init__(self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs):
         self.sig_cache = sig_cache
         self._fallbacks = fallbacks
+        self._multisigs = multisigs
         self._pending = pending
         self._schnorr = schnorr
         self._ecdsa = ecdsa
@@ -394,6 +513,7 @@ class DispatchHandle:
         # fallback lane resolution BEFORE the device callbacks: the serial
         # path ran the VM at collect time, so VM failures must win the
         # first-error slot over same-token batch failures, in collect order
+        vm_failures = []  # (job, error) of the VM lane
         if self._fallbacks:
             with trace.span("txscript.fallback_join", jobs=len(self._fallbacks), parallel=self._pending is not None):
                 errors = (
@@ -401,9 +521,7 @@ class DispatchHandle:
                     if self._pending is not None
                     else [_run_fallback(j) for j in self._fallbacks]
                 )
-            for job, err in zip(self._fallbacks, errors):
-                if err is not None:
-                    self._fail(job.token, ScriptCheckError(str(err), job.input_index))
+            vm_failures = [(job, err) for job, err in zip(self._fallbacks, errors) if err is not None]
 
         if self._tickets is not None:
             # coalesced device lane: block on this round's tickets (wait()
@@ -425,9 +543,27 @@ class DispatchHandle:
                         )
                     raise
 
+        # the device's answers go into the signature cache first: a multisig
+        # input the walk does not accept is re-run through the host VM, which
+        # reads them there and does no curve arithmetic of its own
+        batch_failures: list = []  # (token, error) of the P2PK jobs, in queue order
         for jobs, mask in ((self._schnorr, schnorr_mask), (self._ecdsa, ecdsa_mask)):
             if mask is not None:
                 for j, ok in zip(jobs, mask):
                     self.sig_cache.insert(j.cache_key, bool(ok))
-                    j.callback(bool(ok), self._fail)
+                    j.callback(bool(ok), lambda token, err: batch_failures.append((token, err)))
+        if self._multisigs:
+            with trace.span("txscript.multisig_resolve", inputs=len(self._multisigs)) as sp:
+                reruns = [ms.vm for ms in self._multisigs if not ms.accepted()]
+                _MULTISIG_RERUNS.inc(len(reruns))
+                for job in reruns:
+                    err = _run_fallback(job)
+                    if err is not None:
+                        vm_failures.append((job, err))
+                sp.set(vm_reruns=len(reruns))
+            vm_failures.sort(key=lambda f: f[0].seq)
+        for job, err in vm_failures:
+            self._fail(job.token, ScriptCheckError(str(err), job.input_index))
+        for token, err in batch_failures:
+            self._fail(token, err)
         return self._results
