@@ -712,18 +712,19 @@ PHASES = {"kernels": phase_kernels, "replay": phase_replay, "daemon": phase_daem
 
 
 def _engines() -> dict:
-    """Which storage and keystream engines are live: both fall back to
+    """Which storage and host-crypto engines are live (the keystream and the
+    verify builders' lift_x share one library): both fall back to
     Python without a word when g++ fails, and both build from ``native/``
     as checked out (utils/nativebuild.py names the library after a digest
     of its sources, so a stale ignored ``*.so`` is never what runs)."""
-    from kaspa_tpu.crypto import chacha
+    from kaspa_tpu.crypto import hostcrypto
     from kaspa_tpu.storage import kv
 
     with tempfile.TemporaryDirectory(prefix="chip_smoke_kv_") as d:
         store = kv.open_store(os.path.join(d, "probe"))
         storage = type(store).__name__
         store.close()
-    return {"keystream": "native" if chacha._native_lib() is not None else "python", "storage": storage}
+    return {"keystream": "native" if hostcrypto.lib() is not None else "python", "storage": storage}
 
 
 def _device() -> dict:

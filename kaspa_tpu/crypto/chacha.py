@@ -10,42 +10,12 @@ TPU U3072 reduction.
 from __future__ import annotations
 
 import ctypes
-import os
-import threading
 
-from kaspa_tpu.utils import nativebuild
-from kaspa_tpu.utils.sync import ranked_lock
+from kaspa_tpu.crypto import hostcrypto
 
 import numpy as np
 
 _CONSTANTS = np.array([0x61707865, 0x3320646E, 0x79622D32, 0x6B206574], dtype=np.uint32)
-
-_SRC = os.path.join(os.path.dirname(__file__), "..", "..", "native", "hostcrypto", "hostcrypto.cc")
-_LOCK = ranked_lock("chacha.build")
-_LIB = None
-_LIB_FAILED = False
-
-
-def _native_lib():
-    """Build/load the native keystream library; None if unavailable."""
-    global _LIB, _LIB_FAILED
-    if _LIB is not None or _LIB_FAILED:
-        return _LIB
-    with _LOCK:
-        if _LIB is not None or _LIB_FAILED:
-            return _LIB
-        try:
-            lib = ctypes.CDLL(nativebuild.build(_SRC, "hostcrypto", opt="-O3"))
-            lib.chacha20_keystream_batch.argtypes = [
-                ctypes.c_char_p,
-                ctypes.c_uint64,
-                ctypes.c_void_p,
-                ctypes.c_uint64,
-            ]
-            _LIB = lib
-        except Exception:
-            _LIB_FAILED = True
-    return _LIB
 
 
 def _rotl(x, n):
@@ -71,7 +41,7 @@ def keystream(keys: np.ndarray, n_bytes: int) -> np.ndarray:
     """
     assert keys.ndim == 2 and keys.shape[1] == 32
     n = keys.shape[0]
-    lib = _native_lib()
+    lib = hostcrypto.lib()
     if lib is not None and n > 0:
         keys_u8 = np.ascontiguousarray(keys, dtype=np.uint8)
         out = np.empty((n, n_bytes), dtype=np.uint8)

@@ -7,10 +7,20 @@ validity bitmask the validator consumes unchanged — mirroring the role of
 libsecp256k1 calls inside the reference's script engine
 (crypto/txscript/src/lib.rs:885-935) but batched across a whole block/DAG
 slice instead of per-input.
+
+Host preparation is per batch wherever the arithmetic is big: the keys of
+a batch are lifted to curve points by one native call (``_lift_keys``:
+native/hostcrypto's ``secp_lift_x_batch``, entered with the GIL held, or
+``eclib.lift_x`` per key where the library cannot be built) and ECDSA's ``s`` are inverted together
+(``_batch_inverse``).  Native is the lift alone; the tagged hash is
+hashlib's, the range checks and the scalar products are Python ints, and
+eclib stays the oracle of every verdict (the host lane, the tests).  The
+aggregate lane's builder still lifts per job.
 """
 
 from __future__ import annotations
 
+import ctypes
 import hashlib
 import os
 import threading
@@ -18,7 +28,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from kaspa_tpu.crypto import eclib
+from kaspa_tpu.crypto import eclib, hostcrypto
 from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import PERCENT_BUCKETS, REGISTRY, SIZE_BUCKETS
 from kaspa_tpu.ops.secp256k1 import points as pt
@@ -113,6 +123,15 @@ _DEVICE_ECDSA_JOBS = REGISTRY.counter(
     "secp_device_ecdsa_jobs", help="of secp_device_jobs, those the ECDSA ladder answered (secp_device_jobs stays the total)"
 )
 
+# which way the builders' keys were lifted: the second equals the first
+# wherever native/hostcrypto built; under it, eclib's pow() answered the rest
+_HOST_LIFT_JOBS = REGISTRY.counter(
+    "secp_host_lift_jobs", help="public keys lifted to curve points by the verify batch builders, whichever way"
+)
+_NATIVE_LIFT_JOBS = REGISTRY.counter(
+    "secp_native_lift_jobs", help="of secp_host_lift_jobs, those the native batched lift_x call answered"
+)
+
 _CHALLENGE_MID = hashlib.sha256(
     hashlib.sha256(b"BIP0340/challenge").digest() * 2
 )  # pre-tagged sha256 state
@@ -133,6 +152,9 @@ def schnorr_challenge(r32: bytes, px32: bytes, msg32: bytes) -> int:
 
 
 _ZERO32 = b"\x00" * 32
+# equal-length big-endian strings order as the numbers they spell
+_P_BE = eclib.P.to_bytes(32, "big")
+_N_BE = eclib.N.to_bytes(32, "big")
 
 
 @dataclass
@@ -162,9 +184,15 @@ class _Batch:
         self.ok.append(False)
 
     def push(self, px: int, py: int, rc: int, s1: int, s2: int):
-        self.px.append(px.to_bytes(32, "big"))
-        self.py.append(py.to_bytes(32, "big"))
-        self.rc.append(rc.to_bytes(32, "big"))
+        self.push_wire(px.to_bytes(32, "big"), py.to_bytes(32, "big"), rc.to_bytes(32, "big"), s1, s2)
+
+    def push_wire(self, px: bytes, py: bytes, rc: bytes, s1, s2):
+        """push() for a job whose coordinates are already the 32 big-endian
+        bytes the columns hold (a key's x and a signature's r as the wire has
+        them, y as the batched lift returns it)."""
+        self.px.append(px)
+        self.py.append(py)
+        self.rc.append(rc)
         self.d1.append(s1)
         self.d2.append(s2)
         self.ok.append(True)
@@ -293,24 +321,76 @@ def _host_lane(batch: _Batch, kernel_name: str, items: list, host_verify) -> np.
     return mask
 
 
+# keys a native call: the call keeps the GIL (crypto/hostcrypto.py), so this
+# bounds the hold at ≈ 3 ms, under the interpreter's 5 ms switch interval
+_LIFT_CHUNK = 512
+
+
+def _lift_keys(xs: list, odd: bytes | None = None) -> list:
+    """The y of each x (32 big-endian bytes each), as 32 big-endian bytes, or
+    None where x >= p or no curve point has that x: the even root (BIP340
+    lift_x), or the odd one where ``odd[i]`` (a 0x03 compressed key).
+
+    One native call for the whole batch (native/hostcrypto
+    ``secp_lift_x_batch``; one per ``_LIFT_CHUNK`` keys past that) where the
+    library loaded; ``eclib.lift_x``, a modular exponentiation in the
+    interpreter per key, where it did not.  Same answer either way: eclib is the oracle
+    tests/test_secp_native_lift.py holds the native entry to.
+    """
+    n = len(xs)
+    if n == 0:
+        return []
+    _HOST_LIFT_JOBS.inc(n)
+    lib = hostcrypto.lib()
+    if lib is None:
+        ys = []
+        for i, x in enumerate(xs):
+            point = eclib.lift_x(int.from_bytes(x, "big"))
+            if point is None:
+                ys.append(None)
+            else:
+                y = eclib.P - point[1] if odd is not None and odd[i] else point[1]
+                ys.append(y.to_bytes(32, "big"))
+        return ys
+    joined = b"".join(xs)
+    if len(joined) != 32 * n or (odd is not None and len(odd) != n):
+        raise ValueError("lift_keys: every x is 32 bytes, one parity flag a key")
+    out = ctypes.create_string_buffer(32 * _LIFT_CHUNK)
+    flags = ctypes.create_string_buffer(_LIFT_CHUNK)
+    ys = []
+    for off in range(0, n, _LIFT_CHUNK):
+        m = min(_LIFT_CHUNK, n - off)
+        lib.secp_lift_x_batch(
+            joined[32 * off : 32 * (off + m)], m, None if odd is None else odd[off : off + m], out, flags
+        )
+        raw, ok = out.raw, flags.raw
+        ys += [raw[32 * i : 32 * i + 32] if ok[i] else None for i in range(m)]
+    _NATIVE_LIFT_JOBS.inc(n)
+    return ys
+
+
 def _build_schnorr_batch(items: list) -> _Batch:
+    """Two passes: the encoding and range checks pick the jobs whose key is
+    worth lifting, one ``_lift_keys`` call lifts them all, then challenge
+    and columns in item order."""
+    # BIP340 allows arbitrary-length messages (matching eclib oracle);
+    # kaspa consensus always passes 32-byte sighash digests.
+    live = [
+        i
+        for i, (pub, _msg, sig) in enumerate(items)
+        if len(pub) == 32 and len(sig) == 64 and sig[:32] < _P_BE and sig[32:] < _N_BE
+    ]
+    ys = dict(zip(live, _lift_keys([bytes(items[i][0]) for i in live])))
     batch = _Batch()
-    for pub, msg, sig in items:
-        # BIP340 allows arbitrary-length messages (matching eclib oracle);
-        # kaspa consensus always passes 32-byte sighash digests.
-        if len(pub) != 32 or len(sig) != 64:
-            batch.push_invalid()
-            continue
-        pk = eclib.lift_x(int.from_bytes(pub, "big"))
-        r = int.from_bytes(sig[:32], "big")
-        s = int.from_bytes(sig[32:], "big")
-        if pk is None or r >= eclib.P or s >= eclib.N:
+    for i, (pub, msg, sig) in enumerate(items):
+        y = ys.get(i)
+        if y is None:
             batch.push_invalid()
             continue
         e = schnorr_challenge(sig[:32], pub, msg)
         # s rides as its canonical 32-byte wire encoding (range-checked
         # above): _scalars_to_digits takes it with zero per-item int work
-        batch.push(pk[0], pk[1], r, sig[32:], e)
+        batch.push_wire(bytes(pub), y, sig[:32], sig[32:], e)
     return batch
 
 
@@ -318,7 +398,8 @@ def schnorr_verify_batch(items) -> np.ndarray:
     """items: iterable of (pubkey32, msg32, sig64) -> bool mask.
 
     Encoding/range checks and lift_x run on host (failures short-circuit to
-    False without occupying useful device lanes beyond padding).
+    False without occupying useful device lanes beyond padding); the lift is
+    one native call for the batch (``_lift_keys``).
     """
     items = list(items)
     with trace.span("secp.host_prepare", kernel="schnorr_verify", jobs=len(items)):
@@ -572,24 +653,48 @@ def schnorr_verify_batch_aggregate(items) -> np.ndarray:
     return mask
 
 
+def _batch_inverse(values: list, m: int) -> list:
+    """Every value's inverse mod the prime m for one pow(): Montgomery's
+    trick (running products up, the one inverse peeled back down).  Every
+    value is in [1, m)."""
+    prefix, acc = [], 1
+    for v in values:
+        prefix.append(acc)
+        acc = acc * v % m
+    inv = pow(acc, -1, m)
+    out = [0] * len(values)
+    for i in range(len(values) - 1, -1, -1):
+        out[i] = inv * prefix[i] % m
+        inv = inv * values[i] % m
+    return out
+
+
 def _build_ecdsa_batch(items: list) -> _Batch:
-    batch = _Batch()
+    """Two passes, as _build_schnorr_batch: checks, one ``_lift_keys`` call
+    (the key's prefix says which root), one modular inversion for every s
+    of the batch, then u1 / u2 and columns in item order."""
     half_n = eclib.N // 2
-    for pub, msg, sig in items:
-        if len(sig) != 64 or len(msg) != 32:
-            batch.push_invalid()
+    live, rs, ss = [], [], []
+    for i, (pub, msg, sig) in enumerate(items):
+        if len(sig) != 64 or len(msg) != 32 or len(pub) != 33 or pub[0] not in (2, 3):
             continue
-        pk = eclib.parse_compressed(pub)
         r = int.from_bytes(sig[:32], "big")
         s = int.from_bytes(sig[32:], "big")
-        if pk is None or not (1 <= r < eclib.N) or not (1 <= s < eclib.N) or s > half_n:
+        if 1 <= r < eclib.N and 1 <= s <= half_n:  # high-S is rejected (eclib.ecdsa_verify)
+            live.append(i)
+            rs.append(r)
+            ss.append(s)
+    ys = _lift_keys([bytes(items[i][0][1:]) for i in live], bytes(items[i][0][0] & 1 for i in live))
+    rows = {i: (y, r, si) for i, y, r, si in zip(live, ys, rs, _batch_inverse(ss, eclib.N)) if y is not None}
+    batch = _Batch()
+    for i, (pub, msg, sig) in enumerate(items):
+        if i not in rows:
             batch.push_invalid()
             continue
+        y, r, si = rows[i]
         z = int.from_bytes(msg, "big") % eclib.N
-        si = pow(s, -1, eclib.N)
-        u1 = z * si % eclib.N
-        u2 = r * si % eclib.N
-        batch.push(pk[0], pk[1], r, u1, u2)
+        # r < n < p: the signature's first half is the target as the column holds it
+        batch.push_wire(bytes(pub[1:]), y, sig[:32], z * si % eclib.N, r * si % eclib.N)
     return batch
 
 

@@ -181,7 +181,7 @@ RANKS: dict[str, int] = {
     "service.list": 102,       # core/service.py — bound-services list
     "wrpc.ids": 104,           # rpc/wrpc.py — client request-id counter
     "storage.build": 105,      # storage/kv.py — one-shot native build guard
-    "chacha.build": 106,       # crypto/chacha.py — one-shot native build guard
+    "chacha.build": 106,       # crypto/hostcrypto.py — one-shot native build guard (chacha + lift_x)
     "observability.registry": 110,  # observability/core.py — metric registration (innermost)
 }
 

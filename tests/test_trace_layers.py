@@ -79,6 +79,23 @@ def test_host_prepare_once_per_super_batch(replay):
         assert any(_inside(p, s) and s["attrs"]["jobs"] == p["attrs"]["jobs"] for s in supers)
 
 
+def test_host_prepare_stays_one_span_a_batch_with_the_native_lift(replay):
+    """The batched lift_x call runs inside ``secp.host_prepare``: the span
+    keeps its two attributes, nothing per job is opened or labelled, and the
+    two lift counters account for every job the spans prepared."""
+    from kaspa_tpu.crypto import hostcrypto
+
+    spans, before, after, _ = replay
+    prepares = _named(spans, "secp.host_prepare")
+    assert prepares and all(set(p["attrs"]) == {"kernel", "jobs"} for p in prepares)
+    assert not [s["name"] for s in spans if "lift" in s["name"]]
+    assert len(prepares) < sum(p["attrs"]["jobs"] for p in prepares)  # a span a batch, not a job
+    lifted = _moved(after, before, "secp_host_lift_jobs")
+    assert isinstance(lifted, int) and lifted == sum(p["attrs"]["jobs"] for p in prepares)
+    native = _moved(after, before, "secp_native_lift_jobs")
+    assert isinstance(native, int) and native == (lifted if hostcrypto.lib() is not None else 0)
+
+
 def test_device_call_and_readback_once_per_device_dispatch(replay):
     spans = replay[0]
     dispatches = _named(spans, "secp.device_dispatch")
