@@ -664,7 +664,7 @@ def phase_mesh(
         "chips": chips,
         "visible_devices": len(jax.devices()),
         "formulation": {
-            "mesh1": "fused Pallas ladder (ladder_pallas._build_call_plain)",
+            "mesh1": "fused Pallas ladder (ladder_pallas._build_call)",
             f"mesh{chips}": "shard_map-wrapped XLA ladder (verify.schnorr_verify_kernel / ecdsa_verify_kernel)",
         },
         "mesh1": {"fingerprint": fp1, **one["report"]},

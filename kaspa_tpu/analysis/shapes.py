@@ -13,8 +13,8 @@ the ratchet apply:
    ``jax.eval_shape`` on a minimal representative set of traces (see
    ``kernel_catalog.audit_all``: tracing is seconds per kernel, and the
    graph is identical across batch widths); a verify kernel that stops
-   returning a ``[b] bool`` mask, or an aggregate partial that changes
-   layout, fails lint before it fails a device batch.
+   returning a ``[b] bool`` mask fails lint before it fails a device
+   batch.
 2. **coverage holes** — a reachable signature matched by no
    ``WARM_COVERAGE`` rule: the shape would compile cold in production
    with no pretrace replaying it.
@@ -35,7 +35,6 @@ _CATALOG_REL = "kaspa_tpu/ops/kernel_catalog.py"
 _FAMILY_OWNERS = {
     "ladder": "kaspa_tpu/ops/secp256k1/verify.py",
     "ecdsa": "kaspa_tpu/ops/secp256k1/verify.py",
-    "aggregate": "kaspa_tpu/ops/secp256k1/aggregate.py",
     "muhash": "kaspa_tpu/ops/muhash_ops.py",
 }
 

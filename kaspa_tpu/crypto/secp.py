@@ -14,8 +14,7 @@ native/hostcrypto's ``secp_lift_x_batch``, entered with the GIL held, or
 ``eclib.lift_x`` per key where the library cannot be built) and ECDSA's ``s`` are inverted together
 (``_batch_inverse``).  Native is the lift alone; the tagged hash is
 hashlib's, the range checks and the scalar products are Python ints, and
-eclib stays the oracle of every verdict (the host lane, the tests).  The
-aggregate lane's builder still lifts per job.
+eclib stays the oracle of every verdict (the host lane, the tests).
 """
 
 from __future__ import annotations
@@ -31,8 +30,7 @@ import numpy as np
 from kaspa_tpu.crypto import eclib, hostcrypto
 from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import PERCENT_BUCKETS, REGISTRY, SIZE_BUCKETS
-from kaspa_tpu.ops.secp256k1 import points as pt
-from kaspa_tpu.ops.secp256k1.verify import _be32_to_limbs, _scalars_to_digits, ecdsa_verify, schnorr_verify
+from kaspa_tpu.ops.secp256k1.verify import ecdsa_verify, schnorr_verify
 from kaspa_tpu.resilience import supervisor
 from kaspa_tpu.resilience.breaker import HUNG, device_breaker
 from kaspa_tpu.resilience.faults import FAULTS
@@ -87,27 +85,9 @@ def _cold_split_enabled() -> bool:
     jit cost grows superlinearly with batch width on the XLA formulation
     (~3 min for bucket 16 on CPU), so crossing into a cold bucket
     mid-pipeline can stall the commit lock for minutes.
-    `KASPA_TPU_COLD_BUCKET_SPLIT=0` restores pad-up-and-compile — bench
-    sweeps that deliberately measure specific bucket shapes need that."""
+    `KASPA_TPU_COLD_BUCKET_SPLIT=0` restores pad-up-and-compile — a run
+    that deliberately measures one bucket shape needs that."""
     return os.environ.get("KASPA_TPU_COLD_BUCKET_SPLIT", "1") not in ("0", "off", "false")
-
-# aggregate-lane telemetry: one RLC multi-scalar check replaces a whole
-# batch of dual ladders, so throughput lives or dies on how often the
-# combined check passes outright vs decays into bisection
-_AGG_BATCHES = REGISTRY.counter("secp_aggregate_batches", help="batches verified through the aggregate RLC lane")
-_AGG_JOBS = REGISTRY.counter("secp_aggregate_jobs", help="verify jobs entering the aggregate RLC lane")
-_AGG_CHECKS = REGISTRY.counter(
-    "secp_aggregate_checks", help="device dispatches of the combined multi-scalar check (incl. bisect halves)"
-)
-_AGG_BISECT_STEPS = REGISTRY.counter(
-    "secp_aggregate_bisect_steps", help="failed aggregate checks split in half to isolate bad signatures"
-)
-_AGG_LEAF_JOBS = REGISTRY.counter(
-    "secp_aggregate_leaf_jobs", help="jobs resolved by per-signature ladder leaves of the bisection"
-)
-_AGG_FALLBACK_JOBS = REGISTRY.counter(
-    "secp_aggregate_fallback_jobs", help="aggregate-lane jobs that fell back to the host degraded lane"
-)
 
 # degraded-lane occupancy: how much of the verify workload is riding the
 # host oracle instead of the device (breaker open, or a dispatch died) —
@@ -233,10 +213,7 @@ class _Batch:
                 # would skip the split and pay a surprise compile wall
                 _seen_shapes.discard(shape_key)
                 raise
-            supervisor.note_shape(
-                kernel.__name__, b,
-                family="ecdsa" if "ecdsa" in kernel.__name__ else "ladder",
-            )
+            supervisor.note_shape(kernel.__name__, b)
         else:
             mask = kernel(*args)
         return np.asarray(mask)[:n]
@@ -407,252 +384,6 @@ def schnorr_verify_batch(items) -> np.ndarray:
     return _run_guarded(batch, schnorr_verify, items, eclib.schnorr_verify)
 
 
-# --- aggregated random-linear-combination verification ---------------------
-#
-# ops/secp256k1/aggregate.py holds the math; this is the host half: weight
-# derivation, scalar prep, the guarded device dispatch, and the bisection
-# that converges a failed combined check back to the exact per-signature
-# mask (so verify_batch semantics are unchanged between modes).
-
-_AGG_KERNEL_NAME = "schnorr_aggregate"
-_AGG_WEIGHT_BYTES = 16  # 128-bit weights: cancellation probability 2^-128
-# below this many live lanes a sub-aggregate stops paying off (two device
-# round trips per level vs one ladder dispatch) — resolve per-signature
-_AGG_LEAF = 8
-
-
-@dataclass
-class _AggBatch:
-    """Host prep for the aggregate lane: negated points + raw scalars.
-
-    pxn/pyn are -P_i (lifted pubkey, y negated), rxn/ryn are -R_i with
-    R_i = lift_x(r_i) — negation on host so the device only ever adds.
-    """
-
-    pxn: list = field(default_factory=list)  # 32B BE x(-P) == x(P)
-    pyn: list = field(default_factory=list)  # 32B BE p - y(P)
-    rxn: list = field(default_factory=list)
-    ryn: list = field(default_factory=list)
-    s: list = field(default_factory=list)  # sig s scalars (python ints)
-    e: list = field(default_factory=list)  # challenge scalars
-    ok: list = field(default_factory=list)
-
-
-def _build_schnorr_aggregate(items: list) -> _AggBatch:
-    """Same prechecks as _build_schnorr_batch, plus the r -> R_i lift the
-    aggregate equation needs as an explicit point."""
-    batch = _AggBatch()
-    for pub, msg, sig in items:
-        if len(pub) != 32 or len(sig) != 64:
-            batch.ok.append(False)
-            continue
-        pk = eclib.lift_x(int.from_bytes(pub, "big"))
-        r = int.from_bytes(sig[:32], "big")
-        s = int.from_bytes(sig[32:], "big")
-        rp = eclib.lift_x(r) if r < eclib.P else None
-        if pk is None or rp is None or s >= eclib.N:
-            batch.ok.append(False)
-            continue
-        batch.pxn.append(pk[0].to_bytes(32, "big"))
-        batch.pyn.append((eclib.P - pk[1]).to_bytes(32, "big"))
-        batch.rxn.append(rp[0].to_bytes(32, "big"))
-        batch.ryn.append((eclib.P - rp[1]).to_bytes(32, "big"))
-        batch.s.append(s)
-        batch.e.append(schnorr_challenge(sig[:32], pub, msg))
-        batch.ok.append(True)
-    return batch
-
-
-def _aggregate_weights(items: list) -> list:
-    """Deterministic per-signature random weights a_i, seeded from the
-    batch transcript: ChaCha20 keystream keyed by the SHA256 of every
-    (pub, msg, sig) in order.  An attacker committing to signatures before
-    knowing the weights cannot craft errors that cancel (the falsification
-    test pins exactly this).  a_i == 0 is remapped to 1 so every live lane
-    stays coupled to the combined check."""
-    from kaspa_tpu.crypto import chacha
-
-    h = hashlib.sha256(b"kaspa-tpu/aggregate-weights/v1")
-    for pub, msg, sig in items:
-        for part in (pub, msg, sig):
-            h.update(len(part).to_bytes(4, "little"))
-            h.update(part)
-    seed = np.frombuffer(h.digest(), dtype=np.uint8)[None, :]
-    stream = chacha.keystream(seed, _AGG_WEIGHT_BYTES * len(items))[0].tobytes()
-    return [
-        int.from_bytes(stream[i * _AGG_WEIGHT_BYTES : (i + 1) * _AGG_WEIGHT_BYTES], "big") or 1
-        for i in range(len(items))
-    ]
-
-
-def _aggregate_args(prep: _AggBatch, weights: list, rows: dict, idxs: list):
-    """Marshal the selected live lanes into bucket-padded device arrays.
-
-    rows[i] is item i's position in prep's compacted columns.  Pad lanes
-    are all-zero: digit 0 selects the true-identity table entry, so they
-    contribute nothing to any window sum.
-    """
-    from kaspa_tpu.ops.secp256k1 import aggregate as agg
-
-    b = _bucket(len(idxs))
-    cs = [weights[i] * prep.e[rows[i]] % eclib.N for i in idxs]
-    u = 0
-    for i in idxs:
-        u += weights[i] * prep.s[rows[i]]
-    ws = [weights[i] for i in idxs]
-    c_digits = _scalars_to_digits(cs, b)
-    # 128-bit weights: digit columns 0..31 are statically zero, ship only
-    # the live half so the kernel skips half the R-side gathers/adds
-    a_digits = _scalars_to_digits(ws, b)[:, agg.A_WINDOWS :]
-    u_digits = pt.scalar_digits_msb(u % eclib.N)
-    sel = lambda col: [col[rows[i]] for i in idxs]  # noqa: E731
-    return (
-        _be32_to_limbs(sel(prep.pxn), b),
-        _be32_to_limbs(sel(prep.pyn), b),
-        _be32_to_limbs(sel(prep.rxn), b),
-        _be32_to_limbs(sel(prep.ryn), b),
-        c_digits,
-        a_digits,
-        u_digits,
-    ), b
-
-
-def _run_aggregate_shape(b: int, args) -> bool:
-    """One aggregate device dispatch with the same compile bookkeeping as
-    _Batch.run: jit_compile span + warm-manifest entry (family aggregate)
-    on the first sight of a bucket, shape discarded if the compile dies."""
-    from kaspa_tpu.ops.secp256k1 import aggregate as agg
-
-    shape_key = _shape_key(_AGG_KERNEL_NAME, b)
-    new_shape = shape_key not in _seen_shapes
-    if new_shape:
-        _seen_shapes.add(shape_key)
-        _NEW_SHAPES.inc(_AGG_KERNEL_NAME)
-        try:
-            with trace.span("secp.jit_compile", kernel=_AGG_KERNEL_NAME, bucket=b):
-                FAULTS.fire("device.jit_compile")
-                ok = agg.aggregate_check(*args)
-        except BaseException:
-            _seen_shapes.discard(shape_key)
-            raise
-        supervisor.note_shape(_AGG_KERNEL_NAME, b, family="aggregate")
-        return ok
-    return agg.aggregate_check(*args)
-
-
-def _aggregate_device_check(prep: _AggBatch, weights: list, rows: list, idxs: list):
-    """Guarded combined check for one lane subset: True / False, or None
-    when the device is unavailable (breaker open, hang, dispatch error) —
-    the caller then routes the subset to the host degraded lane."""
-    FAULTS.fire("device.verify")
-    FAULTS.fire("device.hang")
-    n = len(idxs)
-    with trace.span("secp.host_marshal", kernel=_AGG_KERNEL_NAME, batch=n) as sp:
-        args, b = _aggregate_args(prep, weights, rows, idxs)
-        sp.set(lanes=b)
-    _AGG_CHECKS.inc()
-    _BATCH_SIZE.observe(n)
-    _OCCUPANCY.observe(100.0 * n / b)
-    _PADDED_LANES.inc(b - n)
-    br = device_breaker()
-    if not br.allow():
-        return None
-    tier = "dispatch" if _shape_key(_AGG_KERNEL_NAME, b) in _seen_shapes else "compile"
-    try:
-        with trace.span("secp.device_dispatch", kernel=_AGG_KERNEL_NAME, batch=n, bucket=b):
-            ok = supervisor.run_supervised(
-                lambda: _run_aggregate_shape(b, args),
-                tier=tier,
-                kernel=_AGG_KERNEL_NAME,
-                jobs=n,
-            )
-    except supervisor.DeviceHangError:
-        br.record_failure(cause=HUNG)
-        supervisor.note_requeue(n)
-        return None
-    except Exception:  # noqa: BLE001 - device boundary: any failure trips
-        br.record_failure()
-        return None
-    br.record_success()
-    return bool(ok)
-
-
-def _resolve_aggregate(prep, weights, rows, idxs, mask, items) -> None:
-    """Recursive bisection to the exact mask.  A passing combined check
-    proves every lane in the subset; a failing one splits in half (both
-    halves re-aggregated under the SAME top-level weights, so one bad
-    signature keeps failing every superset it lands in); subsets at or
-    below the leaf size resolve per-signature on the ladder path."""
-    if not idxs:
-        return
-    if len(idxs) <= _AGG_LEAF:
-        _AGG_LEAF_JOBS.inc(len(idxs))
-        sub_mask = schnorr_verify_batch([items[i] for i in idxs])
-        for k, i in enumerate(idxs):
-            mask[i] = bool(sub_mask[k])
-        return
-    # warm-bucket discipline: a subset that would pad into a never-compiled
-    # bucket splits at the largest warm one instead (each chunk is its own
-    # sound sub-aggregate), exactly like _Batch.run's cold-split
-    b = _bucket(len(idxs))
-    if (
-        _shape_key(_AGG_KERNEL_NAME, b) not in _seen_shapes
-        and _cold_split_enabled()
-        and not getattr(_force_tls, "on", False)
-    ):
-        warm = _largest_warm_below(_AGG_KERNEL_NAME, b)
-        if warm is not None and warm < len(idxs):
-            _COLD_SPLITS.inc(_AGG_KERNEL_NAME)
-            for off in range(0, len(idxs), warm):
-                _resolve_aggregate(
-                    prep, weights, rows, idxs[off : off + warm], mask, items
-                )
-            return
-    ok = _aggregate_device_check(prep, weights, rows, idxs)
-    if ok is True:
-        _DEVICE_JOBS.inc(len(idxs))
-        for i in idxs:
-            mask[i] = True
-        return
-    if ok is None:
-        _AGG_FALLBACK_JOBS.inc(len(idxs))
-        sub_mask = host_verify_batch("schnorr", [items[i] for i in idxs])
-        for k, i in enumerate(idxs):
-            mask[i] = bool(sub_mask[k])
-        return
-    _AGG_BISECT_STEPS.inc()
-    half = len(idxs) // 2
-    _resolve_aggregate(prep, weights, rows, idxs[:half], mask, items)
-    _resolve_aggregate(prep, weights, rows, idxs[half:], mask, items)
-
-
-def schnorr_verify_batch_aggregate(items) -> np.ndarray:
-    """Aggregate-mode schnorr verify: bit-identical mask contract to
-    schnorr_verify_batch, one multi-scalar device pass in the common
-    (all-valid) case.  items: iterable of (pubkey32, msg32, sig64)."""
-    items = list(items)
-    n = len(items)
-    if n == 0:
-        return np.zeros(0, dtype=bool)
-    with trace.span("dispatch.aggregate", jobs=n):
-        with trace.span("secp.host_prepare", kernel=_AGG_KERNEL_NAME, jobs=n):
-            prep = _build_schnorr_aggregate(items)
-            weights = _aggregate_weights(items)
-        _AGG_BATCHES.inc()
-        _AGG_JOBS.inc(n)
-        mask = np.zeros(n, dtype=bool)
-        # rows maps item index -> position in prep's compacted columns
-        # (precheck failures occupy no column and stay False in the mask)
-        rows, idxs, live = {}, [], 0
-        for i, ok in enumerate(prep.ok):
-            if ok:
-                rows[i] = live
-                idxs.append(i)
-                live += 1
-        _resolve_aggregate(prep, weights, rows, idxs, mask, items)
-    return mask
-
-
 def _batch_inverse(values: list, m: int) -> list:
     """Every value's inverse mod the prime m for one pow(): Montgomery's
     trick (running products up, the one inverse peeled back down).  Every
@@ -709,17 +440,8 @@ def ecdsa_verify_batch(items) -> np.ndarray:
 def verify_batch(kind: str, items) -> np.ndarray:
     """Kind-dispatching batched verify ("schnorr" | "ecdsa") — the entry
     the verify fabric's slice workers, the coalescing dispatcher, and the
-    legacy synchronous txscript lane all route through.  Schnorr batches
-    honor the process-wide verify mode (`ops.dispatch.set_verify_mode`):
-    the aggregate RLC lane when selected (or when "auto" says the batch
-    is past the measured crossover), the per-signature ladder otherwise —
-    masks are bit-identical either way."""
-    items = list(items)
+    legacy synchronous txscript lane all route through."""
     if kind == "schnorr":
-        from kaspa_tpu.ops import dispatch as dispatch_mod  # deferred: import DAG
-
-        if dispatch_mod.resolve_verify_mode(kind, len(items)) == "aggregate":
-            return schnorr_verify_batch_aggregate(items)
         return schnorr_verify_batch(items)
     return ecdsa_verify_batch(items)
 
@@ -779,40 +501,6 @@ def canary_probe() -> bool:
 _PRETRACE_KERNELS = {"schnorr_verify": schnorr_verify, "ecdsa_verify": ecdsa_verify}
 
 
-def _pretrace_aggregate_bucket(bucket: int) -> str:
-    """Aggregate-family pretrace: compile the multi-scalar partials +
-    finish kernels at one bucket shape with an all-zero (identity-summing)
-    batch, under the compile-tier watchdog."""
-    if _shape_key(_AGG_KERNEL_NAME, bucket) in _seen_shapes:
-        return "warm"
-    zeros32 = [_ZERO32] * bucket
-    args = (
-        _be32_to_limbs(zeros32, bucket),
-        _be32_to_limbs(zeros32, bucket),
-        _be32_to_limbs(zeros32, bucket),
-        _be32_to_limbs(zeros32, bucket),
-        _scalars_to_digits([0] * bucket, bucket),
-        _scalars_to_digits([0] * bucket, bucket)[:, 32:],
-        pt.scalar_digits_msb(0),
-    )
-
-    def _dispatch():
-        from kaspa_tpu.resilience import faults as faults_mod
-
-        _force_tls.on = True
-        try:
-            with faults_mod.suppress():
-                return _run_aggregate_shape(bucket, args)
-        finally:
-            _force_tls.on = False
-
-    try:
-        supervisor.run_supervised(_dispatch, tier="compile", kernel=_AGG_KERNEL_NAME, jobs=bucket)
-    except Exception as e:  # noqa: BLE001 - pretrace is best-effort
-        return f"error:{type(e).__name__}"
-    return "traced"
-
-
 def pretrace_bucket(kernel_name: str, bucket: int) -> str:
     """Compile one (kernel, bucket) shape ahead of traffic (warm-manifest
     restart path).  Dispatches an all-invalid batch of exactly ``bucket``
@@ -820,8 +508,6 @@ def pretrace_bucket(kernel_name: str, bucket: int) -> str:
     runs under the watchdog's compile tier.  Returns "warm" (already
     compiled this process), "traced", or "error:...".
     """
-    if kernel_name == _AGG_KERNEL_NAME:
-        return _pretrace_aggregate_bucket(bucket) if bucket >= 8 else f"error:unknown {kernel_name}/{bucket}"
     if kernel_name == "muhash_tree":
         from kaspa_tpu.ops import muhash_ops
 

@@ -157,7 +157,7 @@ class VerifyService:
             with self._lock:
                 self._conns.append(conn)
             try:
-                conn.send(wire.encode_hello(self.slices, modes=wire.MODE_AGGREGATE))
+                conn.send(wire.encode_hello(self.slices))
             except OSError:
                 conn.close()
                 continue
@@ -249,11 +249,9 @@ def main(argv=None) -> int:
     ap.add_argument("--slices", type=int, default=None,
                     help="slice worker lanes (default: mesh slice count)")
     ap.add_argument("--mesh", default=None, help="device mesh spec (N | auto | RxC)")
-    # graftlint: allow(env-knob) -- verifyd exists to batch: its CLI default is sweep-seeded auto, deliberately diverging from the in-node default of off
+    # graftlint: allow(env-knob) -- verifyd exists to batch: its CLI default is auto, deliberately diverging from the in-node default of off
     ap.add_argument("--coalesce", default=os.environ.get("KASPA_TPU_COALESCE", "auto"),
                     help="local coalescing target feeding the slices (N | auto | off)")
-    ap.add_argument("--verify-mode", default=None, choices=("ladder", "aggregate", "auto"),
-                    help="schnorr verify lane: per-sig ladder, RLC aggregate, or auto by batch size")
     args = ap.parse_args(argv)
 
     from kaspa_tpu.utils import jax_setup
@@ -265,15 +263,12 @@ def main(argv=None) -> int:
     if args.mesh is not None:
         mesh.configure(args.mesh)
     coalesce.configure(args.coalesce)
-    if args.verify_mode is not None:
-        coalesce.set_verify_mode(args.verify_mode)
 
     svc = VerifyService(args.listen, slices=args.slices)
     host, port = svc.start()
     print(json.dumps({
         "fabric_listen": f"{host}:{port}", "slices": svc.slices,
         "mesh": mesh.active_size(), "pid": os.getpid(),
-        "verify_mode": coalesce.verify_mode(),
     }), flush=True)
 
     done = threading.Event()

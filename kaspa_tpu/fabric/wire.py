@@ -7,7 +7,7 @@ fields (`p2p/proto/wire_format.py` primitives) — no schema compiler, no
 new dependency, same bounds discipline as the P2P wire.
 
     HELLO        server -> client on accept: proto version, slice count,
-                 capability mode flags (proto >= 2, e.g. MODE_AGGREGATE)
+                 capability mode flags (proto >= 2; none defined)
     VERIFY_REQ   req_id, kind, target slice, trace id, [(pub,msg,sig)...]
     VERIFY_RESP  req_id, status; ok: packed mask + server-side timings +
                  the slice's post-completion inflight count (the load
@@ -27,9 +27,6 @@ from kaspa_tpu.p2p.proto.framing import encode_grpc_frame, read_grpc_frame
 from kaspa_tpu.p2p.proto.wire_format import ProtoWireError, decode_varint, encode_varint
 
 PROTO_VERSION = 2
-
-# HELLO capability bitflags (proto >= 2; proto-1 peers simply omit them)
-MODE_AGGREGATE = 0x01  # server can run schnorr RLC aggregate verification
 
 HELLO = 0x01
 VERIFY_REQ = 0x02

@@ -114,15 +114,8 @@ def parse_args(argv=None) -> DaemonArgs:
     p.add_argument(
         "--coalesce", default=None, metavar="N",
         help="coalesce signature verify jobs across blocks into super-batches of "
-        "up to N jobs before device dispatch (default off; 'auto' seeds the "
-        "target from BENCH_SWEEP.json; flush age via KASPA_TPU_COALESCE_AGE_MS)",
-    )
-    p.add_argument(
-        "--verify-mode", choices=("ladder", "aggregate", "auto"), default=None,
-        help="schnorr batch-verify lane: per-signature ladders (default), the "
-        "aggregated random-linear-combination multi-scalar check, or 'auto' "
-        "(aggregate at/above BENCH_SWEEP.json's measured crossover batch); "
-        "results are bit-identical either way",
+        "up to N jobs before device dispatch (default off; 'auto' = 1024; "
+        "flush age via KASPA_TPU_COALESCE_AGE_MS)",
     )
     p.add_argument(
         "--fabric", nargs="+", default=None, metavar=("MODE", "ADDR"),
@@ -389,13 +382,8 @@ class Daemon:
         # through the mesh once configured (> 1)
         self.mesh_size = mesh_dispatch.configure(getattr(args, "mesh", None))
         # process-wide: verify jobs coalesce across blocks/callers into
-        # super-batches once configured (> 0); mesh must resolve first so
-        # 'auto' picks the sweep's best batch for the active mesh size
+        # super-batches once configured (> 0)
         self.coalesce_target = verify_dispatch.configure(getattr(args, "coalesce", None))
-        # process-wide: which schnorr batch-verify lane dispatch resolves to
-        # (ladder / aggregate / auto-by-crossover); bit-identical either way
-        if getattr(args, "verify_mode", None) is not None:
-            verify_dispatch.set_verify_mode(args.verify_mode)
         fab = getattr(args, "fabric", None) or []
         self.fabric_mode = fab[0] if fab else None
         if self.fabric_mode not in (None, "serve", "connect"):
@@ -565,8 +553,6 @@ class Daemon:
             self.log.info("mesh dispatch enabled over %d devices", self.mesh_size)
         if self.coalesce_target:
             self.log.info("verify coalescing enabled, super-batch target %d", self.coalesce_target)
-        if verify_dispatch.verify_mode() != "ladder":
-            self.log.info("schnorr verify mode: %s", verify_dispatch.verify_mode())
         self.core = Core()
         self.perf_monitor = PerfMonitor()
         self.metrics_data = MetricsData()

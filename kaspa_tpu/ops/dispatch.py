@@ -8,8 +8,8 @@ from *concurrent* callers — pipeline stage workers, mempool checks, RPC
 validators — accumulate into device-sized super-batches and are flushed
 by a dedicated dispatcher thread:
 
-- **size**: a kind's pending jobs reach the adaptive target (seeded from
-  ``BENCH_SWEEP.json``'s best batch for the active mesh, fallback 1024);
+- **size**: a kind's pending jobs reach the target (``DEFAULT_TARGET``
+  unless the caller pins one);
 - **age**: the oldest queued chunk exceeds the flush age
   (``KASPA_TPU_COALESCE_AGE_MS``, default 2 ms);
 - **nudge**: a caller blocks on its ticket — the queue flushes as soon
@@ -42,14 +42,13 @@ class carries its own coalesce target (``KASPA_TPU_TX_COALESCE``, default
 256) and flush age (``KASPA_TPU_TX_COALESCE_AGE_MS``, default 5 ms);
 flush triggers, chunk packing, and span/counter attribution all key on
 the full qualified kind, while the device call maps back to the base
-kind — so the aggregate/auto verify-mode crossover, the fabric balancer,
-breaker degradation, and host fallback are inherited unchanged.
+kind — so the fabric balancer, breaker degradation, and host fallback are
+inherited unchanged.
 """
 
 from __future__ import annotations
 
 import itertools
-import json
 import os
 import threading
 
@@ -466,11 +465,8 @@ class CoalescingDispatcher:
         items = [it for c in batch for it in c.items]
         try:
             t0 = perf_counter_ns()
-            # verify_batch resolves the process-wide verify mode, so a
-            # coalesced schnorr super-batch takes the aggregate RLC lane
-            # exactly when a direct caller's batch of the same size would;
             # class-qualified kinds map to their base kernel here, keeping
-            # the crossover/fabric/breaker behavior identical per class
+            # the fabric/breaker behavior identical per class
             with trace.span("dispatch.super_batch", kind=kind, jobs=jobs, chunks=len(batch)):
                 mask = np.asarray(secp.verify_batch(base_kind(kind), items))
             t1 = perf_counter_ns()
@@ -528,67 +524,6 @@ _cfg_lock = ranked_lock("dispatch.config")
 _configured: str | int | None = None
 _engine: CoalescingDispatcher | None = None
 
-# --- verify-mode selection (ladder | aggregate | auto) ----------------------
-# The dispatch module owns which schnorr lane runs: the per-signature dual
-# ladder, or the aggregate RLC multi-scalar lane (ops/secp256k1/aggregate).
-# "auto" consults the bench sweep's measured crossover batch size — below
-# it the per-batch doubling chain + bisection risk outweigh the saved
-# ladders.  secp.verify_batch calls resolve_verify_mode() on every batch,
-# so the legacy synchronous txscript lane, the coalescing dispatcher, and
-# the fabric slice workers all honor one process-wide knob.
-
-VERIFY_MODES = ("ladder", "aggregate", "auto")
-_DEFAULT_AGG_CROSSOVER = 64  # conservative floor when no sweep artifact exists
-_verify_mode: str | None = None  # None -> consult KASPA_TPU_VERIFY_MODE
-
-
-def set_verify_mode(mode: str | None) -> str:
-    """Pin the process-wide schnorr verify mode; None re-reads the
-    KASPA_TPU_VERIFY_MODE env var (default "ladder").  Returns the raw
-    mode now in force."""
-    global _verify_mode
-    if mode is not None and mode not in VERIFY_MODES:
-        raise ValueError(f"verify mode {mode!r} not in {VERIFY_MODES}")
-    with _cfg_lock:
-        _verify_mode = mode
-    return verify_mode()
-
-
-def verify_mode() -> str:
-    """The raw configured mode ("ladder" | "aggregate" | "auto")."""
-    m = _verify_mode
-    if m is None:
-        m = os.environ.get("KASPA_TPU_VERIFY_MODE", "ladder")
-    return m if m in VERIFY_MODES else "ladder"
-
-
-def _aggregate_crossover() -> int:
-    """Batch size where the aggregate lane starts winning, from the bench
-    sweep artifact's ``aggregate.crossover_batch`` (bench.py --sweep), with
-    a conservative default when no measurement exists."""
-    path = os.environ.get(
-        "KASPA_TPU_BENCH_SWEEP_PATH",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "BENCH_SWEEP.json"),
-    )
-    try:
-        with open(path) as f:
-            agg = json.load(f).get("aggregate", {})
-        x = int(agg.get("crossover_batch", 0))
-        return x if x > 0 else _DEFAULT_AGG_CROSSOVER
-    except (OSError, ValueError, TypeError):
-        return _DEFAULT_AGG_CROSSOVER
-
-
-def resolve_verify_mode(kind: str, jobs: int) -> str:
-    """The lane one concrete batch should take: "ladder" or "aggregate"."""
-    if base_kind(kind) != "schnorr" or jobs <= 0:
-        return "ladder"
-    m = verify_mode()
-    if m == "auto":
-        return "aggregate" if jobs >= _aggregate_crossover() else "ladder"
-    return m
-
-
 def _flush_age_s() -> float:
     return float(os.environ.get("KASPA_TPU_COALESCE_AGE_MS", "2")) / 1000.0
 
@@ -605,34 +540,12 @@ def _tx_class_spec(block_target: int) -> tuple[int, float]:
     return max(_TARGET_MIN, min(_TARGET_MAX, target)), age
 
 
-def _sweep_target() -> int:
-    """Adaptive super-batch target: the best-throughput batch recorded by
-    `bench.py --sweep` for the active mesh size, else DEFAULT_TARGET."""
-    path = os.environ.get(
-        "KASPA_TPU_BENCH_SWEEP_PATH",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "BENCH_SWEEP.json"),
-    )
-    try:
-        with open(path) as f:
-            best = json.load(f).get("best", {})
-    except (OSError, ValueError):
-        return DEFAULT_TARGET
-    from kaspa_tpu.ops import mesh
-
-    n = mesh.active_size()
-    for key in (f"schnorr/mesh{n}", "schnorr/mesh1"):
-        entry = best.get(key)
-        if entry and entry.get("batch"):
-            return int(entry["batch"])
-    return DEFAULT_TARGET
-
-
 def configure(spec: int | str | None) -> int:
     """Select the process-wide coalescing mode; returns the resolved
     super-batch target (0 = disabled, the default).
 
-    spec: None/0/"off" disable; "auto" seeds the target from
-    BENCH_SWEEP.json; an integer pins the target.  With no explicit spec
+    spec: None/0/"off" disable; "auto" is DEFAULT_TARGET; an integer pins
+    the target.  With no explicit spec
     the KASPA_TPU_COALESCE env var is consulted the same way.
     """
     global _configured, _engine
@@ -644,7 +557,7 @@ def configure(spec: int | str | None) -> int:
         old.close(timeout=10.0)
     if raw in (0, "0", "", "off", None):
         return 0
-    target = _sweep_target() if raw == "auto" else int(raw)
+    target = DEFAULT_TARGET if raw == "auto" else int(raw)
     target = max(_TARGET_MIN, min(_TARGET_MAX, target))
     with _cfg_lock:
         _engine = CoalescingDispatcher(
@@ -693,12 +606,8 @@ def shutdown(timeout: float = 10.0) -> bool:
 def _dispatch_state() -> dict:
     eng = _engine
     if eng is None:
-        return {
-            "enabled": False,
-            "configured": str(_configured) if _configured is not None else "",
-            "verify_mode": verify_mode(),
-        }
-    out = {"enabled": True, "configured": str(_configured), "verify_mode": verify_mode()}
+        return {"enabled": False, "configured": str(_configured) if _configured is not None else ""}
+    out = {"enabled": True, "configured": str(_configured)}
     out.update(eng.stats())
     return out
 

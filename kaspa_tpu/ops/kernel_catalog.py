@@ -31,11 +31,10 @@ from dataclasses import dataclass
 
 LIMBS = 16  # 256-bit field elements: 16 x 16-bit limbs (ops/secp256k1)
 DIGITS = 64  # 4-bit MSB window digits per 256-bit scalar
-A_WINDOWS = 32  # aggregate weights are 128-bit: only the low window half ships
 MUHASH_LIMBS = 192  # 3072-bit muhash elements: 192 x 16-bit limbs
 
 # secp._bucket pads to powers of two, min 8; the dispatch tiers cap
-# coalesced batches at 1024 (BENCH_SWEEP targets stay inside this ladder)
+# coalesced batches at 1024
 VERIFY_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024)
 MUHASH_BUCKETS = (64, 1024)  # mirrors ops.muhash_ops.BUCKETS
 MESH_SIZES = (1, 2, 4, 8)  # KASPA_TPU_MESH values the partition rules serve
@@ -43,7 +42,7 @@ MESH_SIZES = (1, 2, 4, 8)  # KASPA_TPU_MESH values the partition rules serve
 
 @dataclass(frozen=True)
 class Family:
-    name: str  # warm-manifest "family"
+    name: str
     kernel: str  # warm-manifest "kernel"
     buckets: tuple
     mesh_sizes: tuple
@@ -52,7 +51,6 @@ class Family:
 FAMILIES: dict[str, Family] = {
     "ladder": Family("ladder", "schnorr_verify", VERIFY_BUCKETS, MESH_SIZES),
     "ecdsa": Family("ecdsa", "ecdsa_verify", VERIFY_BUCKETS, MESH_SIZES),
-    "aggregate": Family("aggregate", "schnorr_aggregate", VERIFY_BUCKETS, MESH_SIZES),
     # the 3072-bit tree product shards whole buckets, not lanes: audit the
     # fixed buckets at mesh 1 (mesh dispatch reuses the same bucket shapes)
     "muhash": Family("muhash", "muhash_tree", MUHASH_BUCKETS, (1,)),
@@ -65,7 +63,6 @@ FAMILIES: dict[str, Family] = {
 WARM_COVERAGE: tuple[tuple[str, int, int], ...] = (
     ("ladder", 8, 1024),
     ("ecdsa", 8, 1024),
-    ("aggregate", 8, 1024),
     ("muhash", 64, 1024),
 )
 
@@ -128,27 +125,6 @@ def audit_signature(row: dict) -> str | None:
             )
             if out.shape != (shard,) or out.dtype != jnp.bool_:
                 return f"verify mask drifted: got {out.shape}/{out.dtype}, want ({shard},)/bool"
-        elif fam == "aggregate":
-            from kaspa_tpu.ops.secp256k1 import aggregate as agg
-
-            parts = jax.eval_shape(
-                agg.aggregate_partials_kernel,
-                _i32(shard, LIMBS), _i32(shard, LIMBS),
-                _i32(shard, LIMBS), _i32(shard, LIMBS),
-                _i32(shard, DIGITS), _i32(shard, DIGITS - agg.A_WINDOWS),
-            )
-            if len(parts) != 3 or any(
-                p.shape != (DIGITS, LIMBS) or p.dtype != jnp.int32 for p in parts
-            ):
-                got = [(p.shape, str(p.dtype)) for p in parts]
-                return f"aggregate partials drifted: got {got}, want 3x(({DIGITS}, {LIMBS})/int32)"
-            fin = jax.eval_shape(
-                agg.aggregate_reduce_finish_kernel,
-                _i32(mesh, DIGITS, LIMBS), _i32(mesh, DIGITS, LIMBS),
-                _i32(mesh, DIGITS, LIMBS), _i32(DIGITS),
-            )
-            if fin.shape != () or fin.dtype != jnp.bool_:
-                return f"aggregate finish drifted: got {fin.shape}/{fin.dtype}, want ()/bool"
         elif fam == "muhash":
             from kaspa_tpu.ops import muhash_ops
 
@@ -165,26 +141,6 @@ def audit_signature(row: dict) -> str | None:
     return None
 
 
-def _audit_agg_finish(mesh: int) -> str | None:
-    """eval_shape only the aggregate finish kernel at one mesh width."""
-    import jax
-    import jax.numpy as jnp
-
-    from kaspa_tpu.ops.secp256k1 import aggregate as agg
-
-    try:
-        fin = jax.eval_shape(
-            agg.aggregate_reduce_finish_kernel,
-            _i32(mesh, DIGITS, LIMBS), _i32(mesh, DIGITS, LIMBS),
-            _i32(mesh, DIGITS, LIMBS), _i32(DIGITS),
-        )
-        if fin.shape != () or fin.dtype != jnp.bool_:
-            return f"aggregate finish drifted: got {fin.shape}/{fin.dtype}, want ()/bool"
-    except Exception as e:  # noqa: BLE001
-        return f"eval_shape failed: {type(e).__name__}: {e}"
-    return None
-
-
 def audit_all(rows: list[dict]) -> tuple[list[tuple[dict, str]], int]:
     """Audit every row with a minimal set of eval_shape traces:
     ``([(representative_row, error)...], traces_performed)``.
@@ -193,14 +149,13 @@ def audit_all(rows: list[dict]) -> tuple[list[tuple[dict, str]], int]:
     trace time) and its graph — so any dtype drift in it — is identical
     across batch widths: the kernels take no static arguments, only the
     batch axis changes.  One representative trace per kernel therefore
-    validates the whole bucket ladder.  The exceptions re-trace: the
-    aggregate *finish* kernel's shard axis is the mesh width (one trace
-    per distinct mesh), and ``_tree_product``'s ``levels`` static
-    argument changes the graph per muhash bucket (one trace per bucket).
+    validates the whole bucket ladder.  The exception re-traces:
+    ``_tree_product``'s ``levels`` static argument changes the graph per
+    muhash bucket (one trace per bucket).
     """
     errors: list[tuple[dict, str]] = []
     traces = 0
-    for fam in ("ladder", "ecdsa", "aggregate"):
+    for fam in ("ladder", "ecdsa"):
         frows = [r for r in rows if r["family"] == fam]
         if not frows:
             continue
@@ -209,16 +164,6 @@ def audit_all(rows: list[dict]) -> tuple[list[tuple[dict, str]], int]:
         err = audit_signature(rep)
         if err is not None:
             errors.append((rep, err))
-        if fam == "aggregate":
-            for mesh in sorted({r["mesh"] for r in frows} - {rep["mesh"]}):
-                traces += 1
-                err = _audit_agg_finish(mesh)
-                if err is not None:
-                    frep = min(
-                        (r for r in frows if r["mesh"] == mesh),
-                        key=lambda r: r["shard"],
-                    )
-                    errors.append((frep, err))
     for row in (r for r in rows if r["family"] == "muhash"):
         traces += 1
         err = audit_signature(row)

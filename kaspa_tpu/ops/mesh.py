@@ -251,11 +251,8 @@ def _slice_mesh(r: int, c: int, idx: int):
 # replicates.  register_partition_rule() lets a new kernel claim a layout
 # without touching the dispatch plumbing.
 DEFAULT_PARTITION_RULES: tuple = (
-    (r"(px|py|rc|pxn|pyn|rxn|ryn|.*digits|elements)$", (("slice", "shard"), None)),
+    (r"(px|py|rc|.*digits|elements)$", (("slice", "shard"), None)),
     (r"(valid_in|mask)$", (("slice", "shard"),)),
-    # aggregate window partials: each shard emits a [1, 64, W] stack —
-    # batch-sharded on the leading axis, windows/limbs replicated
-    (r"partials$", (("slice", "shard"), None, None)),
     (r".*", ()),  # replicate
 )
 
@@ -464,90 +461,6 @@ def dispatch_verify(kind: str, px, py, rc, d1_digits, d2_digits, valid_in) -> np
         _SLICE_DISPATCHES.inc(str(pin))
         _SLICE_JOBS.inc(str(pin), b)
     return mask
-
-
-# --- aggregate RLC window partials -----------------------------------------
-#
-# The aggregate multi-scalar kernel (ops/secp256k1/aggregate.py) shards the
-# same way as verify — pure batch-dim data parallelism — but each shard
-# returns its lanes' [64] window-sum points instead of a mask slice; the
-# [n, 64] stack combines in the (tiny, unsharded) reduce/finish kernel, the
-# muhash partial-product pattern applied to the EC group.
-
-_AGG_ARG_NAMES = ("pxn", "pyn", "rxn", "ryn", "c_digits", "a_digits")
-
-
-def _agg_local_kernel():
-    from kaspa_tpu.ops.secp256k1 import aggregate as agg
-
-    raw = agg.aggregate_partials_kernel.__wrapped__
-
-    def local(*args):
-        sx, sy, sz = raw(*args)
-        return sx[None], sy[None], sz[None]  # leading shard axis for out spec
-
-    return local
-
-
-@functools.lru_cache(maxsize=None)
-def _agg_entry(n: int):
-    in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _AGG_ARG_NAMES)
-    out_spec = partition_spec_for("partials", flat=True)
-    return _sharded_jit(_agg_local_kernel(), _mesh(n), in_specs, (out_spec,) * 3)
-
-
-@functools.lru_cache(maxsize=None)
-def _agg_entry_2d(r: int, c: int):
-    in_specs = tuple(partition_spec_for(nm) for nm in _AGG_ARG_NAMES)
-    out_spec = partition_spec_for("partials")
-    return _sharded_jit(_agg_local_kernel(), _mesh2d(r, c), in_specs, (out_spec,) * 3)
-
-
-@functools.lru_cache(maxsize=None)
-def _agg_entry_slice(r: int, c: int, idx: int):
-    in_specs = tuple(partition_spec_for(nm, flat=True) for nm in _AGG_ARG_NAMES)
-    out_spec = partition_spec_for("partials", flat=True)
-    return _sharded_jit(_agg_local_kernel(), _slice_mesh(r, c, idx), in_specs, (out_spec,) * 3)
-
-
-def dispatch_aggregate_partials(pxn, pyn, rxn, ryn, c_digits, a_digits):
-    """Batch-dim sharded aggregate partials: pads lanes to a shard
-    multiple (all-zero rows select only identity table entries, so pads
-    contribute nothing), returns (Sx, Sy, Sz) each [n, 64, W] — one
-    window-sum stack per shard, combined by the reduce/finish kernel.
-
-    Slice pinning works exactly as dispatch_verify: a thread inside
-    ``slice_lane(i)`` runs on slice i's devices only.
-    """
-    from kaspa_tpu.resilience.faults import FAULTS
-
-    FAULTS.fire("device.mesh.dispatch")
-    total = active_size()
-    g = _grid
-    pin = getattr(_slice_tls, "idx", None) if g else None
-    if g is None:
-        n, entry = total, _agg_entry(total)
-    elif pin is not None:
-        n, entry = g[1], _agg_entry_slice(g[0], g[1], pin)
-    else:
-        n, entry = total, _agg_entry_2d(g[0], g[1])
-    pxn = np.asarray(pxn)
-    b = pxn.shape[0]
-    m = -(-b // n) * n  # ceil to shard multiple
-    args = (
-        _pad_rows(pxn, m),
-        _pad_rows(pyn, m),
-        _pad_rows(rxn, m),
-        _pad_rows(ryn, m),
-        _pad_rows(c_digits, m),
-        _pad_rows(a_digits, m),
-    )
-    sx, sy, sz = entry(*args)
-    _observe("schnorr_aggregate", b, m, n)
-    if pin is not None:
-        _SLICE_DISPATCHES.inc(str(pin))
-        _SLICE_JOBS.inc(str(pin), b)
-    return sx, sy, sz
 
 
 # --- muhash tree product ---------------------------------------------------

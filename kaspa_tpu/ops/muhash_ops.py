@@ -104,7 +104,7 @@ def _note_bucket(bucket: int) -> None:
     try:
         from kaspa_tpu.resilience import supervisor
 
-        supervisor.note_shape("muhash_tree", bucket, family="muhash")
+        supervisor.note_shape("muhash_tree", bucket)
     except Exception:  # noqa: BLE001 - the manifest is an optimization
         pass
 

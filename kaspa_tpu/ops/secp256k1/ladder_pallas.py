@@ -49,7 +49,6 @@ from kaspa_tpu.ops import bigint as bi
 
 W8 = 32  # 8-bit limbs per 256-bit element
 BLK = 256  # batch lanes per grid step
-N_WIN = 33  # 4-bit windows per GLV half-scalar (|k1|,|k2| < 2**132)
 
 SECP_P = bi.SECP_P
 SECP_N = bi.SECP_N
@@ -64,9 +63,9 @@ def launched_lanes(b: int) -> int:
     return -(-b // BLK) * BLK
 
 
-def _kernel_name(ecdsa: bool, glv: bool) -> str:
+def _kernel_name(ecdsa: bool) -> str:
     """The Mosaic call's name on the device (profiler events, HLO metadata)."""
-    return "secp256k1_ladder_" + ("ecdsa" if ecdsa else "schnorr") + ("_glv" if glv else "")
+    return "secp256k1_ladder_" + ("ecdsa" if ecdsa else "schnorr")
 
 
 def _c_digits(c: int) -> tuple[int, ...]:
@@ -97,47 +96,10 @@ def _m_limbs8(m: int) -> np.ndarray:
 _MP8 = _m_limbs8(SECP_P)
 _MN8 = _m_limbs8(SECP_N)
 
-# --- GLV endomorphism -------------------------------------------------------
-# secp256k1 has an order-3 automorphism phi(x, y) = (beta*x, y) acting as
-# scalar multiplication by lambda; splitting each 256-bit scalar into two
-# signed ~128-bit halves over the reduced lattice below halves the shared
-# doubling chain (64 -> 33 windows).  The constants are validated here, not
-# trusted: lambda**3 == 1 (mod n), beta**3 == 1 (mod p), phi(G) == lambda*G.
-
-GLV_LAMBDA = 0x5363AD4CC05C30E0A5261C028812645A122E22EA20816678DF02967C1B23BD72
-GLV_BETA = 0x7AE96A2B657C07106E64479EAC3434E99CF0497512F58995C1396C28719501EE
-# Lagrange-Gauss reduced basis of {(x, y) : x + y*lambda == 0 (mod n)}
-_GLV_U = (64502973549206556628585045361533709077, -303414439467246543595250775667605759171)
-_GLV_V = (367917413016453100223835821029139468248, 64502973549206556628585045361533709077)
-_GLV_DET = _GLV_U[0] * _GLV_V[1] - _GLV_V[0] * _GLV_U[1]
-
-assert pow(GLV_LAMBDA, 3, SECP_N) == 1 and GLV_LAMBDA != 1
-assert pow(GLV_BETA, 3, SECP_P) == 1 and GLV_BETA != 1
-assert (_GLV_U[0] + _GLV_U[1] * GLV_LAMBDA) % SECP_N == 0
-assert (_GLV_V[0] + _GLV_V[1] * GLV_LAMBDA) % SECP_N == 0
-
-
-def _rdiv(a: int, b: int) -> int:
-    """Exact round(a/b) for ints (b > 0 after normalisation)."""
-    if b < 0:
-        a, b = -a, -b
-    return (2 * a + b) // (2 * b)
-
-
-def glv_split(k: int) -> tuple[int, int]:
-    """k -> (k1, k2), k1 + k2*lambda == k (mod n), |k1|,|k2| <~ 2**128."""
-    c1 = _rdiv(k * _GLV_V[1], _GLV_DET)
-    c2 = _rdiv(-k * _GLV_U[1], _GLV_DET)
-    k1 = k - c1 * _GLV_U[0] - c2 * _GLV_V[0]
-    k2 = -(c1 * _GLV_U[1] + c2 * _GLV_V[1])
-    return k1, k2
-
-
-# G / phi(G) multiples tables (1..15, entry 0 placeholder), transposed [W8, 16]
+# G multiples table (1..15, entry 0 placeholder), transposed [W8, 16]
 def _gtab8():
     from kaspa_tpu.crypto import eclib
 
-    assert eclib.point_mul(eclib.G, GLV_LAMBDA) == ((GLV_BETA * eclib.GX) % SECP_P, eclib.GY)
     pts = []
     acc = None
     for _ in range(15):
@@ -145,14 +107,11 @@ def _gtab8():
         pts.append(acc)
     pts = [pts[0]] + pts
     gx = np.stack([int_to_limbs8(q[0]) for q in pts], axis=1)  # [W8, 16]
-    gxb = np.stack([int_to_limbs8(q[0] * GLV_BETA % SECP_P) for q in pts], axis=1)
     gy = np.stack([int_to_limbs8(q[1]) for q in pts], axis=1)
-    return gx, gxb, gy
+    return gx, gy
 
 
-_GTAB8_X, _GTAB8_XB, _GTAB8_Y = _gtab8()
-_BETA8 = int_to_limbs8(GLV_BETA).reshape(W8, 1)
-
+_GTAB8_X, _GTAB8_Y = _gtab8()
 
 
 # ---------------------------------------------------------------------------
@@ -454,111 +413,13 @@ def _select_gtab(gtx, gty, digit):
     return gx, gy
 
 
-def _cond_negate(y, sign_mask):
-    """y -> -y mod p where sign_mask (int32 [1, L]) is 1."""
-    yn = _neg(y)
-    return yn * sign_mask + y * (1 - sign_mask)
-
-
 def _verify_kernel(
-    ecdsa: bool, gtx_ref, gtxb_ref, gty_ref, mp_ref, mn_ref, beta_ref,
-    px_ref, py_ref, rc_ref, g1_ref, g2_ref, p1_ref, p2_ref, sgn_ref, vin_ref,
-    out_ref, tabx, tabxb, taby, tabz,
-):
-    """GLV quad-scalar ladder: R = (g1 + lam*g2)*G + (p1 + lam*p2)*P.
-
-    Four signed ~128-bit digit streams share one 33-window doubling chain:
-    G and phi(G) add mixed-affine from constant tables; P and phi(P) add
-    projective from the per-lane scratch tables (phi only rescales X by
-    beta, so the phi tables share Y/Z).  sgn_ref row 0 packs the four
-    half-scalar sign bits.
-    """
-    lanes = px_ref.shape[1]
-    px = px_ref[:]
-    py = py_ref[:]
-    if not ecdsa:
-        py = _neg(py)  # BIP340: R = s*G + e*(-P)
-
-    # P multiples table 0..15 (entry 0 = identity; complete adds handle it)
-    zero = jnp.zeros((W8, lanes), dtype=jnp.int32)
-    one = jnp.concatenate([jnp.ones((1, lanes), jnp.int32), zero[1:]], axis=0)
-    beta = jnp.broadcast_to(beta_ref[:], (W8, lanes))
-    tabx[0] = zero
-    tabxb[0] = zero
-    taby[0] = one
-    tabz[0] = zero
-    tabx[1] = px
-    tabxb[1] = _mul(px, beta)
-    taby[1] = py
-    tabz[1] = one
-
-    def build(e, _):
-        prev = (
-            tabx[pl.ds(e - 1, 1)].reshape(W8, lanes),
-            taby[pl.ds(e - 1, 1)].reshape(W8, lanes),
-            tabz[pl.ds(e - 1, 1)].reshape(W8, lanes),
-        )
-        nx, ny, nz = _pt_add(prev, (px, py, one))
-        tabx[pl.ds(e, 1)] = nx.reshape(1, W8, lanes)
-        tabxb[pl.ds(e, 1)] = _mul(nx, beta).reshape(1, W8, lanes)
-        taby[pl.ds(e, 1)] = ny.reshape(1, W8, lanes)
-        tabz[pl.ds(e, 1)] = nz.reshape(1, W8, lanes)
-        return 0
-
-    jax.lax.fori_loop(2, 16, build, 0)
-
-    gtx = gtx_ref[:]
-    gtxb = gtxb_ref[:]
-    gty = gty_ref[:]
-    sgn = sgn_ref[0:1, :]
-
-    def window(w, r):
-        for _ in range(4):
-            r = _pt_double(r)
-        # fixed-base streams: G (digits g1) and phi(G) (digits g2)
-        for dig_ref, xtab, bit in ((g1_ref, gtx, 0), (g2_ref, gtxb, 1)):
-            gd = dig_ref[pl.ds(w, 1), :]
-            gx, gy = _select_gtab(xtab, gty, gd)
-            gy = _cond_negate(gy, (sgn >> bit) & 1)
-            ra = _pt_add_mixed(r, (gx, gy))
-            keep = (gd == 0).astype(jnp.int32)
-            r = tuple(a * keep + b * (1 - keep) for a, b in zip(r, ra))
-        # per-lane streams: P (digits p1) and phi(P) (digits p2)
-        for dig_ref, xtab, bit in ((p1_ref, tabx, 2), (p2_ref, tabxb, 3)):
-            pd = dig_ref[pl.ds(w, 1), :]
-            qx, qy, qz = _select_ptab(xtab, taby, tabz, pd)
-            qy = _cond_negate(qy, (sgn >> bit) & 1)
-            r = _pt_add(r, (qx, qy, qz))
-        return r
-
-    x, y, z = jax.lax.fori_loop(0, N_WIN, window, _pt_identity(lanes))
-
-    mp = mp_ref[:]
-    zc = _canon(z, mp)
-    inf = jnp.all(zc == 0, axis=0, keepdims=True)
-    zi = _inv(z)
-    xa = _canon(_mul(x, zi), mp)
-    if ecdsa:
-        # x mod n: x < p < 2n, so a single conditional subtract suffices
-        xn = _cond_sub_m(mn_ref[:], xa)
-        ok = jnp.all(xn == rc_ref[:], axis=0, keepdims=True)
-    else:
-        ok = jnp.all(xa == rc_ref[:], axis=0, keepdims=True)
-        ya = _canon(_mul(y, zi), mp)
-        ok = ok & ((ya[0:1] & 1) == 0)
-    ok = ok & ~inf & (vin_ref[0:1] > 0)
-    out_ref[:] = jnp.broadcast_to(ok.astype(jnp.int32), (8, lanes))
-
-
-def _verify_kernel_plain(
     ecdsa: bool, gtx_ref, gty_ref, mp_ref, mn_ref,
     px_ref, py_ref, rc_ref, sd_ref, ed_ref, vin_ref, out_ref, tabx, taby, tabz,
 ):
-    """Non-GLV dual-scalar ladder (64 unsigned 4-bit windows).
-
-    The production path: the GLV quad-stream kernel above is ~25% lighter
-    arithmetically but has never produced a mask on a TPU, so it stays
-    opt-in (KASPA_TPU_GLV=1)."""
+    """Dual-scalar ladder (64 unsigned 4-bit windows): R = k1*G + k2*P, G
+    added mixed-affine from the constant table, P projective from the
+    per-lane scratch tables."""
     lanes = px_ref.shape[1]
     px = px_ref[:]
     py = py_ref[:]
@@ -611,6 +472,7 @@ def _verify_kernel_plain(
     zi = _inv(z)
     xa = _canon(_mul(x, zi), mp)
     if ecdsa:
+        # x mod n: x < p < 2n, so a single conditional subtract suffices
         xn = _cond_sub_m(mn_ref[:], xa)
         ok = jnp.all(xn == rc_ref[:], axis=0, keepdims=True)
     else:
@@ -621,14 +483,14 @@ def _verify_kernel_plain(
     out_ref[:] = jnp.broadcast_to(ok.astype(jnp.int32), (8, lanes))
 
 
-# one lane of the array a plain call takes: px | py | rc | k1 | k2, each the
+# one lane of the array a call takes: px | py | rc | k1 | k2, each the
 # 32 big-endian bytes the wire has, then the valid byte
 LANE_BYTES = 5 * 32 + 1
 
 
 def pack_lanes(px, py, rc, k1, k2, valid_in, lanes: int) -> np.ndarray:
     """Host: a batch's byte columns -> the one ``uint8 [LANE_BYTES, lanes]``
-    array a plain call takes (the lane axis last, as the kernel has it).
+    array a call takes (the lane axis last, as the kernel has it).
 
     px/py/rc: n 32-byte big-endian strings each; k1/k2: n scalars, python
     ints or canonical 32-byte strings (the schnorr s column's wire form);
@@ -657,7 +519,7 @@ def unpack_lanes(packed):
 
 
 @functools.lru_cache(maxsize=None)
-def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
+def _build_call(n_padded: int, ecdsa: bool, interpret: bool):
     grid = n_padded // BLK
 
     def const_spec(shape):
@@ -667,7 +529,7 @@ def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
     dig_spec = pl.BlockSpec((64, BLK), lambda i: (0, i), memory_space=pltpu.VMEM)
     v_spec = pl.BlockSpec((8, BLK), lambda i: (0, i), memory_space=pltpu.VMEM)
     call = pl.pallas_call(
-        functools.partial(_verify_kernel_plain, ecdsa),
+        functools.partial(_verify_kernel, ecdsa),
         out_shape=jax.ShapeDtypeStruct((8, n_padded), jnp.int32),
         grid=(grid,),
         in_specs=[
@@ -689,7 +551,7 @@ def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
             pltpu.VMEM((16, W8, BLK), jnp.int32),
         ],
         interpret=interpret,
-        name=_kernel_name(ecdsa, glv=False),
+        name=_kernel_name(ecdsa),
     )
 
     @jax.jit
@@ -699,93 +561,7 @@ def _build_call_plain(n_padded: int, ecdsa: bool, interpret: bool):
     return run
 
 
-@functools.lru_cache(maxsize=None)
-def _build_call(n_padded: int, ecdsa: bool, interpret: bool):
-    grid = n_padded // BLK
-
-    def const_spec(shape):
-        return pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
-
-    limb_spec = pl.BlockSpec((W8, BLK), lambda i: (0, i), memory_space=pltpu.VMEM)
-    dig_spec = pl.BlockSpec((N_WIN, BLK), lambda i: (0, i), memory_space=pltpu.VMEM)
-    v_spec = pl.BlockSpec((8, BLK), lambda i: (0, i), memory_space=pltpu.VMEM)
-    call = pl.pallas_call(
-        functools.partial(_verify_kernel, ecdsa),
-        out_shape=jax.ShapeDtypeStruct((8, n_padded), jnp.int32),
-        grid=(grid,),
-        in_specs=[
-            const_spec((W8, 16)),   # gtx
-            const_spec((W8, 16)),   # gtxb (beta-scaled)
-            const_spec((W8, 16)),   # gty
-            const_spec((W8, 1)),    # modulus p
-            const_spec((W8, 1)),    # modulus n
-            const_spec((W8, 1)),    # beta
-            limb_spec,              # px
-            limb_spec,              # py
-            limb_spec,              # rc
-            dig_spec,               # g1 digits
-            dig_spec,               # g2 digits
-            dig_spec,               # p1 digits
-            dig_spec,               # p2 digits
-            v_spec,                 # sign bits
-            v_spec,                 # valid_in
-        ],
-        out_specs=v_spec,
-        scratch_shapes=[
-            pltpu.VMEM((16, W8, BLK), jnp.int32),  # tabx
-            pltpu.VMEM((16, W8, BLK), jnp.int32),  # tabxb
-            pltpu.VMEM((16, W8, BLK), jnp.int32),  # taby
-            pltpu.VMEM((16, W8, BLK), jnp.int32),  # tabz
-        ],
-        interpret=interpret,
-        name=_kernel_name(ecdsa, glv=True),
-    )
-
-    @jax.jit
-    def run(px8, py8, rc8, g1, g2, p1, p2, sgn, vin):
-        return call(
-            _GTAB8_X, _GTAB8_XB, _GTAB8_Y, _MP8, _MN8, _BETA8,
-            px8, py8, rc8, g1, g2, p1, p2, sgn, vin,
-        )[0]
-
-    return run
-
-
-def _radix8_T(col) -> np.ndarray:
-    """Host: B 32-byte big-endian strings -> [32, B] radix-2**8 limbs, LSB first."""
-    return np.frombuffer(b"".join(col), np.uint8).reshape(len(col), 32)[:, ::-1].T.astype(np.int32)
-
-
-def _pad_lanes(x: np.ndarray, n: int) -> np.ndarray:
-    if x.shape[-1] == n:
-        return x
-    pad = np.zeros((*x.shape[:-1], n - x.shape[-1]), dtype=x.dtype)
-    return np.concatenate([x, pad], axis=-1)
-
-
-def _glv_digits(scalars) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host: scalars (ints mod n) -> (d1, d2 [N_WIN, B] MSB-first 4-bit
-    digit arrays of |k1|, |k2|, sign bits [B] as (s1 | s2 << 1))."""
-    b = len(scalars)
-    halves = [
-        glv_split((int.from_bytes(k, "big") if type(k) is bytes else k) % SECP_N)
-        for k in scalars
-    ]
-    signs = np.fromiter(
-        ((k1 < 0) | ((k2 < 0) << 1) for k1, k2 in halves), dtype=np.int32, count=b
-    )
-    raw = b"".join(
-        abs(k1).to_bytes(17, "big") + abs(k2).to_bytes(17, "big") for k1, k2 in halves
-    )
-    arr = np.frombuffer(raw, dtype=np.uint8).reshape(b, 2, 17)
-    nib = np.empty((b, 2, 34), np.uint8)
-    nib[..., 0::2] = arr >> 4
-    nib[..., 1::2] = arr & 0x0F
-    digs = nib[..., 34 - N_WIN :].astype(np.int32)  # |k| < 2**(4*N_WIN)
-    return digs[:, 0].T.copy(), digs[:, 1].T.copy(), signs
-
-
-def verify_batch_pallas(px, py, rc, k1, k2, valid_in, *, ecdsa: bool, interpret: bool = False, glv: bool | None = None):
+def verify_batch_pallas(px, py, rc, k1, k2, valid_in, *, ecdsa: bool, interpret: bool = False):
     """Fused-Pallas batched verification.
 
     px/py/rc: the batch's 32-byte big-endian columns, one string a job (rc
@@ -793,38 +569,20 @@ def verify_batch_pallas(px, py, rc, k1, k2, valid_in, *, ecdsa: bool, interpret:
     python ints or canonical 32-byte strings (s/e for Schnorr, u1/u2 for
     ECDSA); valid_in: [B] bool, B >= jobs the width the batch is counted at
     (lanes from the last job on are padding).
-    -> ([B] bool mask, the number of host arrays handed to the device).
-
-    Two kernels: the 64-window dual-scalar ladder (default: one packed
-    array up, `pack_lanes`) and the GLV quad-stream 33-window ladder (opt-in
-    via KASPA_TPU_GLV=1 or glv=True until it has run on a TPU; its signed
-    digit split is host bigint work, so it still takes nine arrays).
+    -> ([B] bool mask, the number of host arrays handed to the device: 1,
+    the packed lanes of `pack_lanes`).
     """
-    import os
-
-    if glv is None:
-        glv = bool(os.environ.get("KASPA_TPU_GLV"))
     b = len(valid_in)
     n = launched_lanes(b)
-    kernel = ("ecdsa" if ecdsa else "schnorr") + ("_pallas_glv" if glv else "_pallas")
+    kernel = ("ecdsa" if ecdsa else "schnorr") + "_pallas"
     with trace.span("secp.host_marshal", kernel=kernel, batch=b, lanes=n):
-        if glv:
-            g1, g2, gs = _glv_digits(k1)
-            p1, p2, ps = _glv_digits(k2)
-            rows8 = lambda v: np.broadcast_to(np.asarray(v, dtype=np.int32), (8, len(v)))  # noqa: E731
-            args = tuple(
-                _pad_lanes(a, n)
-                for a in (_radix8_T(px), _radix8_T(py), _radix8_T(rc), g1, g2, p1, p2, rows8(gs | (ps << 2)), rows8(valid_in))
-            )
-        else:
-            args = (pack_lanes(px, py, rc, k1, k2, valid_in, n),)
+        packed = pack_lanes(px, py, rc, k1, k2, valid_in, n)
     # transfer in, launch and the kernel itself, to the ready output; the
     # copy back is queued behind the kernel at once, as a bare np.asarray
     # would queue it, so splitting the wait costs no extra round trip
-    with trace.span("secp.device_call", kernel=kernel, lanes=n, bytes=sum(a.nbytes for a in args)):
-        call = (_build_call if glv else _build_call_plain)(n, ecdsa, interpret)
-        out = call(*args)
+    with trace.span("secp.device_call", kernel=kernel, lanes=n, bytes=packed.nbytes):
+        out = _build_call(n, ecdsa, interpret)(packed)
         out.copy_to_host_async()
         jax.block_until_ready(out)
     with trace.span("secp.readback", kernel=kernel):
-        return np.asarray(out)[:b].astype(bool), len(args)
+        return np.asarray(out)[:b].astype(bool), 1

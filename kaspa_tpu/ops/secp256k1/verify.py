@@ -39,15 +39,7 @@ def _jit_compile_counts() -> dict:
     verifies/sec" failure recurs, this says whether the device ever
     finished a compile at all."""
     out = {}
-    pairs = [("schnorr", schnorr_verify_kernel), ("ecdsa", ecdsa_verify_kernel)]
-    try:  # the aggregate lane's two kernels, when the module has loaded
-        from kaspa_tpu.ops.secp256k1 import aggregate as _agg
-
-        pairs.append(("aggregate_partials", _agg.aggregate_partials_kernel))
-        pairs.append(("aggregate_finish", _agg.aggregate_reduce_finish_kernel))
-    except Exception:  # noqa: BLE001
-        pass
-    for name, fn in pairs:
+    for name, fn in (("schnorr", schnorr_verify_kernel), ("ecdsa", ecdsa_verify_kernel)):
         try:
             out[name] = int(fn._cache_size())
         except Exception:  # noqa: BLE001 - jax internals may shift
@@ -56,8 +48,7 @@ def _jit_compile_counts() -> dict:
     # module only loads on a TPU backend
     lp = sys.modules.get("kaspa_tpu.ops.secp256k1.ladder_pallas")
     if lp is not None:
-        out["pallas_plain"] = lp._build_call_plain.cache_info().currsize
-        out["pallas_glv"] = lp._build_call.cache_info().currsize
+        out["pallas"] = lp._build_call.cache_info().currsize
     mo = sys.modules.get("kaspa_tpu.ops.muhash_ops")
     if mo is not None:
         out["muhash_tree"] = int(mo._tree_product._cache_size())
@@ -94,8 +85,7 @@ def _scalars_to_digits(ks, b: int) -> np.ndarray:
     against a log-depth shift-or bigint tree and a uint64-decompose numpy
     path: the one-join form is ~2-3x faster than either (CPython's
     to_bytes C path wins), and dropping the old loop's per-item ``int()``
-    coercion is another 1.4-1.7x.  Shared by the ladder lane (s/e, u1/u2)
-    and the aggregate lane's weight/combined-challenge digits.
+    coercion is another 1.4-1.7x.
     """
     out = np.zeros((b, 64), np.int32)
     if ks:

@@ -335,23 +335,22 @@ def _read_manifest(path: str) -> list[dict]:
         out = []
         for e in entries:
             if isinstance(e, dict):
-                # schema upgrade: entries written before the aggregate lane
-                # carry no kernel family — they are all ladder shapes
-                e.setdefault("family", "ladder")
+                # older trees wrote a "family" beside the kernel name, which
+                # says the same: without it their rows dedup against ours
+                e.pop("family", None)
                 out.append(e)
         return out
     except (OSError, ValueError):
         return []
 
 
-def note_shape(kernel_name: str, bucket: int, family: str = "ladder") -> None:
+def note_shape(kernel_name: str, bucket: int) -> None:
     """Record a freshly compiled (kernel, bucket) shape in the manifest,
-    keyed by the current mesh/backend/jax version plus the kernel family
-    ("ladder" | "aggregate" — so a pretrace warms the right kernels).  Write-through on new shapes
-    only (rare); never allowed to fail a dispatch."""
+    keyed by the current mesh/backend/jax version.  Write-through on new
+    shapes only (rare); never allowed to fail a dispatch."""
     try:
         path = manifest_path()
-        entry = {"kernel": str(kernel_name), "bucket": int(bucket), "family": str(family), **_env_key()}
+        entry = {"kernel": str(kernel_name), "bucket": int(bucket), **_env_key()}
         with _manifest_lock:
             entries = _read_manifest(path)
             if entry in entries:
@@ -389,7 +388,7 @@ def pretrace_warm(budget_s: float | None = None) -> list[dict]:
     out: list[dict] = []
     t_all = time.monotonic()
     for e in sorted(load_warm_entries(), key=lambda e: (e.get("bucket", 0), e.get("kernel", ""))):
-        row = {"kernel": e.get("kernel"), "bucket": e.get("bucket"), "family": e.get("family", "ladder")}
+        row = {"kernel": e.get("kernel"), "bucket": e.get("bucket")}
         if budget_s is not None and time.monotonic() - t_all > budget_s:
             row["status"] = "skipped:budget"
             out.append(row)

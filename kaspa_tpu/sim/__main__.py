@@ -39,14 +39,8 @@ def main() -> None:
     p.add_argument(
         "--coalesce", default=None, metavar="N",
         help="route the replay's verify batches through the cross-block coalescing "
-        "queue with super-batch target N ('auto' = best batch from BENCH_SWEEP.json; "
+        "queue with super-batch target N ('auto' = 1024; "
         "default off — results are bit-identical either way)",
-    )
-    p.add_argument(
-        "--verify-mode", default=None, choices=("ladder", "aggregate", "auto"),
-        help="schnorr verify lane: per-sig ladder (default), one RLC aggregate "
-        "multi-scalar pass per batch, or auto (aggregate above the measured "
-        "crossover batch size); results are bit-identical either way",
     )
     p.add_argument(
         "--fabric", default=None, metavar="ADDR[,ADDR...]",
@@ -155,8 +149,6 @@ def main() -> None:
         # exercising a no-op action
         args.coalesce = "auto"
     coalesce_target = coalesce.configure(args.coalesce)
-    if args.verify_mode is not None:
-        coalesce.set_verify_mode(args.verify_mode)
     fabric_bal = None
     if args.fabric:
         from kaspa_tpu.fabric import balancer as fabric_balancer
@@ -201,10 +193,8 @@ def main() -> None:
         "realtime_factor": round(len(res.blocks) / args.bps / elapsed, 2),
         "mesh": mesh_size,
         "coalesce": coalesce_target,
-        "verify_mode": coalesce.verify_mode(),
-        # end-state fingerprints: identical across --mesh/--coalesce/
-        # --verify-mode values is the bit-identity acceptance check for the
-        # sharded/aggregated dispatch
+        # end-state fingerprints: identical across --mesh/--coalesce values
+        # is the bit-identity acceptance check for the sharded dispatch
         "sink": sink.hex(),
         "utxo_commitment": fresh.multisets[sink].finalize().hex(),
         "pipeline": bool(args.pipeline),

@@ -1,4 +1,4 @@
-"""Seeded signature batches for harnesses (bench.py, chip_smoke.py).
+"""Seeded signature batches for harnesses (chip_smoke.py).
 
 Every lane is a DISTINCT (pubkey, message, signature) triple, made cheaply:
 P_i = P_{i-1} + G and R_i = R_{i-1} + G cost two point_adds per lane

@@ -503,8 +503,6 @@ class DispatchHandle:
             # legacy synchronous device lane (coalescing disabled)
             if self._schnorr:
                 with trace.span("txscript.dispatch", kind="schnorr", jobs=len(self._schnorr)):
-                    # verify_batch (not schnorr_verify_batch): the sync lane
-                    # honors --verify-mode aggregate/auto like the coalesced one
                     schnorr_mask = secp.verify_batch("schnorr", [(j.pubkey, j.msg, j.sig) for j in self._schnorr])
             if self._ecdsa:
                 with trace.span("txscript.dispatch", kind="ecdsa", jobs=len(self._ecdsa)):

@@ -680,7 +680,7 @@ def test_kernel_catalog_enumeration():
 
     rows = cat.enumerate_signatures()
     fams = {r["family"] for r in rows}
-    assert fams == {"ladder", "aggregate", "muhash", "ecdsa"}
+    assert fams == {"ladder", "ecdsa", "muhash"}
     for r in rows:
         assert r["bucket"] % r["mesh"] == 0
         assert r["shard"] >= 8

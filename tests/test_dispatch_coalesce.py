@@ -12,7 +12,6 @@ fresh XLA compile on CPU, minutes of tier-1 budget).
 """
 
 import hashlib
-import json
 import random
 import threading
 import time
@@ -62,14 +61,42 @@ def test_configure_modes():
     assert REGISTRY.snapshot()["dispatch"]["enabled"] is False
 
 
-def test_configure_auto_seeds_target_from_sweep(tmp_path, monkeypatch):
-    sweep = tmp_path / "BENCH_SWEEP.json"
-    sweep.write_text(json.dumps({"best": {"schnorr/mesh1": {"batch": 512, "value": 1.0}}}))
-    monkeypatch.setenv("KASPA_TPU_BENCH_SWEEP_PATH", str(sweep))
-    assert coalesce.configure("auto") == 512
-    # no sweep file -> documented default
-    monkeypatch.setenv("KASPA_TPU_BENCH_SWEEP_PATH", str(tmp_path / "missing.json"))
-    assert coalesce.configure("auto") == coalesce.DEFAULT_TARGET
+def test_configure_auto_is_the_default_target_and_opens_no_file(monkeypatch):
+    import builtins
+
+    opened = []
+    real_open = builtins.open
+    monkeypatch.setattr(builtins, "open", lambda *a, **k: opened.append(a) or real_open(*a, **k))
+    assert coalesce.configure("auto") == coalesce.DEFAULT_TARGET == 1024
+    assert opened == []
+
+
+def test_dead_verify_switches_change_nothing(monkeypatch):
+    """The two variables that used to pick another Schnorr formulation are
+    set on purpose: a mixed batch still gets the oracle's mask from one pass
+    of the Schnorr builder and one dispatch of kernel ``schnorr``."""
+    from kaspa_tpu.crypto import eclib, secp
+    from kaspa_tpu.observability import trace
+
+    monkeypatch.setenv("KASPA_TPU_VERIFY_MODE", "aggregate")
+    monkeypatch.setenv("KASPA_TPU_GLV", "1")
+    items = _schnorr_items(7, corrupt_every=3)
+    items[4] = (items[4][0], items[4][1], b"\xff" * 32 + items[4][2][32:])  # r >= p: refused on the host
+    want = [bool(eclib.schnorr_verify(*it)) for it in items]
+    assert any(want) and not all(want)
+    secp.verify_batch("schnorr", items)  # bucket 8 is warm from here on
+    trace.set_capture(1 << 12)
+    trace.drain()
+    before = REGISTRY.snapshot()["counters"].get("secp_device_dispatches", {})
+    try:
+        mask = secp.verify_batch("schnorr", items)
+        spans = trace.drain()
+    finally:
+        trace.set_capture(0)
+    assert np.asarray(mask).tolist() == want
+    after = REGISTRY.snapshot()["counters"]["secp_device_dispatches"]
+    assert {k: v - before.get(k, 0) for k, v in after.items() if v - before.get(k, 0)} == {"schnorr": 1}
+    assert [s["attrs"]["kernel"] for s in spans if s["name"] == "secp.host_prepare"] == ["schnorr_verify"]
 
 
 # --- engine mechanics -------------------------------------------------------

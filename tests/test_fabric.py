@@ -54,9 +54,12 @@ def _schnorr_items(n: int, corrupt_every: int = 4):
 
 
 def test_wire_hello_roundtrip():
-    mtype, msg = wire.decode(wire.encode_hello(4, modes=wire.MODE_AGGREGATE))
+    mtype, msg = wire.decode(wire.encode_hello(4))
     assert mtype == wire.HELLO
-    assert msg == {"proto": wire.PROTO_VERSION, "slices": 4, "modes": wire.MODE_AGGREGATE}
+    assert msg == {"proto": wire.PROTO_VERSION, "slices": 4, "modes": 0}
+    # an older verifyd set capability bit 0x01: the varint still decodes, and
+    # nothing reads it
+    assert wire.decode(wire.encode_hello(4, modes=0x01)) == (wire.HELLO, {**msg, "modes": 0x01})
 
 
 def test_wire_hello_proto1_compat():

@@ -35,8 +35,7 @@ def _col(vals):
     return [v.to_bytes(32, "big") for v in vals]
 
 
-@pytest.mark.parametrize("glv", [False, True])
-def test_schnorr_pallas_interpret(keys, glv):
+def test_schnorr_pallas_interpret(keys):
     sk = keys
     pubs = [eclib.schnorr_pubkey(k) for k in sk]
     pks = [eclib.lift_x(int.from_bytes(p, "big")) for p in pubs]
@@ -59,8 +58,8 @@ def test_schnorr_pallas_interpret(keys, glv):
     ok[3] = False  # host-side encoding rejection must mask through
     expect[3] = False
 
-    mask, uploads = verify_batch_pallas(px, py, rc, sd, ed, ok, ecdsa=False, interpret=True, glv=glv)
-    assert mask.tolist() == expect and uploads == (9 if glv else 1)
+    mask, uploads = verify_batch_pallas(px, py, rc, sd, ed, ok, ecdsa=False, interpret=True)
+    assert mask.tolist() == expect and uploads == 1
 
     # oracle cross-check on the uncorrupted lanes
     for i in (0, 2, 4, 7):
@@ -91,14 +90,3 @@ def test_ecdsa_pallas_interpret(keys):
 
     mask, uploads = verify_batch_pallas(px, py, rn, u1, u2, ok, ecdsa=True, interpret=True)
     assert mask.tolist() == expect and uploads == 1
-
-
-def test_glv_split_identity():
-    from kaspa_tpu.ops.secp256k1.ladder_pallas import GLV_LAMBDA, glv_split
-
-    random.seed(11)
-    for _ in range(500):
-        k = random.randrange(eclib.N)
-        k1, k2 = glv_split(k)
-        assert (k1 + k2 * GLV_LAMBDA) % eclib.N == k
-        assert abs(k1).bit_length() <= 132 and abs(k2).bit_length() <= 132

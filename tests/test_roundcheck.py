@@ -1,7 +1,8 @@
-"""Round-evidence tooling: roundcheck artifact + bench probe / no-TPU behaviour."""
+"""Round-evidence tooling: the roundcheck artifact and where its lanes write."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import subprocess
@@ -12,24 +13,37 @@ import pytest
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_roundcheck_writes_round_evidence(tmp_path):
-    out = tmp_path / "ROUNDCHECK.json"
+def _root_files() -> dict:
+    """Digest of every regular file in the repo root (where the lanes used to
+    write), by name."""
+    out = {}
+    for name in sorted(os.listdir(REPO_ROOT)):
+        path = os.path.join(REPO_ROOT, name)
+        if os.path.isfile(path):
+            with open(path, "rb") as f:
+                out[name] = hashlib.sha256(f.read()).hexdigest()
+    return out
+
+
+@pytest.fixture(scope="module")
+def round_run(tmp_path_factory):
+    """One roundcheck run with the lanes tier-1 can afford (sim, serving,
+    tenbps, supervision), shared by the tests below."""
+    out = tmp_path_factory.mktemp("round") / "ROUNDCHECK.json"
+    root_before = _root_files()
     proc = subprocess.run(
         [
             sys.executable,
             os.path.join(REPO_ROOT, "tools", "roundcheck.py"),
             "--skip-tests",
-            "--skip-bench",
             # the mesh lanes re-trace the verify ladder in fresh subprocesses
             # (minutes on CPU) — they get their own roundcheck run per round,
             # not a seat inside the tier-1 fast lane; same for the chaos
-            # sustain run (three full replays of a hostile workload) and the
-            # coalesced-dispatch throughput lane (bench child + dual replay)
+            # sustain run (three full replays of a hostile workload)
             # and the obs lane (traced 24-block replay plus a tracing-off
             # overhead A/B whose 2% gate is noise under suite load)
             "--skip-mesh",
             "--skip-chaos",
-            "--skip-dispatch",
             "--skip-obs",
             # and the fabric drill (a verifyd subprocess + three replays)
             "--skip-fabric",
@@ -50,11 +64,6 @@ def test_roundcheck_writes_round_evidence(tmp_path):
             # audit (real eval_shape traces, ~50 s on CPU) — it gets its
             # own `roundcheck --only lint` acceptance run
             "--skip-lint",
-            # and the aggregated-verify lane: its bench child traces BOTH
-            # verify lanes from a cold process (minutes of XLA compile on
-            # CPU, ~5x everything else in this run combined) — it gets its
-            # own `roundcheck --only aggregate` acceptance run
-            "--skip-aggregate",
             "--blocks",
             "8",
             "--out",
@@ -66,12 +75,27 @@ def test_roundcheck_writes_round_evidence(tmp_path):
         text=True,
         timeout=300,
     )
+    return proc, out, root_before, _root_files()
+
+
+def test_roundcheck_writes_round_evidence(round_run):
+    proc, out, _before, _after = round_run
     assert proc.returncode == 0, proc.stdout
     evidence = json.loads(out.read_text())
     assert evidence["ok"] is True
     sim = evidence["sections"]["sim"]
     assert sim["ok"] and sim["result"]["blocks"] == 8
     assert "created" in evidence
+
+
+def test_roundcheck_lanes_write_beside_out(round_run):
+    """The lanes' own artifacts land in the directory of --out, and the repo
+    root (where they are tracked files) is as it was."""
+    proc, out, before, after = round_run
+    assert proc.returncode == 0, proc.stdout
+    assert "supervision" in json.loads(out.read_text())["sections"]
+    assert json.loads((out.parent / "SUSTAIN_WEDGE.json").read_text())
+    assert after == before
 
 
 def test_roundcheck_only_selector(tmp_path):
@@ -96,57 +120,3 @@ def test_roundcheck_only_selector(tmp_path):
         cwd=REPO_ROOT, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, timeout=60,
     )
     assert bad.returncode != 0 and "unknown --only" in bad.stdout
-
-
-def _run_bench(extra_env: dict, argv=()):
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.update(extra_env)
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py"), *argv],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True, timeout=300,
-    )
-
-
-@pytest.mark.parametrize(
-    "extra_env,argv",
-    [
-        ({"KASPA_TPU_BENCH_CHILD": "1", "KASPA_TPU_BENCH_B": "8"}, ()),  # the measuring child itself
-        ({}, ()),  # the jax-free parent, headline
-        ({}, ("--sweep",)),  # ... and the sweep
-    ],
-    ids=["child", "parent", "sweep"],
-)
-def test_bench_exits_nonzero_without_tpu(extra_env, argv):
-    """bench.py measures a TPU or nothing: on the CPU backend it exits
-    non-zero and no line it prints carries a value — there is no CPU lane
-    whose number could be read beside a device metric."""
-    proc = _run_bench(extra_env, argv)
-    assert proc.returncode != 0
-    lines = [json.loads(ln) for ln in proc.stdout.splitlines() if ln.startswith("{")]
-    assert lines, "bench.py must say why it measured nothing"
-    for obj in lines:
-        assert "value" not in obj and "cpu_fallback_value" not in obj
-    last = lines[-1]
-    assert "tpu" in (last.get("error") or last.get("child_error") or "").lower()
-
-
-def test_bench_probe_mode_emits_json_line():
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["KASPA_TPU_BENCH_CHILD"] = "1"
-    env["KASPA_TPU_BENCH_MODE"] = "probe"
-    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.DEVNULL,
-        text=True,
-        timeout=180,
-    )
-    line = [ln for ln in proc.stdout.strip().splitlines() if ln.startswith("{")][-1]
-    obj = json.loads(line)
-    assert obj["probe_ok"] is True and proc.returncode == 0
-    assert obj["platform"] == "cpu" and obj["device_platform"] == "cpu"

@@ -275,6 +275,31 @@ def test_warm_manifest_roundtrip(monkeypatch, tmp_path):
     assert [r["status"] for r in rows] == ["skipped:budget"] * 2
 
 
+def test_warm_manifest_of_an_older_tree(monkeypatch, tmp_path):
+    """A manifest an older tree wrote: every row carries a ``family``, and one
+    names a kernel this tree no longer has.  Boot's pretrace raises nothing,
+    answers that row as it answers any unknown kernel, and still warms the
+    others; a row with the key and ours without it are one shape."""
+    import json
+
+    path = tmp_path / "warm_manifest.json"
+    monkeypatch.setenv("KASPA_TPU_WARM_MANIFEST", str(path))
+    env = supervisor._env_key()
+    path.write_text(json.dumps({"entries": [
+        {"kernel": "schnorr_aggregate", "bucket": 16, "family": "aggregate", **env},
+        {"kernel": "schnorr_verify", "bucket": 8, "family": "ladder", **env},
+    ]}))
+    # the real pretrace_bucket: bucket 8 marked compiled, so nothing compiles
+    monkeypatch.setattr(secp, "_seen_shapes", {("schnorr_verify", 8, env["mesh"])})
+    rows = supervisor.pretrace_warm()
+    assert [(r["kernel"], r["bucket"], r["status"]) for r in rows] == [
+        ("schnorr_verify", 8, "warm"),
+        ("schnorr_aggregate", 16, "error:unknown schnorr_aggregate/16"),
+    ]
+    supervisor.note_shape("schnorr_verify", 8)
+    assert supervisor.cache_report()["entries_total"] == 2
+
+
 def test_pretrace_bucket_rejects_unknown():
     assert supervisor.run_supervised(lambda: None) is None  # smoke: pool alive
     assert secp.pretrace_bucket("no_such_kernel", 8).startswith("error:")

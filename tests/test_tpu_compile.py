@@ -6,8 +6,8 @@ refuses — a tiling it cannot do, too much fast memory, a kernel it cannot
 partition — shows here at no chip time.  This is the only test file that
 describes the chip, and it does so inside a module-scoped fixture: only one
 process at a time may load the TPU library, and every xdist worker imports
-every test file.  The remaining shapes — the opt-in GLV builder, the other
-padded widths, bucket-1024 muhash, and the 4-device shard_map ladder (over
+every test file.  The remaining shapes — the other padded widths,
+bucket-1024 muhash, and the 4-device shard_map ladder (over
 a minute per compile, which would push this file past two) — are in
 ``tools/tpu_rehearse.py``, which also owns the case builders used here.
 """

@@ -138,9 +138,8 @@ def test_pallas_launched_lanes_are_whole_blocks(b, lanes):
 def test_pallas_programs_are_named_on_the_device():
     from kaspa_tpu.ops.secp256k1 import ladder_pallas
 
-    names = {ladder_pallas._kernel_name(e, g) for e in (False, True) for g in (False, True)}
-    assert names == {"secp256k1_ladder_schnorr", "secp256k1_ladder_ecdsa",
-                     "secp256k1_ladder_schnorr_glv", "secp256k1_ladder_ecdsa_glv"}
+    names = {ladder_pallas._kernel_name(e) for e in (False, True)}
+    assert names == {"secp256k1_ladder_schnorr", "secp256k1_ladder_ecdsa"}
 
 
 def test_padded_lanes_help_says_bucket_not_device():

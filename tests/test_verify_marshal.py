@@ -50,7 +50,7 @@ def _reference_operands(batch, b, lanes):
     planes = [_to_radix8_T(verify._be32_to_limbs(c, b)) for c in (batch.px, batch.py, batch.rc)]
     planes += [_full_digits(batch.d1 + pad), _full_digits(batch.d2 + pad)]
     planes.append(np.broadcast_to(np.asarray(ok, dtype=np.int32), (8, b)).copy())
-    return [lp._pad_lanes(p, lanes) for p in planes], ok
+    return [np.pad(p, ((0, 0), (0, lanes - b))) for p in planes], ok
 
 
 def _batch(n, seed):
@@ -113,6 +113,13 @@ def test_pack_lanes_of_no_jobs_is_all_padding():
     assert not lp.pack_lanes([], [], [], [], [], np.zeros(8, bool), 256).any()
 
 
+def test_scalars_to_digits_bytes_match_ints():
+    ks = [0, 1, eclib.N - 1, 0x1234567890ABCDEF]
+    as_int = verify._scalars_to_digits(ks, 6)
+    as_bytes = verify._scalars_to_digits([k.to_bytes(32, "big") for k in ks], 6)
+    assert (as_int == as_bytes).all()
+
+
 # --- which marshal a dispatch takes, and what it hands over ------------------
 
 
@@ -164,7 +171,7 @@ def test_pallas_lane_hands_the_device_one_array(monkeypatch, warm_shapes):
         return run
 
     monkeypatch.setattr(verify, "_use_pallas", lambda: True)
-    monkeypatch.setattr(lp, "_build_call_plain", built_call)
+    monkeypatch.setattr(lp, "_build_call", built_call)
     items = _schnorr_items(5)
     trace.set_capture(1 << 12)
     trace.drain()
@@ -247,3 +254,46 @@ def test_mesh_lane_receives_the_arrays_it_always_did(monkeypatch, warm_shapes):
     ((kind, got),) = received
     assert kind == "schnorr" and all(np.array_equal(g, w) for g, w in zip(got, _expected_xla_args(batch, 16)))
     assert _moved(before, "secp_device_uploads") == 6 and _moved(before, "secp_device_dispatches") == {"schnorr_mesh": 1}
+
+
+# --- which back end a dispatch takes -----------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["schnorr", "ecdsa"])
+@pytest.mark.parametrize(
+    "backend,mesh_n,lane,suffix",
+    [("cpu", 1, "xla", ""), ("cpu", 4, "mesh", "_mesh"), ("tpu", 1, "pallas", "_pallas")],
+    ids=["cpu-mesh1", "mesh4", "tpu-mesh1"],
+)
+def test_verify_selects_its_back_end_from_platform_and_mesh(monkeypatch, kind, backend, mesh_n, lane, suffix):
+    """`_verify` picks the Pallas ladder (a TPU at mesh 1), the shard_map XLA
+    ladder (mesh > 1) or the XLA ladder (anything else) from
+    `jax.default_backend()` and `mesh.active_size()`, read here from
+    `secp_device_dispatches{kernel}`.  All three back ends are stubbed to
+    answer with the valid flags, so nothing compiles: the kernels have their
+    own tests."""
+    import jax.numpy as jnp
+
+    from kaspa_tpu.ops import mesh
+
+    taken = []
+    monkeypatch.delenv("KASPA_TPU_NO_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(mesh, "active_size", lambda: mesh_n)
+    monkeypatch.setattr(
+        lp, "verify_batch_pallas",
+        lambda px, py, rc, k1, k2, valid_in, *, ecdsa: taken.append(("pallas", ecdsa)) or (np.asarray(valid_in), 1),
+    )
+    monkeypatch.setattr(
+        mesh, "dispatch_verify", lambda k, *args: taken.append(("mesh", k == "ecdsa")) or np.asarray(args[5])
+    )
+    monkeypatch.setattr(
+        verify, f"{kind}_verify_kernel", lambda *args: taken.append(("xla", kind == "ecdsa")) or jnp.asarray(args[5])
+    )
+    monkeypatch.setattr(secp, "_seen_shapes", {(f"{kind}_verify", 8, mesh_n)})
+    batch = _batch(5, seed=31)
+    before = REGISTRY.snapshot()["counters"]
+    assert batch.run(getattr(secp, f"{kind}_verify")).tolist() == batch.ok
+    assert taken == [(lane, kind == "ecdsa")]
+    assert _moved(before, "secp_device_dispatches") == {kind + suffix: 1}
+    assert _moved(before, "secp_device_uploads") == (1 if lane == "pallas" else 6)

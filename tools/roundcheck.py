@@ -1,11 +1,10 @@
 #!/usr/bin/env python
 """One-command round evidence: graftlint + fast-lane tests + sim replay
-+ bench probe + multichip dryrun + mesh smoke + flight-recorder trace
-+ chaos sustain.
++ multichip dryrun + mesh smoke + flight-recorder trace + chaos sustain.
 
-Runs the repo's tier-1 fast lane, a short simulator replay, the bench
-session probe, the sharded multichip dryrun (on every visible device,
-forced-CPU), a `--mesh 8` sim smoke replay, the flight-recorder lane (a
+Runs the repo's tier-1 fast lane, a short simulator replay, the sharded
+multichip dryrun (on every visible device, forced-CPU), a `--mesh 8` sim
+smoke replay, the flight-recorder lane (a
 traced 24-block pipelined replay whose dump must hold one connected
 >=4-thread span tree per block with >= 90% critical-path attribution and
 a valid Perfetto export, plus a tracing-off-within-2% overhead gate),
@@ -24,12 +23,13 @@ late-join IBD, gated on fleet-wide bit-identity, fault-free match, zero
 lost tickets and a relay-amplification budget), then writes a single
 round-evidence JSON (ROUNDCHECK.json)
 summarizing them — the artifact a driver round or a reviewer reads
-instead of eight scrollback logs.
+instead of eight scrollback logs.  Every lane's own artifact
+(SUSTAIN*.json, FLIGHT*.json, SERVING_LOAD.json, SWARM.json) is written
+beside ``--out``, whose default is the repo root.
 
     python tools/roundcheck.py                     # everything
     python tools/roundcheck.py --only tier1        # just one section
     python tools/roundcheck.py --only sim --only fabric
-    python tools/roundcheck.py --skip-bench        # no device probe
     python tools/roundcheck.py --skip-mesh         # no multichip/mesh lanes
     python tools/roundcheck.py --skip-obs          # no flight-recorder lane
     python tools/roundcheck.py --skip-chaos        # no fault-injection sustain
@@ -44,9 +44,9 @@ instead of eight scrollback logs.
 
 ``--only SECTION`` (repeatable, or comma-separated) runs exactly the
 named sections and ignores the skip flags; section names are the keys in
-ROUNDCHECK.json (tier1, sim, bench_probe, multichip, mesh_smoke,
-dispatch, aggregate, serving, obs, tenbps, chaos, supervision,
-fabric, ingest, overload, swarm).  Every section records its own
+ROUNDCHECK.json (lint, tier1, sim, multichip, mesh_smoke, serving,
+serving_load, obs, tenbps, chaos, supervision, fabric, ingest, overload,
+swarm).  Every section records its own
 ``wall_seconds`` in the artifact.
 
 Exit code 0 iff every section that ran passed.
@@ -194,11 +194,8 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--skip-tests", action="store_true", help="skip the tier-1 fast lane")
     ap.add_argument("--skip-sim", action="store_true", help="skip the simulator replay")
-    ap.add_argument("--skip-bench", action="store_true", help="skip the bench device probe")
     ap.add_argument("--skip-mesh", action="store_true", help="skip the multichip dryrun + mesh smoke replay")
     ap.add_argument("--skip-chaos", action="store_true", help="skip the hostile-load chaos sustain run")
-    ap.add_argument("--skip-dispatch", action="store_true", help="skip the coalesced-dispatch throughput lane")
-    ap.add_argument("--skip-aggregate", action="store_true", help="skip the aggregated RLC verify lane")
     ap.add_argument("--skip-serving", action="store_true", help="skip the serving-tier dual-encoding + kill -9 lane")
     ap.add_argument("--skip-obs", action="store_true", help="skip the flight-recorder traced-replay lane")
     ap.add_argument("--skip-tenbps", action="store_true", help="skip the 10-BPS speculative-pipeline lane")
@@ -223,9 +220,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--mesh-blocks", type=int, default=48, help="mesh smoke replay length")
     ap.add_argument("--blocks", type=int, default=64, help="sim replay length")
     ap.add_argument("--test-timeout", type=float, default=900.0)
-    ap.add_argument("--probe-timeout", type=float, default=180.0)
     ap.add_argument("--out", default=os.path.join(REPO_ROOT, "ROUNDCHECK.json"))
     args = ap.parse_args(argv)
+    out_dir = os.path.dirname(os.path.abspath(args.out))
 
     # forced 8 CPU host devices: the mesh lanes must work on any box the
     # round runs on, with or without a real accelerator
@@ -273,16 +270,6 @@ def main(argv: list[str] | None = None) -> int:
         sect["ok"] = sect["rc"] == 0 and result is not None
         return sect
 
-    def _sect_bench_probe() -> dict:
-        sect = _run(
-            [sys.executable, os.path.join(REPO_ROOT, "bench.py"), "--probe"],
-            args.probe_timeout,
-        )
-        result = _last_json_line(sect)
-        sect["result"] = result
-        sect["ok"] = bool(result and result.get("probe_ok"))
-        return sect
-
     def _sect_multichip() -> dict:
         # multichip dryrun: masks + muhash product checked against host
         # oracles on every visible device (round evidence for item 6)
@@ -318,90 +305,6 @@ def main(argv: list[str] | None = None) -> int:
         result = _last_json_line(sect)
         sect["result"] = result
         sect["ok"] = sect["rc"] == 0 and bool(result) and result.get("mesh") == 8
-        return sect
-
-    def _sect_dispatch() -> dict:
-        # coalesced dispatch lane: cross-block coalescing vs legacy per-block
-        # dispatch over the same jobs on the CPU bench path.  Chunk size 4
-        # models the sim's per-block signature count (tpb 4; every block
-        # pads half its bucket-8 lanes); the coalesced lane packs the same
-        # jobs into 64-lane super-batches.  Acceptance: >= 1.3x verifies/sec AND
-        # a 24-block sim replay (long enough for coinbase maturity, so real
-        # signature batches flow) bit-identical (sink + utxo_commitment)
-        # with coalescing on vs off.
-        sect = _run(
-            [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-            900.0,
-            {
-                **mesh_env,
-                "KASPA_TPU_BENCH_CHILD": "1",
-                "KASPA_TPU_BENCH_MODE": "dispatch",
-                "KASPA_TPU_BENCH_DISPATCH_B": "120",
-                "KASPA_TPU_BENCH_CHUNK": "4",
-                "KASPA_TPU_COALESCE": "64",
-                "KASPA_TPU_BENCH_DISPATCH_REPLAY": "24",
-            },
-        )
-        result = _last_json_line(sect)
-        if result is not None:
-            result.pop("observability", None)
-        sect["result"] = result
-        sect["ok"] = (
-            sect["rc"] == 0
-            and bool(result)
-            and result.get("speedup", 0.0) >= 1.3
-            and bool(result.get("replay_identical"))
-        )
-        return sect
-
-    def _sect_aggregate() -> dict:
-        # aggregated RLC verify lane: ONE random-linear-combination
-        # multi-scalar pass over the super-batch vs per-signature ladders,
-        # on the CPU bench path.  Batch 64 is the production coalesce size
-        # and sits past the measured crossover (batch 16).  Acceptance:
-        # >= 1.5x verifies/sec AND a 24-block sim replay with
-        # --verify-mode aggregate bit-identical (sink + utxo_commitment)
-        # with the ladder replay — bisection must make the two lanes
-        # indistinguishable, not just agree on all-valid batches.
-        sect = _run(
-            [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
-            900.0,
-            {
-                "JAX_PLATFORMS": "cpu",
-                "KASPA_TPU_BENCH_CHILD": "1",
-                "KASPA_TPU_BENCH_MODE": "aggregate",
-                "KASPA_TPU_BENCH_AGG_B": "64",
-                "KASPA_TPU_COLD_BUCKET_SPLIT": "0",
-            },
-        )
-        result = _last_json_line(sect)
-        if result is not None:
-            result.pop("observability", None)
-        sect["result"] = result
-        replay_cmd = [
-            sys.executable, "-m", "kaspa_tpu.sim",
-            "--bps", "2", "--blocks", "24", "--tpb", "4", "--json",
-        ]
-        lad = _run(replay_cmd + ["--verify-mode", "ladder"], 600.0, {"JAX_PLATFORMS": "cpu"})
-        agg = _run(replay_cmd + ["--verify-mode", "aggregate"], 600.0, {"JAX_PLATFORMS": "cpu"})
-        j_lad = _last_json_line(lad)
-        j_agg = _last_json_line(agg)
-        identical = bool(
-            j_lad and j_agg
-            and j_lad["sink"] == j_agg["sink"]
-            and j_lad["utxo_commitment"] == j_agg["utxo_commitment"]
-        )
-        sect["replay_ladder"] = j_lad
-        sect["replay_aggregate"] = j_agg
-        sect["replay_identical"] = identical
-        sect["ok"] = (
-            sect["rc"] == 0
-            and bool(result)
-            and result.get("speedup", 0.0) >= 1.5
-            and lad["rc"] == 0
-            and agg["rc"] == 0
-            and identical
-        )
         return sect
 
     def _sect_serving() -> dict:
@@ -440,7 +343,7 @@ def main(argv: list[str] | None = None) -> int:
                 sys.executable, os.path.join(REPO_ROOT, "tools", "serving_load.py"),
                 "--subscribers", str(args.serving_load_subscribers),
                 "--shards", "4",
-                "--out", os.path.join(REPO_ROOT, "SERVING_LOAD.json"),
+                "--out", os.path.join(out_dir, "SERVING_LOAD.json"),
             ],
             1500.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -466,8 +369,8 @@ def main(argv: list[str] | None = None) -> int:
         # >= 4 threads with >= 90% critical-path attribution, the Perfetto
         # export must be valid Chrome trace JSON, and the tracing-disabled
         # replay must stay within 2% of the default (PR 5 baseline) replay.
-        flight_path = os.path.join(REPO_ROOT, "FLIGHT.json")
-        perfetto_path = os.path.join(REPO_ROOT, "FLIGHT.perfetto.json")
+        flight_path = os.path.join(out_dir, "FLIGHT.json")
+        perfetto_path = os.path.join(out_dir, "FLIGHT.perfetto.json")
         sect = _run(
             [
                 sys.executable, "-m", "kaspa_tpu.sim",
@@ -563,7 +466,7 @@ def main(argv: list[str] | None = None) -> int:
                 sys.executable, "-m", "kaspa_tpu.sim",
                 "--hostile", "--faults", "default", "--blocks", str(args.chaos_blocks),
                 "--tpb", "4", "--seed", "7", "--json",
-                "--sustain-out", os.path.join(REPO_ROOT, "SUSTAIN.json"),
+                "--sustain-out", os.path.join(out_dir, "SUSTAIN.json"),
             ],
             900.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -589,7 +492,7 @@ def main(argv: list[str] | None = None) -> int:
                 sys.executable, "-m", "kaspa_tpu.sim",
                 "--hostile", "--wedge-drill", "--blocks", "24",
                 "--tpb", "4", "--seed", "7", "--coalesce", "256", "--json",
-                "--sustain-out", os.path.join(REPO_ROOT, "SUSTAIN_WEDGE.json"),
+                "--sustain-out", os.path.join(out_dir, "SUSTAIN_WEDGE.json"),
             ],
             1200.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -643,7 +546,7 @@ def main(argv: list[str] | None = None) -> int:
                 sys.executable, "-m", "kaspa_tpu.sim",
                 "--txflood", "--no-pace", "--blocks", "24", "--tpb", "4",
                 "--seed", "7", "--json",
-                "--sustain-out", os.path.join(REPO_ROOT, "SUSTAIN_TXFLOOD.json"),
+                "--sustain-out", os.path.join(out_dir, "SUSTAIN_TXFLOOD.json"),
             ],
             900.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -679,7 +582,7 @@ def main(argv: list[str] | None = None) -> int:
                 "--txflood", "--overload", "--no-pace", "--blocks", "24",
                 "--tpb", "4", "--seed", "7", "--json",
                 "--overload-config", '{"warm_frac": 0.5, "ramp_frac": 0.2, "hold_frac": 0.2}',
-                "--sustain-out", os.path.join(REPO_ROOT, "SUSTAIN_OVERLOAD.json"),
+                "--sustain-out", os.path.join(out_dir, "SUSTAIN_OVERLOAD.json"),
             ],
             900.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -711,7 +614,7 @@ def main(argv: list[str] | None = None) -> int:
             [
                 sys.executable, "-m", "kaspa_tpu.sim",
                 "--swarm", "3", "--blocks", "24", "--seed", "7", "--json",
-                "--swarm-out", os.path.join(REPO_ROOT, "SWARM.json"),
+                "--swarm-out", os.path.join(out_dir, "SWARM.json"),
             ],
             900.0,
             {"JAX_PLATFORMS": "cpu"},
@@ -732,11 +635,8 @@ def main(argv: list[str] | None = None) -> int:
         ("lint", not args.skip_lint, _sect_lint),
         ("tier1", not args.skip_tests, _sect_tier1),
         ("sim", not args.skip_sim, _sect_sim),
-        ("bench_probe", not args.skip_bench, _sect_bench_probe),
         ("multichip", not args.skip_mesh, _sect_multichip),
         ("mesh_smoke", not args.skip_mesh, _sect_mesh_smoke),
-        ("dispatch", not args.skip_dispatch, _sect_dispatch),
-        ("aggregate", not args.skip_aggregate, _sect_aggregate),
         ("serving", not args.skip_serving, _sect_serving),
         ("serving_load", not args.skip_serving_load, _sect_serving_load),
         ("obs", not args.skip_obs, _sect_obs),

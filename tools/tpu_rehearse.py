@@ -7,8 +7,8 @@ guide, section 2, third rehearsal).
     JAX_PLATFORMS=cpu python tools/tpu_rehearse.py pallas     # one group
 
 Groups: ``pallas`` (the default single-chip ladder, Schnorr + ECDSA at the
-padded widths 256/512/1024 the served buckets map to), ``glv`` (the opt-in
-GLV builder, same widths), ``muhash`` (tree product at 64 and 1024),
+padded widths 256/512/1024 the served buckets map to), ``muhash`` (tree
+product at 64 and 1024),
 ``mesh`` (the shard_map-wrapped XLA ladder on a 4-device mesh built from
 the described topology, shard 256 = bucket 1024 / 4, plus the sharded
 muhash tree).  One JSON line per compile: seconds, generated-code and temp
@@ -48,7 +48,7 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def pallas_ladder(topo, kind: str, n_padded: int, glv: bool = False):
+def pallas_ladder(topo, kind: str, n_padded: int):
     """Lower + compile the fused Mosaic ladder for one described chip."""
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -56,17 +56,10 @@ def pallas_ladder(topo, kind: str, n_padded: int, glv: bool = False):
     from kaspa_tpu.ops.secp256k1 import ladder_pallas as lp
 
     chip = SingleDeviceSharding(topo.devices[0])
-    if glv:
-        run = lp._build_call(n_padded, kind == "ecdsa", False)
-        i32 = lambda *s: _shape(s, jnp.int32, chip)  # noqa: E731
-        limbs, dig, valid = i32(lp.W8, n_padded), i32(lp.N_WIN, n_padded), i32(8, n_padded)
-        args = (limbs, limbs, limbs, dig, dig, dig, dig, valid, valid)
-    else:
-        # the one packed byte array of a plain call; limbs and digits are
-        # laid out inside the program (ladder_pallas.unpack_lanes)
-        run = lp._build_call_plain(n_padded, kind == "ecdsa", False)
-        args = (_shape((lp.LANE_BYTES, n_padded), jnp.uint8, chip),)
-    return run.lower(*args).compile()
+    # the one packed byte array of a call; limbs and digits are laid out
+    # inside the program (ladder_pallas.unpack_lanes)
+    run = lp._build_call(n_padded, kind == "ecdsa", False)
+    return run.lower(_shape((lp.LANE_BYTES, n_padded), jnp.uint8, chip)).compile()
 
 
 def muhash_tree(topo, bucket: int):
@@ -138,13 +131,10 @@ def report(compiled) -> dict:
 
 def _cases(groups: set) -> list:
     cases = []
-    for glv, group in ((False, "pallas"), (True, "glv")):
-        if group in groups:
-            for kind in ("schnorr", "ecdsa"):
-                for n in (256, 512, 1024):
-                    cases.append(
-                        (f"{group}/{kind}/n{n}", lambda t, k=kind, n=n, g=glv: pallas_ladder(t, k, n, g))
-                    )
+    if "pallas" in groups:
+        for kind in ("schnorr", "ecdsa"):
+            for n in (256, 512, 1024):
+                cases.append((f"pallas/{kind}/n{n}", lambda t, k=kind, n=n: pallas_ladder(t, k, n)))
     if "muhash" in groups:
         for bucket in (64, 1024):
             cases.append((f"muhash/tree/b{bucket}", lambda t, b=bucket: muhash_tree(t, b)))
@@ -156,7 +146,7 @@ def _cases(groups: set) -> list:
 
 
 def main() -> int:
-    groups = set(sys.argv[1:]) or {"pallas", "glv", "muhash", "mesh"}
+    groups = set(sys.argv[1:]) or {"pallas", "muhash", "mesh"}
     import jax
 
     # a described-device compile is written to the persistent cache but can

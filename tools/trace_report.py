@@ -4,14 +4,13 @@
 Input: a JSONL span log written by ``kaspa_tpu.observability.trace.dump``
 (one span dict per line: name/path/start_us/dur_us/thread/depth/attrs), or
 a JSON document embedding such a list under an ``observability`` /
-``spans`` key — e.g. a bench.py result line or a BENCH_*.json entry whose
-``tail`` carries the snapshot — or a flight-recorder dump
+``spans`` key — e.g. a chip_smoke.py phase line — or a flight-recorder dump
 (``kaspa_tpu.observability.flight.dump``: per-block span trees with
 critical-path attribution).
 
 Output: a path-aggregated flame table (total vs self time, counts,
 mean/max) plus the slowest individual spans — enough to answer "which
-stage stalled" when a bench reports 0.0 verifies/sec.  Flight dumps
+stage stalled" when a run reports 0.0 verifies/sec.  Flight dumps
 additionally get a per-block critical-path table, and export to the
 Chrome trace-event format that ui.perfetto.dev / chrome://tracing load:
 
