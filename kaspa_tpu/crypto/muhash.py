@@ -12,10 +12,13 @@ u3072.rs) + the consensus extensions (consensus/core/src/muhash.rs):
 The host object keeps exact python-int accumulators (cheap at 3072 bits).
 Bulk diffs — ``add_transactions_batch``, the call the consensus virtual
 processor makes per mergeset — derive all element preimages at once
-(native-vectorised ChaCha20) and, above ``DEVICE_BATCH_THRESHOLD``
+(native-vectorised ChaCha20) and, from ``DEVICE_BATCH_THRESHOLD``
 elements, reduce the products through the device U3072 tree-product kernel
-(ops/muhash_ops.batch_product_ints); the two bulk products (numerator /
-denominator) each combine into the accumulator with one host multiply.
+(ops/muhash_ops.ProductGroup).  A commit waits for the device once: the
+numerator's chunks are launched, the denominator's digests, keystream and
+limbs are derived on the host while the device works, its chunks are
+launched too, and only then are both products read back; each combines
+into the accumulator with one host multiply.
 """
 
 from __future__ import annotations
@@ -70,29 +73,47 @@ def elements_from_preimages(preimages: list[bytes]) -> list[int]:
     return element_hashes_to_ints(_digests(preimages))
 
 
+def bulk_element_products(batches: list[list[bytes]], use_device: bool = True) -> list[int]:
+    """Product mod PRIME of the field elements of each list of preimages.
+
+    A list of at least ``DEVICE_BATCH_THRESHOLD`` goes through the device
+    tree-product kernel: its chunks are launched and the next list is
+    prepared (or multiplied, under the threshold) on the host while the
+    device works; every launched product is read back in one wait at the
+    end.  The device path views the raw keystream bytes as 16-bit limbs
+    directly - values in [PRIME, 2**3072) are legal lazy-limb inputs that
+    the kernel's final canon reduces - so no per-element host bigint
+    conversion happens."""
+    products = [1] * len(batches)
+    group = None
+    launched: list[int] = []  # the batches whose product the group holds, in launch order
+    for i, preimages in enumerate(batches):
+        if not preimages:
+            continue
+        if use_device and len(preimages) >= DEVICE_BATCH_THRESHOLD:
+            from kaspa_tpu.ops import muhash_ops
+
+            with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
+                ks = chacha.keystream(_digests(preimages), ELEMENT_BYTE_SIZE)
+                limbs = ks.view(np.dtype("<u2")).astype(np.int32)  # [N, 192]
+            group = group or muhash_ops.ProductGroup()
+            group.launch(limbs)
+            launched.append(i)
+        else:
+            with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
+                elements = elements_from_preimages(preimages)
+            _HOST_ELEMENTS.inc(len(preimages))
+            for e in elements:
+                products[i] = products[i] * e % PRIME
+    if group is not None:
+        for i, product in zip(launched, group.finish()):
+            products[i] = product
+    return products
+
+
 def bulk_element_product(preimages: list[bytes], use_device: bool = True) -> int:
-    """Product of the field elements of `preimages` mod PRIME.
-
-    Routes through the device tree-product kernel above the threshold.  The
-    device path views the raw keystream bytes as 16-bit limbs directly —
-    values in [PRIME, 2**3072) are legal lazy-limb inputs that the kernel's
-    final canon reduces — so no per-element host bigint conversion happens."""
-    if not preimages:
-        return 1
-    if use_device and len(preimages) >= DEVICE_BATCH_THRESHOLD:
-        from kaspa_tpu.ops import muhash_ops
-
-        with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
-            ks = chacha.keystream(_digests(preimages), ELEMENT_BYTE_SIZE)
-            limbs = ks.view(np.dtype("<u2")).astype(np.int32)  # [N, 192]
-        return muhash_ops.batch_product_device(limbs)
-    with trace.span("muhash.host_prepare", phase="elements", elements=len(preimages)):
-        elements = elements_from_preimages(preimages)
-    _HOST_ELEMENTS.inc(len(preimages))
-    acc = 1
-    for e in elements:
-        acc = acc * e % PRIME
-    return acc
+    """Product of the field elements of `preimages` mod PRIME."""
+    return bulk_element_products([preimages], use_device)[0]
 
 
 def serialize_utxo(outpoint, entry) -> bytes:
@@ -174,7 +195,8 @@ class MuHash:
 
         All element preimages of the batch are derived together and the two
         monoid products (created outputs -> numerator, spent entries ->
-        denominator) reduce through the device kernel above the threshold.
+        denominator) reduce through the device kernel from the threshold,
+        launched one after the other and read back together.
         Equivalent to calling add_transaction per item, in any order — the
         multiset hash is commutative (reference rayon map-reduce:
         consensus/src/pipeline/virtual_processor/utxo_validation.rs:334-363).
@@ -188,10 +210,11 @@ class MuHash:
                     adds += a
                     removes += r
                 sp.set(elements=len(adds) + len(removes))
+            added, removed = bulk_element_products([adds, removes], use_device)
             if adds:
-                self.numerator = self.numerator * bulk_element_product(adds, use_device) % PRIME
+                self.numerator = self.numerator * added % PRIME
             if removes:
-                self.denominator = self.denominator * bulk_element_product(removes, use_device) % PRIME
+                self.denominator = self.denominator * removed % PRIME
 
 
 def _tx_element_preimages(tx, utxo_entries, block_daa_score: int):

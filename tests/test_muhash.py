@@ -9,6 +9,7 @@ expansion, and the GF(2**3072 - 1103717) arithmetic end to end.
 import random
 
 import numpy as np
+import pytest
 
 from kaspa_tpu.crypto.muhash import EMPTY_MUHASH, PRIME, MuHash, data_to_element
 
@@ -91,17 +92,46 @@ def test_combine_and_serialize_roundtrip():
     assert back.finalize() == a.finalize()
 
 
-def test_device_tree_product_matches_host():
+# every size goes out on the bucket-64 program (one XLA-CPU compile; 1,024
+# takes minutes there): a ragged single chunk, a full one, a full one and a
+# rest, several chunks launched before the one read-back
+@pytest.mark.parametrize("n", [3, 64, 70, 200, 449])
+def test_device_tree_product_matches_host(n):
     from kaspa_tpu.ops.muhash_ops import batch_product_ints
 
-    rng = random.Random(5)
-    # sizes straddle one bucket boundary but reuse the single 64-wide compile
-    for n in (3, 64, 70):
-        vals = [rng.randrange(PRIME) for _ in range(n)]
-        exp = 1
-        for v in vals:
-            exp = exp * v % PRIME
-        assert batch_product_ints(vals) == exp, n
+    rng = random.Random(5 + n)
+    vals = [rng.randrange(PRIME) for _ in range(n)]
+    exp = 1
+    for v in vals:
+        exp = exp * v % PRIME
+    assert batch_product_ints(vals) == exp
+
+
+def test_commit_with_two_device_products_matches_the_host_commit():
+    """Adds and removes both past DEVICE_BATCH_THRESHOLD: the commit launches
+    both products before it reads either, and holds what use_device=False holds."""
+    from kaspa_tpu.consensus.model import (
+        ScriptPublicKey, Transaction, TransactionInput, TransactionOutpoint, TransactionOutput, UtxoEntry,
+    )
+    from kaspa_tpu.crypto.muhash import DEVICE_BATCH_THRESHOLD
+
+    rng = random.Random(9)
+    spk = ScriptPublicKey(0, b"\x20" + bytes(32) + b"\xac")
+    items = []
+    for t in range(6):
+        inputs = [TransactionInput.new(TransactionOutpoint(rng.randbytes(32), i), b"", 0, 1) for i in range(7)]
+        outputs = [TransactionOutput(1000 + i, spk) for i in range(12)]
+        entries = [UtxoEntry(5000 + i, spk, 77 + t, False) for i in range(7)]
+        items.append((Transaction(0, inputs, outputs, 0, bytes(20), 0, b""), entries, 100 + t))
+    assert 6 * 7 >= DEVICE_BATCH_THRESHOLD and 6 * 12 >= DEVICE_BATCH_THRESHOLD
+    on_device, on_host, one_by_one = MuHash(), MuHash(), MuHash()
+    on_device.add_transactions_batch(items)
+    on_host.add_transactions_batch(items, use_device=False)
+    for tx, entries, daa in items:
+        one_by_one.add_transaction(tx, entries, daa)
+    assert (on_device.numerator, on_device.denominator) == (on_host.numerator, on_host.denominator)
+    assert (on_host.numerator, on_host.denominator) == (one_by_one.numerator, one_by_one.denominator)
+    assert on_device.denominator != 1 != on_device.numerator
 
 
 def test_utxo_element_serialization():

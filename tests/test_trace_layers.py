@@ -156,27 +156,126 @@ def _preimages(n, tag):
     return [bytes([tag, i]) * 20 for i in range(n)]
 
 
-def test_muhash_product_over_the_threshold_counts_device_elements():
-    from kaspa_tpu.crypto import muhash
-
+def _captured(call):
+    """(result, spans, counters moved) of one call with span capture on."""
     trace.set_capture(1 << 10)
     trace.drain()
     before = _counters()
     try:
-        got = muhash.bulk_element_product(_preimages(40, 1))
+        got = call()
         spans = trace.drain()
     finally:
         trace.set_capture(0)
     after = _counters()
-    assert _moved(after, before, "muhash_device_elements") == 40
-    assert _moved(after, before, "muhash_device_dispatches") == {"64": 1}
-    assert _moved(after, before, "muhash_host_elements") == 0
-    assert got == muhash.bulk_element_product(_preimages(40, 1), use_device=False)
+    return got, spans, lambda name: _moved(after, before, name)
+
+
+@pytest.mark.parametrize("n, dispatches, pad", [(40, 1, 40), (200, 4, 8)])
+def test_muhash_product_over_the_threshold_counts_device_elements(n, dispatches, pad):
+    """However many chunks a product has, they are launched together and read
+    back in one wait: one ``muhash.device_dispatch`` span, the padding outside it."""
+    from kaspa_tpu.crypto import muhash
+
+    got, spans, moved = _captured(lambda: muhash.bulk_element_product(_preimages(n, 1)))
+    assert moved("muhash_device_elements") == n
+    assert moved("muhash_device_dispatches") == {"64": dispatches}
+    assert moved("muhash_device_waits") == 1
+    assert moved("muhash_host_elements") == 0
+    assert got == muhash.bulk_element_product(_preimages(n, 1), use_device=False)
     phases = [(s["attrs"]["phase"], s["attrs"]["elements"]) for s in _named(spans, "muhash.host_prepare")]
-    assert phases == [("elements", 40), ("pad", 40)]
+    assert phases == [("elements", n), ("pad", pad)]
     (dispatch,) = _named(spans, "muhash.device_dispatch")
-    assert dispatch["attrs"] == {"bucket": 64, "elements": 40}
+    assert dispatch["attrs"] == {"bucket": 64, "elements": n, "dispatches": dispatches}
     assert not any(_inside(p, dispatch) for p in _named(spans, "muhash.host_prepare"))
+
+
+def test_muhash_commit_with_two_device_products_waits_once(monkeypatch):
+    """The numerator's chunks are on the device while the denominator's
+    elements are derived: one wait and one span for the commit, and the
+    second product's preparation lies inside that span."""
+    from kaspa_tpu.crypto import muhash
+
+    monkeypatch.setattr(muhash, "_tx_element_preimages", lambda adds, removes, daa: (adds, removes))
+    adds, removes = _preimages(100, 3), _preimages(70, 4)
+    committed = muhash.MuHash()
+    _, spans, moved = _captured(lambda: committed.add_transactions_batch([(adds, removes, 0)]))
+    assert moved("muhash_device_dispatches") == {"64": 4}
+    assert moved("muhash_device_elements") == 170
+    assert moved("muhash_device_waits") == 1
+    on_host = muhash.MuHash()
+    on_host.add_transactions_batch([(adds, removes, 0)], use_device=False)
+    assert (committed.numerator, committed.denominator) == (on_host.numerator, on_host.denominator)
+    (dispatch,) = _named(spans, "muhash.device_dispatch")
+    assert dispatch["attrs"] == {"bucket": 64, "elements": 170, "dispatches": 4}
+    prepares = [(s["attrs"]["phase"], s["attrs"]["elements"], _inside(s, dispatch)) for s in _named(spans, "muhash.host_prepare")]
+    assert prepares == [
+        ("preimages", 170, False), ("elements", 100, False), ("pad", 36, False), ("elements", 70, True), ("pad", 6, True),
+    ]
+    (commit,) = _named(spans, "muhash.commit")
+    assert dispatch["parent"] == commit["span"] and _inside(dispatch, commit)
+
+
+def _recording_tree_product(monkeypatch):
+    """``_tree_product`` replaced by a host fake whose results say when they
+    are read: the returned list holds ("launch", i) / ("read", i) in order."""
+    from kaspa_tpu.ops import bigint as bi
+    from kaspa_tpu.ops import muhash_ops
+
+    events = []
+
+    class Result:
+        def __init__(self, i, limbs):
+            self.i, self.limbs = i, limbs
+
+        def __array__(self, *args, **kwargs):
+            events.append(("read", self.i))
+            return self.limbs
+
+    def fake(x, levels):
+        rows = np.asarray(x)
+        assert rows.shape == (1 << levels, muhash_ops.F.W) and rows.dtype == np.int32
+        product = 1
+        for row in rows:
+            product = product * bi.limbs_to_int(row) % muhash_ops.F.modulus
+        i = sum(kind == "launch" for kind, _ in events)
+        events.append(("launch", i))
+        return Result(i, bi.ints_to_limbs([product], muhash_ops.F.W)[0].astype(np.int32))
+
+    monkeypatch.setattr(muhash_ops, "_tree_product", fake)
+    return events
+
+
+def test_every_launch_of_a_group_precedes_its_first_read(monkeypatch):
+    from kaspa_tpu.crypto import muhash
+
+    events = _recording_tree_product(monkeypatch)
+    lists = [_preimages(200, 5), _preimages(10, 6), _preimages(70, 7)]  # 4 chunks, host, 2 chunks
+    got = muhash.bulk_element_products(lists)
+    assert [e[0] for e in events] == ["launch"] * 6 + ["read"] * 6
+    assert got == muhash.bulk_element_products(lists, use_device=False)
+
+
+def test_in_flight_bound_reads_a_group_back_before_the_next_launch(monkeypatch):
+    import random
+
+    from kaspa_tpu.ops import muhash_ops
+
+    rng = random.Random(12)
+    vals = [rng.randrange(muhash_ops.F.modulus) for _ in range(300)]  # 4 x 64 + 44: five chunks
+    expected = 1
+    for v in vals:
+        expected = expected * v % muhash_ops.F.modulus
+    monkeypatch.setattr(muhash_ops, "MAX_IN_FLIGHT", 2)
+    got, spans, moved = _captured(lambda: muhash_ops.batch_product_ints(vals))
+    assert got == expected
+    assert moved("muhash_device_dispatches") == {"64": 5} and moved("muhash_device_waits") == 3
+    dispatches = _named(spans, "muhash.device_dispatch")
+    assert [(d["attrs"]["dispatches"], d["attrs"]["elements"]) for d in dispatches] == [(2, 128), (2, 128), (1, 44)]
+    assert all(a["end_ns"] <= b["start_ns"] for a, b in zip(dispatches, dispatches[1:]))
+    # and with the fake: never more than two launched and unread
+    events = _recording_tree_product(monkeypatch)
+    assert muhash_ops.batch_product_ints(vals) == expected
+    assert [e[0] for e in events] == ["launch", "launch", "read", "read"] * 2 + ["launch", "read"]
 
 
 def test_muhash_product_under_the_threshold_counts_host_elements():
