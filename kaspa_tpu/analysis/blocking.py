@@ -42,9 +42,17 @@ def _terminal_name(node: ast.AST) -> str:
     return ""
 
 
+def lock_guard_name(node: ast.AST) -> str:
+    """What a with-item's lock goes by: ``x.locked_for(who)`` (LockCtx: the
+    lock, its wait timed as a span) is a guard on ``x``."""
+    if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute) and node.func.attr == "locked_for":
+        return _terminal_name(node.func.value)
+    return _terminal_name(node)
+
+
 def is_lock_expr(node: ast.AST) -> bool:
     """Does this with-item expression look like a lock guard?"""
-    name = _terminal_name(node).lower()
+    name = lock_guard_name(node).lower()
     if not name:
         return False
     if isinstance(node, ast.Call) and name in ("lockctx", "ranked_lock"):

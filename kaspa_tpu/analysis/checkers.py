@@ -20,6 +20,7 @@ from kaspa_tpu.analysis.blocking import (
     _walk_shallow,
     blocking_reason,
     is_lock_expr,
+    lock_guard_name,
 )
 from kaspa_tpu.analysis.callgraph import NO_EXPAND, CallSite, render_chain
 from kaspa_tpu.analysis.core import Finding, Project, SourceFile, register_checker
@@ -58,7 +59,7 @@ def check_blocking_under_lock(project: Project, f: SourceFile) -> list[Finding]:
         if not isinstance(node, (ast.With, ast.AsyncWith)):
             continue
         lock_names = [
-            _terminal_name(item.context_expr)
+            lock_guard_name(item.context_expr)
             for item in node.items
             if is_lock_expr(item.context_expr)
         ]

@@ -9,12 +9,13 @@ verification for the whole wave rides the verify plane off-lock as the
 state-identical to the per-tx ``validate_and_insert_transaction`` path.
 """
 
-from kaspa_tpu.ingest.queue import SOURCE_P2P, SOURCE_RPC, IngestQueue
+from kaspa_tpu.ingest.queue import SOURCE_P2P, SOURCE_RPC, SOURCE_UNORPHAN, IngestQueue
 from kaspa_tpu.ingest.tier import AdmissionTicket, IngestConfig, IngestTier
 
 __all__ = [
     "SOURCE_P2P",
     "SOURCE_RPC",
+    "SOURCE_UNORPHAN",
     "AdmissionTicket",
     "IngestConfig",
     "IngestQueue",

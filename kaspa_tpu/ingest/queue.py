@@ -21,6 +21,7 @@ from kaspa_tpu.observability.core import REGISTRY
 
 SOURCE_RPC = "rpc"
 SOURCE_P2P = "p2p"
+SOURCE_UNORPHAN = "unorphan"  # a block handing back the orphans it gave parents (its lane appears on first use)
 
 _SUBMITTED = REGISTRY.counter_family(
     "ingest_submitted", "source", help="transactions offered to the ingest queue, by source"

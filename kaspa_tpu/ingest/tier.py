@@ -34,7 +34,7 @@ from kaspa_tpu.utils.sync import ranked_lock
 import time
 from dataclasses import dataclass
 
-from kaspa_tpu.ingest.queue import SOURCE_RPC, IngestQueue
+from kaspa_tpu.ingest.queue import SOURCE_RPC, SOURCE_UNORPHAN, IngestQueue
 from kaspa_tpu.mempool.mempool import MempoolError
 from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY, SIZE_BUCKETS
@@ -51,6 +51,8 @@ _WAVE_MS = REGISTRY.histogram(
 _OUTCOMES = REGISTRY.counter_family(
     "ingest_outcomes", "outcome", help="admission verdicts (accepted/orphaned/rejected)"
 )
+_WAVES = REGISTRY.counter("ingest_waves", help="admission waves run")
+_WAVE_TXS = REGISTRY.counter("ingest_wave_txs", help="transactions those waves carried")
 from kaspa_tpu.observability.shed import SHED as _SHED  # noqa: E402  (family declared once there)
 
 ACCEPTED = "accepted"
@@ -190,6 +192,17 @@ class IngestTier:
             )
         return ticket
 
+    def resubmit(self, txs) -> list[AdmissionTicket]:
+        """Hand transactions back for admission together: the orphans whose
+        parents a block just created (the reference's unorphan step of
+        ``on_new_block``).  A running worker takes them as its next wave;
+        without one the caller pumps here and now, which is safe under
+        ``self.lock`` (re-entrant), as the relay handler's ``admit`` is."""
+        tickets = [self.submit(tx, SOURCE_UNORPHAN) for tx in txs]
+        if self._worker is None:
+            self.pump()
+        return tickets
+
     def pump(self) -> int:
         """Synchronously drain the queue in waves; returns txs admitted.
 
@@ -246,13 +259,13 @@ class IngestTier:
     def _admit_wave(self, tickets: list[AdmissionTicket]) -> None:
         t0 = time.perf_counter()
         try:
-            with trace.span("ingest.wave", size=len(tickets)):
+            with trace.span("ingest.wave", size=len(tickets)) as sp:
                 checker = self.mining.consensus.transaction_validator.new_checker(
                     traffic_class=TX_CLASS
                 )
                 prepared: dict[int, object] = {}
                 # phase 1: contextual pre-checks in arrival order, on the lock
-                with self.lock:
+                with self.lock.locked_for("ingest"):
                     for i, t in enumerate(tickets):
                         try:
                             prepared[i] = self.mining.prepare_transaction(t.tx, checker, token=i)
@@ -261,7 +274,7 @@ class IngestTier:
                 # phase 2: one batched verify for the whole wave, off the lock
                 errs = checker.dispatch_async().result() if prepared else {}
                 # phase 3: verdicts + inserts in arrival order, on the lock
-                with self.lock:
+                with self.lock.locked_for("ingest"):
                     for i, t in enumerate(tickets):
                         p = prepared.get(i)
                         if p is None:
@@ -272,6 +285,10 @@ class IngestTier:
                             self._finish_ticket(t, REJECTED, error=e)
                             continue
                         self._finish_ticket(t, ORPHANED if p.orphan else ACCEPTED, evicted=evicted)
+                sp.set(
+                    orphans=sum(t.status == ORPHANED for t in tickets),
+                    rejected=sum(t.status == REJECTED for t in tickets),
+                )
         finally:
             # no ticket ever leaks unresolved: a wave-level failure (device
             # dispatch error, unexpected crash between phases) rejects every
@@ -283,6 +300,8 @@ class IngestTier:
                     )
         with self._mu:
             self._waves += 1
+        _WAVES.inc()
+        _WAVE_TXS.inc(len(tickets))
         _WAVE_SIZE.observe(len(tickets))
         _WAVE_MS.observe((time.perf_counter() - t0) * 1000.0)
 

@@ -18,6 +18,7 @@ from kaspa_tpu.consensus.model.block import Block
 from kaspa_tpu.consensus.processes.coinbase import MinerData
 from kaspa_tpu.consensus.processes.transaction_validator import TxRuleError
 from kaspa_tpu.mempool.mempool import Mempool, MempoolConfig, MempoolError, MempoolTx
+from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY
 
 _TEMPLATE_REBUILD_MS = REGISTRY.histogram(
@@ -254,13 +255,18 @@ class MiningManager:
         self.consensus.notification_root.notify(Notification("new-block-template", {}))
 
     def handle_new_block_transactions(self, block_txs: list[Transaction], daa_score: int) -> list[MempoolTx]:
-        accepted_ids = [tx.id() for tx in block_txs]
-        self.mempool.handle_accepted_transactions(accepted_ids, daa_score)
-        spent = [inp.previous_outpoint for tx in block_txs for inp in tx.inputs]
-        self.mempool.remove_conflicting(spent)
-        self.mempool.expire(daa_score)
-        self.template_cache.clear()
-        # a fresh template is now available (notify/events.rs NewBlockTemplate)
-        self._notify_new_template()
-        # attempt to unorphan txs whose parents were just created
-        return self.mempool.unorphan_candidates(set(accepted_ids))
+        """The mempool's half of ``on_new_block``.  Returns the orphans whose
+        parents the block just created, taken out of the orphan pool: the
+        caller hands them back to admission (they are revalidated there)."""
+        with trace.span("mempool.handle_block", txs=len(block_txs)) as sp:
+            accepted_ids = [tx.id() for tx in block_txs]
+            self.mempool.handle_accepted_transactions(accepted_ids, daa_score)
+            spent = [inp.previous_outpoint for tx in block_txs for inp in tx.inputs]
+            self.mempool.remove_conflicting(spent)
+            self.mempool.expire(daa_score)
+            self.template_cache.clear()
+            # a fresh template is now available (notify/events.rs NewBlockTemplate)
+            self._notify_new_template()
+            unorphaned = self.mempool.unorphan_candidates(set(accepted_ids))
+            sp.set(unorphaned=len(unorphaned))
+        return unorphaned

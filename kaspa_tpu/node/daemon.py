@@ -841,7 +841,7 @@ class Daemon:
             tx = wire_to_tx(params["tx"])
             txid = self.rpc.submit_transaction(tx)
             return txid.hex()
-        with self._dispatch_lock:
+        with self._dispatch_lock.locked_for(method):
             # graftlint: allow(blocking-under-lock) -- RPC mutation path serializes consensus work by design; device round trips run under the dispatch lock deliberately
             return self._dispatch(method, params)
 
@@ -1020,7 +1020,7 @@ class Daemon:
                 return self.mining.get_block_template(miner_data)
 
         def submit(block):
-            with self._dispatch_lock:
+            with self._dispatch_lock.locked_for("block"):
                 # graftlint: allow(blocking-under-lock) -- stratum submit serializes with the RPC mutation path; insert+unorphan device waits are the locked section's job
                 return self.node.submit_block(block)
 

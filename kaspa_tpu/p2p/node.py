@@ -185,9 +185,8 @@ class Node:
         mempool_seed: int | None = None,
         template_debounce: float = 0.0,
         ident: int | None = None,
+        pipeline=None,
     ):
-        import threading
-
         from kaspa_tpu.consensus.manager import ConsensusManager
         from kaspa_tpu.pipeline import ConsensusPipeline
 
@@ -239,8 +238,13 @@ class Node:
         # the concurrent pipeline IS the block intake — relay, RPC submit and
         # IBD all flow through it (the reference runs its 4-processor
         # pipeline always, consensus/src/consensus/mod.rs:369-401; there is
-        # no synchronous alternative path)
-        self.pipeline = ConsensusPipeline(consensus, workers=2)
+        # no synchronous alternative path).  A consensus that already runs
+        # behind a pipeline (one that replayed a chain into it before the
+        # node came up) hands that pipeline over: a second one over the same
+        # consensus would be a second virtual worker
+        if pipeline is not None and pipeline.consensus is not consensus:
+            raise ValueError("the pipeline handed over runs another consensus")
+        self.pipeline = pipeline if pipeline is not None else ConsensusPipeline(consensus, workers=2)
         # batched admission front door (kaspa_tpu/ingest/): RPC submits and
         # P2P relay enqueue tickets; whoever pumps under the node lock
         # admits every concurrently-queued entrant in one wave with a single
@@ -377,10 +381,21 @@ class Node:
 
     def submit_block(self, block: Block) -> str:
         status = self.pipeline.validate_and_insert_block(block)
-        self.mining.handle_new_block_transactions(block.transactions, self.consensus.get_virtual_daa_score())
+        self._on_new_block_transactions(block)
         self._try_unorphan(block.hash)
         self.broadcast_block(block)
         return status
+
+    def _on_new_block_transactions(self, block: Block) -> None:
+        """The mempool's half of ``on_new_block`` (flow_context.rs): the
+        block's transactions leave the pool, and the orphans it gave parents
+        go back through admission together (mining manager.rs
+        ``handle_new_block_transactions`` + ``revalidate``)."""
+        unorphaned = self.mining.handle_new_block_transactions(
+            block.transactions, self.consensus.get_virtual_daa_score()
+        )
+        if unorphaned:
+            self.ingest.resubmit([entry.tx for entry in unorphaned])
 
     def submit_transaction(self, tx) -> list[bytes]:
         """RPC-facing admission through the batched ingest tier.
@@ -887,7 +902,7 @@ class Node:
             if self.score_misbehavior(peer, "invalid_block", 40) and hasattr(peer, "close"):
                 peer.close()
             return
-        self.mining.handle_new_block_transactions(block.transactions, self.consensus.get_virtual_daa_score())
+        self._on_new_block_transactions(block)
         self._try_unorphan(block.hash)
         self.broadcast_block(block)
 

@@ -272,7 +272,7 @@ class WirePeer:
                 self.metrics.frames_rx.inc()
                 self.metrics.bytes_rx.inc(nbytes)
                 self.metrics.msgs_rx.inc(msg_type)
-                with self.node.lock:
+                with self.node.lock.locked_for(msg_type):
                     # graftlint: allow(blocking-under-lock) -- every p2p message is handled under the node lock (the node's serialization point); IBD batch inserts legitimately wait on verify futures there
                     self.node._handle(self, msg_type, payload)
                 if self.handshaken and not steady:

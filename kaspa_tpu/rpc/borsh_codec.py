@@ -639,7 +639,7 @@ def handle_frame(daemon, data: bytes, notification_sink=None, subscriber_ref=Non
             block, _allow_non_daa = decode_submit_block_request(r)
             w = io.BytesIO()
             try:
-                with daemon._dispatch_lock:
+                with daemon._dispatch_lock.locked_for("block"):
                     # graftlint: allow(blocking-under-lock) -- borsh submit serializes with the RPC mutation path under the dispatch lock; insert+unorphan device waits are deliberate
                     daemon.node.submit_block(block)
                 encode_submit_block_response(w, None)

@@ -183,7 +183,15 @@ class RpcCoreService:
             status = self.api.validate_and_insert_block(block)
         except RuleError as e:
             raise RpcError(f"block rejected: {e}") from e
-        self.mining.handle_new_block_transactions(block.transactions, self.api.get_virtual_daa_score())
+        # no node, so no ingest tier: the orphans this block gave parents are
+        # revalidated one by one (their verdicts are nobody's to hear)
+        from kaspa_tpu.consensus.processes.transaction_validator import TxRuleError
+
+        for entry in self.mining.handle_new_block_transactions(block.transactions, self.api.get_virtual_daa_score()):
+            try:
+                self.mining.validate_and_insert_transaction(entry.tx)
+            except (MempoolError, TxRuleError):
+                pass
         return status
 
     def get_block_template(self, pay_address: str, extra_data: bytes = b"") -> Block:

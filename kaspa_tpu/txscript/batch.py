@@ -64,6 +64,13 @@ from kaspa_tpu.txscript.vm import MAX_SCRIPT_ELEMENT_SIZE
 # batch, which is the first thing to check when occupancy drops
 _JOBS = REGISTRY.counter_family("txscript_batch_jobs", "kind", help="signature jobs queued for device dispatch")
 _SIGCACHE_SKIPS = REGISTRY.counter("txscript_batch_sigcache_skips", help="jobs answered by the sig cache pre-dispatch")
+# who asked the signature cache and how often it answered, by the checker's
+# traffic class (one increment a dispatch round): a block whose transactions
+# came through the mempool first reads hits == lookups and waits for no device
+_BLOCK_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_block_lookups", help="signature-cache lookups by block-path checkers")
+_BLOCK_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_block_hits", help="of the block path's lookups, those the cache answered (either verdict)")
+_TX_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_tx_lookups", help="signature-cache lookups by checkers of a traffic class (the ingest tier's waves)")
+_TX_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_tx_hits", help="of the waves' lookups, those the cache answered (either verdict)")
 _VM_FALLBACKS = REGISTRY.counter("txscript_vm_fallbacks", help="inputs routed to the host VM instead of the batch")
 _FALLBACK_BATCH = REGISTRY.histogram(
     "txscript_fallback_batch_size", SIZE_BUCKETS, help="deferred VM fallback jobs per dispatch"
@@ -276,6 +283,7 @@ class BatchScriptChecker:
         self._fallbacks: list[_FallbackJob] = []
         self._multisigs: list[_MultisigInput] = []
         self._results: dict[int, Exception | None] = {}
+        self._cache_lookups = self._cache_hits = 0  # since the last dispatch
 
     def collect_tx(self, token: int, tx, utxo_entries, reused=None, pov_daa_score=None, seq_commit_accessor=None) -> None:
         """Queue all input script checks of `tx`; result under `token`.
@@ -405,7 +413,9 @@ class BatchScriptChecker:
         is queued as a device job that ends in ``callback(ok, fail)``."""
         cache_key = (kind, sig, msg, pubkey)
         cached = self.sig_cache.get(cache_key)
+        self._cache_lookups += 1
         if cached is not None:
+            self._cache_hits += 1
             _SIGCACHE_SKIPS.inc()
             return cached
         _JOBS.inc(kind)
@@ -442,6 +452,13 @@ class BatchScriptChecker:
         multisigs, self._multisigs = self._multisigs, []
         jobs, self._jobs = self._jobs, []
         results, self._results = self._results, {}
+        if self._cache_lookups:
+            lookups, hits = (
+                (_BLOCK_CACHE_LOOKUPS, _BLOCK_CACHE_HITS) if self.traffic_class is None else (_TX_CACHE_LOOKUPS, _TX_CACHE_HITS)
+            )
+            lookups.inc(self._cache_lookups)
+            hits.inc(self._cache_hits)
+            self._cache_lookups = self._cache_hits = 0
 
         pending = None
         if fallbacks:

@@ -211,6 +211,7 @@ class LockCtx:
     def __init__(self, name: str, rank: int, lock=None, reentrant: bool = True):
         self.name = name
         self.rank = rank
+        self._wait_span = f"wait.{name}_lock"  # what locked_for() records the wait under
         if lock is not None:
             self._lock = lock
         else:
@@ -219,6 +220,12 @@ class LockCtx:
     def condition(self) -> threading.Condition:
         """A Condition bound to this lock; use inside ``with ctx:``."""
         return threading.Condition(self._lock)
+
+    def locked_for(self, who: str) -> "_LockedFor":
+        """``with lock.locked_for(who):`` is ``with lock:`` whose wait for
+        the lock is a ``wait.<name>_lock`` span carrying ``who`` (a block
+        behind an admission wave, a wave behind a block)."""
+        return _LockedFor(self, who)
 
     def __enter__(self):
         tracked = _LOCK_DEBUG
@@ -249,3 +256,29 @@ class LockCtx:
             entry = stack.pop()
             _trace_record(self.name, time.perf_counter() - entry[3])
         return False
+
+
+_spans = None  # kaspa_tpu.observability.trace, from the first locked_for() on: observability.core imports this module
+
+
+class _LockedFor:
+    """What ``LockCtx.locked_for`` hands back: the lock's own enter and exit,
+    with the wait for the lock timed as a span."""
+
+    __slots__ = ("_ctx", "_who")
+
+    def __init__(self, ctx: LockCtx, who: str):
+        self._ctx = ctx
+        self._who = who
+
+    def __enter__(self) -> LockCtx:
+        global _spans
+        if _spans is None:
+            from kaspa_tpu.observability import trace as _spans
+        ctx = self._ctx
+        with _spans.span(ctx._wait_span, who=self._who):
+            ctx.__enter__()
+        return ctx
+
+    def __exit__(self, *exc):
+        return self._ctx.__exit__(*exc)

@@ -57,6 +57,25 @@ def test_blocking_under_lock_direct(tmp_path):
     assert report["ok"] is False
 
 
+def test_blocking_under_lock_names_the_lock_behind_locked_for(tmp_path):
+    # `with x.locked_for(who):` (LockCtx: the lock, its wait timed as a span)
+    # is a guard on x; a guard on something that is no lock stays none
+    report = _lint(tmp_path, {"mod.py": """
+        def bad(self):
+            with self.node.lock.locked_for("block"):
+                self.fut.result()
+            with self._dispatch_lock.locked_for("getInfo"):
+                self.fut.result()
+
+        def fine(self):
+            with self.spans.locked_for("block"):
+                self.fut.result()
+    """})
+    found = {f["line"]: f["message"] for f in report["findings"] if f["checker"] == "blocking-under-lock"}
+    assert sorted(found) == [4, 6]
+    assert "while holding lock:" in found[4] and "while holding _dispatch_lock:" in found[6]
+
+
 def test_blocking_under_lock_condvar_wait_exempt(tmp_path):
     # a condition-variable wait RELEASES the lock — exempt by receiver
     # naming convention; an Event.wait parks while still holding it

@@ -413,10 +413,11 @@ def test_reject_frame_delivered_before_close():
     from kaspa_tpu.p2p import wire
     from kaspa_tpu.p2p.node import MSG_REJECT, ProtocolError
     from kaspa_tpu.p2p.transport import WirePeer
+    from kaspa_tpu.utils.sync import LockCtx
 
     class StubNode:
         def __init__(self):
-            self.lock = threading.Lock()
+            self.lock = LockCtx("node", rank=5)  # what Node.lock is: the reader takes it through locked_for()
             self.peers = []
 
         def _handle(self, peer, msg_type, payload):
