@@ -1364,7 +1364,7 @@ class Consensus:
         # UTXO population, sighash and job staging of one merged block: what
         # the virtual stage does for its scripts before the round trip
         with trace.span("txscript.collect", txs=len(txs) - 1, speculative=shared) as sp:
-            jobs0, multisig0 = checker.queued_jobs(), checker.queued_multisig_inputs()
+            jobs0, multisig0, memo0 = checker.queued_jobs(), checker.queued_multisig_inputs(), checker.memo_hits()
             for i, tx in enumerate(txs):
                 if i == 0:
                     continue  # coinbase
@@ -1387,7 +1387,10 @@ class Consensus:
                 except TxRuleError:
                     continue
                 staged.append((token, tx, entries, fee))
-            sp.set(jobs=checker.queued_jobs() - jobs0, multisig=checker.queued_multisig_inputs() - multisig0)
+            sp.set(
+                jobs=checker.queued_jobs() - jobs0, multisig=checker.queued_multisig_inputs() - multisig0,
+                memo_hits=checker.memo_hits() - memo0,
+            )
         if shared:
             return staged
         script_results = checker.dispatch()

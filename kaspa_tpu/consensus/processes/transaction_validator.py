@@ -41,6 +41,9 @@ class TransactionValidator:
         self.params = params
         self.coinbase_maturity = params.coinbase_maturity
         self.sig_cache = sig_cache if sig_cache is not None else SigCache()
+        # the script-verdict memo over it (txscript/batch.py): the same bounded
+        # map at the same size, keyed by a transaction over its spent outputs
+        self.tx_memo = SigCache()
         self.mass_calculator = MassCalculator.from_params(params)
         if vm_fallback is None:
             # nonstandard scripts run through the host VM with the shared
@@ -71,7 +74,7 @@ class TransactionValidator:
         self.vm_fallback = vm_fallback
 
     def new_checker(self, traffic_class: str | None = None) -> BatchScriptChecker:
-        return BatchScriptChecker(self.sig_cache, self.vm_fallback, traffic_class=traffic_class)
+        return BatchScriptChecker(self.sig_cache, self.vm_fallback, traffic_class=traffic_class, tx_memo=self.tx_memo)
 
     # --- in isolation (tx_validation_in_isolation.rs) ---
 

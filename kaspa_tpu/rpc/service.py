@@ -323,6 +323,7 @@ class RpcCoreService:
         import jax
 
         sc = self.consensus.transaction_validator.sig_cache
+        memo = self.consensus.transaction_validator.tx_memo
         obs = observability_snapshot()
         devs = jax.devices()
         return {
@@ -335,6 +336,9 @@ class RpcCoreService:
             "virtual_daa_score": self.api.get_virtual_daa_score(),
             "sig_cache_hits": sc.hits,
             "sig_cache_misses": sc.misses,
+            # the script-verdict memo over it: transactions asked, and those not collected again
+            "tx_memo_hits": memo.hits,
+            "tx_memo_lookups": memo.hits + memo.misses,
             "process_counters": asdict(self.consensus.counters.snapshot()),
             "process_metrics": asdict(self.perf_monitor.sample()),
             # per-lock acquisition/hold aggregates when KASPA_TPU_LOCK_DEBUG

@@ -10,6 +10,14 @@ checks of an entire block/mergeset are *collected* into one device batch:
                      overlapped with the host-VM fallback lane
     resolve phase  : validity bitmask mapped back to per-input results
 
+Over the signature cache sits the script-verdict memo (Bitcoin Core's
+script-execution cache, validation.cpp CheckInputScripts): a transaction whose
+every input took a batch lane and was answered valid is remembered with its
+signature scripts and the amounts and scripts of the outputs it spent, and is
+not collected again while the memo holds it.  The sink of a wide DAG hops
+between chains that each ask for the same spends; the memo answers before a
+script is classified, parsed or hashed.
+
 Consensus equivalence: three script forms take the batch path, chosen by what
 the script is: canonical P2PK Schnorr, canonical P2PK ECDSA, and a P2SH spend
 whose redeem script is the canonical m-of-n multisig
@@ -64,13 +72,18 @@ from kaspa_tpu.txscript.vm import MAX_SCRIPT_ELEMENT_SIZE
 # batch, which is the first thing to check when occupancy drops
 _JOBS = REGISTRY.counter_family("txscript_batch_jobs", "kind", help="signature jobs queued for device dispatch")
 _SIGCACHE_SKIPS = REGISTRY.counter("txscript_batch_sigcache_skips", help="jobs answered by the sig cache pre-dispatch")
-# who asked the signature cache and how often it answered, by the checker's
-# traffic class (one increment a dispatch round): a block whose transactions
-# came through the mempool first reads hits == lookups and waits for no device
-_BLOCK_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_block_lookups", help="signature-cache lookups by block-path checkers")
-_BLOCK_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_block_hits", help="of the block path's lookups, those the cache answered (either verdict)")
-_TX_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_tx_lookups", help="signature-cache lookups by checkers of a traffic class (the ingest tier's waves)")
-_TX_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_tx_hits", help="of the waves' lookups, those the cache answered (either verdict)")
+# signature checks asked of the caches and answered without the device, by the
+# checker's traffic class (one increment a dispatch round): a block whose
+# transactions came through the mempool first reads hits == lookups and waits
+# for no device.  A transaction the verdict memo answers counts as the checks
+# its verdict stood for, asked and answered, whichever cache held them
+_BLOCK_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_block_lookups", help="signature checks block-path checkers asked of the caches (a verdict-memo hit counts as the checks it stood for)")
+_BLOCK_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_block_hits", help="of the block path's checks, those a cache answered without the device (either verdict; a verdict-memo hit counts as the checks it stood for)")
+_TX_CACHE_LOOKUPS = REGISTRY.counter("txscript_sig_cache_tx_lookups", help="signature checks asked of the caches by checkers of a traffic class (the ingest tier's waves), counted as the block path's are")
+_TX_CACHE_HITS = REGISTRY.counter("txscript_sig_cache_tx_hits", help="of the waves' checks, those a cache answered without the device, counted as the block path's are")
+# the verdict memo, asked once a transaction ahead of the signature cache
+_MEMO_LOOKUPS = REGISTRY.counter("txscript_tx_memo_lookups", help="transactions asked of the script-verdict memo at collect")
+_MEMO_HITS = REGISTRY.counter("txscript_tx_memo_hits", help="of those, transactions the memo answered valid: none of their scripts was collected")
 _VM_FALLBACKS = REGISTRY.counter("txscript_vm_fallbacks", help="inputs routed to the host VM instead of the batch")
 _FALLBACK_BATCH = REGISTRY.histogram(
     "txscript_fallback_batch_size", SIZE_BUCKETS, help="deferred VM fallback jobs per dispatch"
@@ -86,6 +99,20 @@ _MULTISIG_RERUNS = REGISTRY.counter(
 _VM_RETRIES = REGISTRY.counter(
     "txscript_vm_fault_retries", help="VM fallback jobs retried after an injected transient fault"
 )
+
+
+def _memo_key(tx, utxo_entries) -> tuple:
+    """What the batch lanes' verdict on a transaction is a function of, and
+    nothing else: the id, the signature scripts and compute commits the id
+    leaves out (a malleated signature script under the same id must miss), and
+    of each spent output the two fields the sighash and the lanes read.  Not
+    its ``block_daa_score``: one outpoint carries another score on every chain
+    that accepted its creator, which is exactly where the memo is asked."""
+    return (
+        tx.id(),
+        tuple([(inp.signature_script, inp.compute_commit) for inp in tx.inputs]),
+        tuple([(entry.amount, entry.script_public_key) for entry in utxo_entries]),
+    )
 
 
 def _default_fallback_workers() -> int:
@@ -264,6 +291,10 @@ class BatchScriptChecker:
     device submissions (e.g. ``"standalone_tx"`` for the ingest tier's
     admission batches).  Class-qualified kinds get their own coalesce
     target/age and counters in ops/dispatch; results are bit-identical.
+
+    ``tx_memo``: the script-verdict memo, a second ``SigCache`` keyed by
+    ``_memo_key``; its value is the number of signature checks the verdict
+    stood for.  A checker made without one remembers nothing past itself.
     """
 
     def __init__(
@@ -272,8 +303,10 @@ class BatchScriptChecker:
         vm_fallback=None,
         fallback_workers: int | None = None,
         traffic_class: str | None = None,
+        tx_memo: SigCache | None = None,
     ):
         self.sig_cache = sig_cache if sig_cache is not None else SigCache()
+        self.tx_memo = tx_memo if tx_memo is not None else SigCache()
         # contract: fn(tx, entries, input_index, reused, pov_daa_score) — the
         # daa score drives fork-activation gating inside the engine
         self.vm_fallback = vm_fallback
@@ -284,19 +317,37 @@ class BatchScriptChecker:
         self._multisigs: list[_MultisigInput] = []
         self._results: dict[int, Exception | None] = {}
         self._cache_lookups = self._cache_hits = 0  # since the last dispatch
+        self._memo_lookups = self._memo_hits = 0  # since the last dispatch
+        # (token, memo key, signature checks) of each transaction collected
+        # wholly into the batch lanes: the memo's entries if all answer valid
+        self._memo_candidates: list[tuple] = []
 
     def collect_tx(self, token: int, tx, utxo_entries, reused=None, pov_daa_score=None, seq_commit_accessor=None) -> None:
         """Queue all input script checks of `tx`; result under `token`.
         ``pov_daa_score`` feeds fork-activation gating in the VM fallback;
         ``seq_commit_accessor`` backs OpChainblockSeqCommit post-Toccata."""
+        self._results.setdefault(token, None)
+        key = _memo_key(tx, utxo_entries)
+        checks = self.tx_memo.get(key)
+        self._memo_lookups += 1
+        if checks is not None:
+            # the collection below, done before: it asked `checks` signature
+            # checks, every one was answered valid, and no lane read the
+            # caller's context (``pov_daa_score``, the seq-commit accessor)
+            self._memo_hits += 1
+            self._cache_lookups += checks
+            self._cache_hits += checks
+            return
         if reused is None:
             reused = chash.SigHashReusedValues()
-        self._results.setdefault(token, None)
+        lookups0, vm0 = self._cache_lookups, len(self._fallbacks)
         for i, (inp, entry) in enumerate(zip(tx.inputs, utxo_entries)):
             try:
                 self._collect_input(token, tx, utxo_entries, i, inp, entry, reused, pov_daa_score, seq_commit_accessor)
             except ScriptCheckError as e:
                 self._fail(token, e)
+        if len(self._fallbacks) == vm0:  # no input went to the host VM, which reads the caller's context
+            self._memo_candidates.append((token, key, self._cache_lookups - lookups0))
 
     def _fail(self, token: int, err: Exception) -> None:
         if self._results.get(token) is None:
@@ -430,6 +481,10 @@ class BatchScriptChecker:
         """Multisig inputs that took the batch path since the last dispatch."""
         return len(self._multisigs)
 
+    def memo_hits(self) -> int:
+        """Transactions the verdict memo answered since the last dispatch."""
+        return self._memo_hits
+
     def _effective_workers(self, jobs: int) -> int:
         w = self.fallback_workers if self.fallback_workers is not None else _default_fallback_workers()
         return min(w, jobs)
@@ -452,6 +507,11 @@ class BatchScriptChecker:
         multisigs, self._multisigs = self._multisigs, []
         jobs, self._jobs = self._jobs, []
         results, self._results = self._results, {}
+        memo_candidates, self._memo_candidates = self._memo_candidates, []
+        if self._memo_lookups:
+            _MEMO_LOOKUPS.inc(self._memo_lookups)
+            _MEMO_HITS.inc(self._memo_hits)
+            self._memo_lookups = self._memo_hits = 0
         if self._cache_lookups:
             lookups, hits = (
                 (_BLOCK_CACHE_LOOKUPS, _BLOCK_CACHE_HITS) if self.traffic_class is None else (_TX_CACHE_LOOKUPS, _TX_CACHE_HITS)
@@ -488,14 +548,18 @@ class BatchScriptChecker:
                 tickets["ecdsa"] = engine.submit(
                     f"{prefix}ecdsa", [(j.pubkey, j.msg, j.sig) for j in ecdsa]
                 )
-        return DispatchHandle(self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs)
+        return DispatchHandle(
+            self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, self.tx_memo, memo_candidates
+        )
 
 
 class DispatchHandle:
     """In-flight dispatch: owns the detached jobs/results of one round."""
 
-    def __init__(self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs):
+    def __init__(self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, tx_memo, memo_candidates):
         self.sig_cache = sig_cache
+        self.tx_memo = tx_memo
+        self._memo_candidates = memo_candidates
         self._fallbacks = fallbacks
         self._multisigs = multisigs
         self._pending = pending
@@ -562,6 +626,7 @@ class DispatchHandle:
         # input the walk does not accept is re-run through the host VM, which
         # reads them there and does no curve arithmetic of its own
         batch_failures: list = []  # (token, error) of the P2PK jobs, in queue order
+        reruns: list = []  # the VM jobs of multisig inputs the walk did not accept
         for jobs, mask in ((self._schnorr, schnorr_mask), (self._ecdsa, ecdsa_mask)):
             if mask is not None:
                 for j, ok in zip(jobs, mask):
@@ -581,4 +646,12 @@ class DispatchHandle:
             self._fail(job.token, ScriptCheckError(str(err), job.input_index))
         for token, err in batch_failures:
             self._fail(token, err)
+        # every lane has resolved: a transaction collected wholly into the
+        # batch lanes whose token stands at None had every script answered
+        # valid, whoever asked (block path, speculative worker, ingest wave);
+        # not one the VM re-ran, whose verdict reads the caller's context
+        rerun_tokens = {job.token for job in reruns}
+        for token, key, checks in self._memo_candidates:
+            if self._results[token] is None and token not in rerun_tokens:
+                self.tx_memo.insert(key, checks)
         return self._results

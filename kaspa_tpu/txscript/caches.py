@@ -1,11 +1,20 @@
-"""Signature cache (reference: crypto/txscript/src/caches.rs:14-55).
+"""The script caches' bounded map (reference: crypto/txscript/src/caches.rs:14-55).
 
-Bounded map keyed by (sig, msg, pubkey, kind) with random eviction, exactly
-like the reference's IndexMap+swap_remove scheme (the reference wraps it in
-a RwLock; here a plain Lock — the parallel VM fallback lane reads and
-writes it from pool threads, and the multi-step eviction must stay atomic).
-Shared across the validator so repeated relay/mempool/block validations of
-the same signature skip the device round-trip.
+One class, two uses, both owned by the ``TransactionValidator``:
+
+- the signature cache, keyed by (kind, sig, msg, pubkey) -> bool, shared
+  across the validator so repeated relay/mempool/block validations of the same
+  signature skip the device round-trip;
+- the script-verdict memo over it (txscript/batch.py ``_memo_key``), keyed by
+  a transaction with its signature scripts over the outputs it spends -> the
+  number of signature checks its all-valid verdict stood for, so a
+  transaction collected again is not classified, parsed or hashed again.
+
+Bounded with random eviction, exactly like the reference's
+IndexMap+swap_remove scheme (the reference wraps it in a RwLock; here a plain
+Lock — the parallel VM fallback lane and the RPC handlers read and write it
+from their own threads, and the multi-step eviction must stay atomic).  A
+stored value is never None: None is ``get``'s "not held".
 """
 
 from __future__ import annotations
@@ -20,7 +29,7 @@ class SigCache:
     def __init__(self, size: int = 10_000, seed: int | None = None):
         assert size > 0
         self.size = size
-        self._map: dict[tuple, bool] = {}
+        self._map: dict[tuple, int] = {}  # a verdict (bool) or a count, never None
         self._keys: list[tuple] = []
         self._rng = random.Random(seed)
         self._lock = ranked_lock("txscript.cache")
@@ -36,7 +45,7 @@ class SigCache:
                 self.hits += 1
             return v
 
-    def insert(self, key: tuple, value: bool) -> None:
+    def insert(self, key: tuple, value: int) -> None:
         with self._lock:
             if key in self._map:
                 self._map[key] = value

@@ -31,6 +31,7 @@ from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY
 from kaspa_tpu.ops import dispatch as coalesce
 from kaspa_tpu.txscript import standard
+from kaspa_tpu.txscript.caches import SigCache
 from kaspa_tpu.txscript.script_builder import ScriptBuilder
 
 PARAMS = simnet_params()
@@ -307,15 +308,22 @@ def test_mixed_block_identical_with_coalescing_on():
 
 def test_cache_answers_are_not_sent_again():
     """A second validation of the same spend through the same validator (as
-    the virtual stage does after a stage worker) asks the cache for every
-    pair and sends nothing."""
+    the virtual stage does after a stage worker) sends nothing: the verdict
+    memo answers the transaction, and where the memo no longer holds it the
+    signature cache is asked for every pair."""
     tv = TransactionValidator(PARAMS)
     spend = _multisig_spend(2, 3, False, [0, 2])
     assert _batch([spend], tv) == {0: None}
     before = _counters()
     assert _batch([spend], tv) == {0: None}
     assert _moved(before, "txscript_batch_jobs") == 0 and _moved(before, "secp_device_jobs") == 0
-    assert _moved(before, "txscript_batch_sigcache_skips") == 4
+    assert _moved(before, "txscript_tx_memo_hits") == 1 and _moved(before, "txscript_multisig_inputs") == 0
+    assert _moved(before, "txscript_sig_cache_block_lookups") == _moved(before, "txscript_sig_cache_block_hits") == 4
+    tv.tx_memo = SigCache()  # the memo evicted it
+    before = _counters()
+    assert _batch([spend], tv) == {0: None}
+    assert _moved(before, "txscript_batch_jobs") == 0 and _moved(before, "secp_device_jobs") == 0
+    assert _moved(before, "txscript_batch_sigcache_skips") == 4 and _moved(before, "txscript_tx_memo_hits") == 0
     assert _moved(before, "txscript_multisig_inputs") == 1 and _moved(before, "txscript_multisig_pairs") == 4
 
 
