@@ -117,6 +117,9 @@ _MISBEHAVIOR_POINTS = REGISTRY.counter_family(
 )
 _PEERS_BANNED = REGISTRY.counter("p2p_peers_banned", help="peers that crossed the ban-score threshold")
 _IBD_TIMEOUTS = REGISTRY.counter("p2p_ibd_timeouts", help="in-flight syncs abandoned for lack of progress")
+_IBD_CHUNKS_RX = REGISTRY.counter("p2p_ibd_chunks_rx", help="IBD batches that carried blocks and went through the pipeline")
+_IBD_BLOCKS_RX = REGISTRY.counter("p2p_ibd_blocks_rx", help="blocks of those batches")
+_IBD_BLOCKS_REJECTED = REGISTRY.counter("p2p_ibd_blocks_rejected", help="blocks of those batches the pipeline refused (RuleError), skipped")
 from kaspa_tpu.observability.shed import SHED as _SHED  # noqa: E402  (family declared once there)
 
 # serve-side SMT snapshot lifetime (prune_caches): a snapshot nobody has
@@ -229,6 +232,10 @@ class Node:
         self.peers: list = []  # the Hub (p2p/src/core/hub.rs)
         self.orphan_blocks: dict[bytes, Block] = {}  # flowcontext/orphans.rs
         self._ibd: dict = {}  # proof-IBD state machine (one active sync)
+        # the peer ibd_from() asked, until its last chunk is in or it has
+        # nothing to give: while set, that peer's reader records its waits
+        # on the socket as wait.p2p_frame spans
+        self._sync_peer = None
         # single-writer discipline: wire reader threads and RPC dispatch all
         # serialize consensus/mempool access through this lock.  Ranked
         # BELOW the pipeline's consensus-commit lock (rank 10): handlers
@@ -630,7 +637,10 @@ class Node:
             if not payload["done"]:
                 # bounded chunks: pull the next batch from where we stopped
                 peer.send(MSG_REQUEST_ANTIPAST, payload["continuation"])
-            elif staging is not None:
+                return
+            if self._sync_peer is peer:
+                self._sync_peer = None
+            if staging is not None:
                 self._finalize_proof_ibd(staging)
         elif msg_type == MSG_REQUEST_IBD_CHAIN_INFO:
             sink = self.consensus.sink()
@@ -815,6 +825,8 @@ class Node:
         (not per message) so a chunked IBD doesn't churn threads."""
         from kaspa_tpu.pipeline import ConsensusPipeline
 
+        if not blocks:
+            return  # the empty last chunk of a sync: nothing to insert, nothing counted
         if target is self.consensus:
             pipe = self.pipeline  # plain IBD rides the steady-state pipeline
         else:
@@ -825,12 +837,19 @@ class Node:
                 cached = (target, ConsensusPipeline(target, workers=2))
                 self._ibd_pipeline = cached
             pipe = cached[1]
-        futures = [pipe.submit(b) for b in blocks]
-        for f in futures:
-            try:
-                f.result(timeout=600)
-            except RuleError:
-                pass  # invalid blocks within an IBD batch are skipped
+        rejected = 0
+        with trace.span("ibd.insert_batch", blocks=len(blocks)) as sp:
+            futures = [pipe.submit(b) for b in blocks]
+            for f in futures:
+                try:
+                    f.result(timeout=600)
+                except RuleError:
+                    rejected += 1  # invalid blocks within an IBD batch are skipped, and counted
+            sp.set(rejected=rejected)
+        _IBD_CHUNKS_RX.inc()
+        _IBD_BLOCKS_RX.inc(len(blocks))
+        if rejected:
+            _IBD_BLOCKS_REJECTED.inc(rejected)
 
     def _on_relay_tx(self, peer: Peer, tx) -> None:
         """Tx-relay intake with flood hygiene (flows/src/v7/txrelay/flow.rs).
@@ -968,16 +987,19 @@ class Node:
         peer's chain info, then either relay-style catch-up (peer's pruning
         point known locally) or a pruning-proof sync into a staging
         consensus."""
+        self._sync_peer = peer
         peer.send(MSG_REQUEST_IBD_CHAIN_INFO, {})
 
     def _on_chain_info(self, peer: Peer, info: dict) -> None:
         peer_pp = info["pruning_point"]
         sink = self.consensus.sink()
         our_work = self.consensus.storage.ghostdag.get_blue_work(sink)
-        if info["sink_blue_work"] <= our_work:
-            return  # nothing to gain from this peer
-        if self._ibd:
-            return  # one sync at a time; don't abandon an in-flight staging
+        if info["sink_blue_work"] <= our_work or self._ibd:
+            # nothing to gain from this peer, or one sync at a time (an
+            # in-flight staging is not abandoned): no sync follows this answer
+            if self._sync_peer is peer:
+                self._sync_peer = None
+            return
         if (
             self.consensus.reachability.has(peer_pp)
             and (

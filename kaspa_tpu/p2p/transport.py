@@ -28,6 +28,7 @@ from time import perf_counter_ns
 
 _SEND_QUEUE_LIMIT = 4096  # frames; overflow => drop the peer (slow consumer)
 
+from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY
 from kaspa_tpu.p2p import wire
 from kaspa_tpu.p2p.node import MIN_PROTOCOL_VERSION, MSG_VERSION, Node, ProtocolError
@@ -256,11 +257,19 @@ class WirePeer:
                 if act is not None and act.mode == "disconnect":
                     raise ConnectionError("injected disconnect")
                 # frame read and payload decode are split so only codec work
-                # is timed — the header/body reads block on the peer
-                meta, body, nbytes = self.codec.read_frame(self._read_exactly)
+                # is timed — the header/body reads block on the peer.  While
+                # this peer is the one the node syncs from, the read is a
+                # span: request out -> whole frame in
+                if getattr(self.node, "_sync_peer", None) is self:
+                    with trace.span("wait.p2p_frame"):
+                        meta, body, nbytes = self.codec.read_frame(self._read_exactly)
+                else:
+                    meta, body, nbytes = self.codec.read_frame(self._read_exactly)
                 t0 = perf_counter_ns()
                 try:
-                    msg_type, payload = self.codec.decode(meta, body)
+                    with trace.span("p2p.decode", bytes=nbytes) as sp:
+                        msg_type, payload = self.codec.decode(meta, body)
+                        sp.set(msg=msg_type)
                 except Exception:  # noqa: BLE001 - body didn't decode but the
                     # frame header did, so the stream is still in sync: score
                     # the peer and keep reading.  A repeat offender crosses
