@@ -309,3 +309,245 @@ def test_relay_out_of_order_parks_on_inflight_parent():
     assert parent_fut.result(30) in ("utxo_valid", "utxo_pending")
     assert node.consensus.storage.statuses.get(child.hash) == "utxo_valid"
     assert node.consensus.sink() == child.hash
+
+
+# ----------------------------------------------------------------------
+# when a virtual cycle starts (pipeline.py module docstring): once the cap is
+# reached, or once nothing is ready or inside a stage worker
+# ----------------------------------------------------------------------
+
+_TOY_DAGS = {
+    # one miner that sees its own block at once: a chain
+    "chain": ({"bps": 2, "delay_s": 1.0, "miners": 1}, {"tx_per_block": 2, "spoiled_blocks": 0, "pool_factor": 3}),
+    # simpa's network at toy size: one miner whose own blocks reach it after the
+    # delay too, about delay x bps = 4 blocks wide; two late siblings carry a failed spend
+    "wide": (
+        {"bps": 4, "delay_s": 1.0, "miners": 1, "own_blocks_delayed": True, "ghostdag_k": 55},
+        {"tx_per_block": 3, "spoiled_blocks": 2, "pool_factor": 8, "gap_stratum_blocks": 4},
+    ),
+}
+
+
+def _cycle_counters():
+    from kaspa_tpu.pipeline.pipeline import _VIRT_CYCLE_BLOCKS, _VIRT_CYCLES
+
+    return _VIRT_CYCLES.value, _VIRT_CYCLE_BLOCKS.value
+
+
+def _spy_cycles(consensus, on_cycle=lambda: None):
+    """Blocks absorbed by each cycle, counted where the virtual worker works:
+    one ``_update_tips`` a block, then one ``_resolve_virtual`` a cycle."""
+    sizes, n = [], [0]
+    update_tips, resolve = consensus._update_tips, consensus._resolve_virtual
+
+    def spy_tips(h):
+        n[0] += 1
+        return update_tips(h)
+
+    def spy_resolve():
+        sizes.append(n[0])
+        n[0] = 0
+        on_cycle()
+        return resolve()
+
+    consensus._update_tips, consensus._resolve_virtual = spy_tips, spy_resolve
+    return sizes
+
+
+def _settled(pipe, timeout=10.0):
+    """The ready-or-staging count once the stage workers are through (a future
+    resolves a few instructions before its task leaves the count)."""
+    import time
+
+    deadline = time.monotonic() + timeout
+    while pipe._staging and time.monotonic() < deadline:
+        time.sleep(0.001)
+    return pipe._staging
+
+
+@pytest.mark.parametrize("shape", ["chain", "wide"])
+def test_cycle_absorbs_what_is_ready(shape, monkeypatch):
+    """N >= 40 blocks queued while the commit lock is held: the cycles absorb
+    what is staged (more than the two blocks two stage workers hand over, never
+    more than the cap), and the state is the block-by-block replay's: sink,
+    ``utxo_commitment``, and every block's status on the sink's chain.  Off that
+    chain a batched resolve leaves ``utxo_pending`` what the replay, in which
+    every block was once a tip, qualified or disqualified (the reference's
+    virtual processor batches the same way); it never reaches another verdict."""
+    from benchmarks import harness
+    from kaspa_tpu.ops import dispatch as coalescing
+
+    cap = 16
+    monkeypatch.setenv("KASPA_TPU_VIRTUAL_BATCH_MAX", str(cap))
+    coalescing.configure(0)
+    network, traffic = _TOY_DAGS[shape]
+    workload = {"config": "toy", "mode": "catchup", "tx_shape": "fanout-then-1to1", "window_blocks": 24, "sig_samples": 0, **traffic}
+    dag = harness.build_dag(workload, {"name": "toy", "network": network, "pipeline": {"coalesce": 64, "stage_workers": 2}}, 36, lambda _m: None)
+    blocks = dag.blocks
+    assert len(blocks) >= 40 and len(dag.spoiled) == traffic["spoiled_blocks"]
+    if shape == "wide":
+        assert dag.facts["mean_window_parents"] >= 4
+
+    replay = Consensus(dag.params)
+    for b in blocks:
+        replay.validate_and_insert_block(b)
+
+    consensus = Consensus(dag.params)
+    pipe = ConsensusPipeline(consensus, workers=2)
+    sizes = _spy_cycles(consensus)
+    cycles0, blocks0 = _cycle_counters()
+    try:
+        with pipe._lock:  # nothing commits until every block is queued
+            futures = [pipe.submit(b) for b in blocks]
+        statuses = [f.result(timeout=300) for f in futures]
+    finally:
+        pipe.shutdown()
+    cycles1, blocks1 = _cycle_counters()
+    assert cycles1 - cycles0 == len(sizes) and blocks1 - blocks0 == sum(sizes) == len(blocks)
+    assert max(sizes) > 2 and max(sizes) <= cap, sizes
+    assert len(sizes) < len(blocks) / 2, sizes  # not one or two blocks a cycle
+    assert pipe._staging == 0
+
+    sink = consensus.sink()
+    assert sink == replay.sink() == dag.sinks[-1]
+    assert consensus.multisets[sink].finalize() == replay.multisets[sink].finalize()
+    assert consensus.get_virtual_daa_score() == replay.get_virtual_daa_score()
+    chain, cur = set(), sink
+    while cur != dag.params.genesis.hash:
+        chain.add(cur)
+        cur = consensus.storage.ghostdag.get_selected_parent(cur)
+    for b, said in zip(blocks, statuses):
+        got, want = consensus.storage.statuses.get(b.hash), replay.storage.statuses.get(b.hash)
+        if b.hash in chain:
+            assert got == want == "utxo_valid"
+            assert consensus.acceptance_data.get(b.hash) == replay.acceptance_data.get(b.hash)
+        else:
+            assert got == want or got == "utxo_pending", (got, want)
+        assert said == got or said == "utxo_pending"  # what the future said, before a later cycle qualified the block
+        assert (want == "disqualified") == (b.hash in dag.spoiled)
+
+
+def test_lone_block_cycle_starts_at_once():
+    """A lone block (every paced and relayed one) finds nothing else ready or
+    staging when it is handed over: its cycle starts with the count at zero
+    and absorbs that block alone."""
+    params, blocks, _ = _build_dag([("2", ["G"]), ("3", ["2"])])
+    consensus = Consensus(params)
+    pipe = ConsensusPipeline(consensus, workers=2)
+    counts = []
+    sizes = _spy_cycles(consensus, on_cycle=lambda: counts.append(pipe._staging))
+    cycles0, blocks0 = _cycle_counters()
+    try:
+        for blk in blocks:
+            assert pipe.submit(blk).result(timeout=60) == "utxo_valid"
+    finally:
+        pipe.shutdown()
+    assert sizes == [1, 1] and counts == [0, 0]
+    assert _cycle_counters() == (cycles0 + 2, blocks0 + 2)
+
+
+def test_every_stage_exit_leaves_the_count():
+    """A stage error, a duplicate (one absorbed by its group, one of a block
+    already stored) and a header-only task each leave the ready-or-staging
+    count at zero, and the next block resolves."""
+    params, blocks, _ = _build_dag([("2", ["G"]), ("3", ["2"]), ("4", ["3"]), ("5", ["4"]), ("6", ["5"])])
+    consensus = Consensus(params)
+    pipe = ConsensusPipeline(consensus, workers=2)
+    try:
+        with pytest.raises(Exception, match="missing parent"):
+            pipe.submit(blocks[2]).result(timeout=30)  # stage error: its parent was never seen
+        assert _settled(pipe) == 0
+        assert pipe.submit(blocks[0]).result(timeout=30) == "utxo_valid"
+
+        with pipe._lock:  # the second submission joins the first one's group
+            twice = [pipe.submit(blocks[1]), pipe.submit(blocks[1])]
+        # the group's second task is answered from the store as soon as the first has staged
+        assert twice[0].result(timeout=30) == "utxo_valid" and twice[1].result(timeout=30) in ("utxo_valid", "utxo_pending")
+        assert pipe.submit(blocks[1]).result(timeout=30) == "utxo_valid"  # already stored: no reprocessing
+        assert _settled(pipe) == 0
+        assert pipe.submit(blocks[2]).result(timeout=30) == "utxo_valid"
+
+        assert pipe.submit(blocks[3], header_only=True).result(timeout=30) == "header_only"
+        assert _settled(pipe) == 0
+        assert pipe.submit(blocks[3]).result(timeout=30) == "utxo_valid"
+        assert pipe.submit(blocks[4]).result(timeout=30) == "utxo_valid"
+        pipe.wait_for_idle(10)
+        assert _settled(pipe) == 0
+    finally:
+        pipe.shutdown()
+    assert consensus.sink() == blocks[4].hash
+
+
+def test_shutdown_wakes_a_waiting_virtual_worker():
+    """``shutdown()`` returns though the virtual worker is waiting for a stage
+    task that never ends (a leaked count), and what was handed over resolves."""
+    import time
+
+    params, blocks, _ = _build_dag([("2", ["G"])])
+    consensus = Consensus(params)
+    pipe = ConsensusPipeline(consensus, workers=2)
+    with pipe._idle_mu:
+        pipe._staging += 1  # a stage task that never leaves
+    fut = pipe.submit(blocks[0])
+    deadline = time.monotonic() + 10
+    while not len(pipe._virtual_q) and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert len(pipe._virtual_q) == 1 and pipe._staging == 1 and not fut.done()  # handed over; the cycle waits
+    t0 = time.monotonic()
+    pipe.shutdown()
+    assert time.monotonic() - t0 < 5
+    assert not pipe._virtual_worker_t.is_alive()
+    assert fut.result(timeout=1) == "utxo_valid"
+
+
+def test_staging_count_under_stress():
+    """More stage workers than cores, a shortened switch interval and four
+    submitters racing the same DAG, each in its own order: a lost update of
+    the ready-or-staging count would leave it off zero (a wedged or an early
+    cycle); every future resolves, the count ends at zero and the cycles
+    absorbed each block exactly once."""
+    import sys
+
+    rng = random.Random(36)
+    topo, levels, level, n = [], [], ["G"], 2
+    while n < 90:
+        width = rng.randint(1, 4)
+        names = [str(n + k) for k in range(width)]
+        topo += [(name, list(level)) for name in names]
+        levels.append(range(n - 2, n - 2 + width))
+        level, n = names, n + width
+    params, blocks, _ = _build_dag(topo)
+    consensus = Consensus(params)
+    pipe = ConsensusPipeline(consensus, workers=16)
+    sizes = _spy_cycles(consensus)
+    futures, mu = [], threading.Lock()
+
+    def submitter(seed):
+        # every thread its own order inside a level, no thread ahead of its own
+        # parents: a child finds them stored or in flight (then it parks)
+        order = random.Random(seed)
+        for lvl in levels:
+            for i in order.sample(lvl, len(lvl)):
+                f = pipe.submit(blocks[i])
+                with mu:
+                    futures.append(f)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=submitter, args=(s,), daemon=True) for s in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+        statuses = [f.result(timeout=120) for f in futures]
+        assert _settled(pipe) == 0
+    finally:
+        sys.setswitchinterval(interval)
+        pipe.shutdown()
+    assert len(statuses) == 4 * len(blocks) and set(statuses) <= {"utxo_valid", "utxo_pending"}
+    assert sum(sizes) == len(blocks) and max(sizes) <= pipe._virtual_batch_max
+    assert not pipe._virtual_worker_t.is_alive() and pipe._staging == 0
+    tips = [b.hash for b in blocks if b.hash not in {p for x in blocks for p in x.header.direct_parents()}]
+    assert consensus.sink() in tips
