@@ -55,6 +55,23 @@ from kaspa_tpu.consensus.utxo import UtxoDiff, UtxoView, apply_diff, unapply_dif
 from kaspa_tpu.crypto import merkle
 from kaspa_tpu.crypto.muhash import MuHash
 from kaspa_tpu.observability import flight, trace
+from kaspa_tpu.observability.core import REGISTRY
+
+# counted where the virtual stage does the work, whichever cycle or thread it
+# runs in: over pipeline_virtual_cycle_blocks they are candidates and collected
+# transactions a block
+_CHAIN_VERIFIED = REGISTRY.counter(
+    "virtual_chain_blocks_verified",
+    help="chain candidates passed through _verify_chain_block, from the cache or recomputed (= speculative_hits + speculative_misses where a verifier is attached)",
+)
+_COLLECTED_TXS = REGISTRY.counter(
+    "txscript_collected_txs",
+    help="non-coinbase transactions handed to _validate_transactions, one increment a merged block (= the txs of the txscript.collect spans)",
+)
+_COLLECTED_TXS_SYNC = REGISTRY.counter(
+    "txscript_sync_collected_txs",
+    help="of txscript_collected_txs, those collected with no shared checker: a blocking dispatch under the commit lock follows",
+)
 
 
 class RuleError(Exception):
@@ -1002,7 +1019,7 @@ class Consensus:
             # The virtual multiset is never read (only chain blocks commit to
             # a utxo_commitment), so skip its device product outright
             self._move_utxo_position(sink)
-            ctx = self._calculate_utxo_state(vgd, daa_window.daa_score, need_multiset=False)
+            ctx = self._calculate_utxo_state(vgd, daa_window.daa_score, need_multiset=False, cause="virtual")
             self.virtual_utxo_diff = ctx["mergeset_diff"]
             prev_state = self.virtual_state
             self.virtual_state = VirtualState(
@@ -1073,35 +1090,48 @@ class Consensus:
         if not chain:
             return True
         chain.reverse()
-        with trace.span("virtual.chain_verify", blocks=len(chain)):
+        with trace.span("virtual.chain_verify", blocks=len(chain)) as sp:
             # batch every cache-missing segment member's context into one
             # coalesced device dispatch before the serial verify loop —
             # k misses cost one script round-trip instead of k
             if self.speculative is not None and len(chain) > 1:
                 self.speculative.precompute_chain(chain)
+            served = {"cache": 0, "sync": 0}  # chain blocks by where their context came from
+            ok = True
             for c in chain:
-                if not self._verify_chain_block(c):
+                ok = self._verify_chain_block(c, served)
+                if not ok:
                     self.storage.statuses.set(c, StatusesStore.STATUS_DISQUALIFIED)
                     self.counters.inc_chain_disqualified()
-                    return False
-        return True
+                    break
+            sp.set(hits=served["cache"], misses=served["sync"], qualified=served["cache"] + served["sync"] - (not ok))
+        return ok
 
-    def _verify_chain_block(self, block: bytes) -> bool:
+    def _verify_chain_block(self, block: bytes, served: dict | None = None) -> bool:
         """verify_expected_utxo_state for one chain-candidate block.
 
         The expensive half — mergeset replay, script batch, muhash product
         (`_calculate_utxo_state`) — is served from the speculative
         precompute cache when a stage worker already ran it for this
         (block, selected_parent) position; the checks + commit half always
-        runs here, so hit and miss paths write identical state."""
+        runs here, so hit and miss paths write identical state.  ``served``
+        (the caller's tally) counts the block under where its context came
+        from: ``cache`` or ``sync``."""
         gd = self.storage.ghostdag.get(block)
         header = self.storage.headers.get(block)
         self._move_utxo_position(gd.selected_parent)
+        _CHAIN_VERIFIED.inc()
         entry = None
         if self.speculative is not None:
             entry = self.speculative.take(block, gd.selected_parent)
-        ctx = entry.ctx if entry is not None else self._calculate_utxo_state(gd, header.daa_score)
-        return self._check_and_commit_chain_block(block, gd, header, ctx)
+        source = "cache" if entry is not None else "sync"
+        if served is not None:
+            served[source] += 1
+        ctx = entry.ctx if entry is not None else self._calculate_utxo_state(gd, header.daa_score, cause="fallback")
+        with trace.span("virtual.chain_commit", source=source) as sp:
+            ok = self._check_and_commit_chain_block(block, gd, header, ctx)
+            sp.set(ok=ok)
+        return ok
 
     def _check_and_commit_chain_block(self, block: bytes, gd: GhostdagData, header, ctx: dict) -> bool:
         """The five verify_expected_utxo_state checks + the chain commit,
@@ -1245,9 +1275,19 @@ class Consensus:
         seed_multiset: MuHash | None = None,
         checker=None,
         token_ns=None,
+        cause: str = "virtual",
     ) -> dict:
         """utxo_validation.rs calculate_utxo_state relative to current position
         (must equal gd.selected_parent).
+
+        One ``virtual.mergeset_replay`` span a call, with the merged ``blocks``
+        (the selected parent included), the accepted ``txs`` (its coinbase
+        not), and who asked as ``cause``: ``stage`` (a stage worker's
+        speculation), ``segment`` (`precompute_chain`), ``fallback``
+        (`_verify_chain_block` with no cached context: the replay made again
+        under the commit lock, or the only one where no verifier is
+        attached), ``virtual`` (the virtual's own mergeset), ``build`` (a
+        block under construction); ``fallback`` says the same as a boolean.
 
         ``need_multiset=False`` skips the muhash device product entirely —
         the virtual resolve never reads it (only chain blocks commit to a
@@ -1261,77 +1301,79 @@ class Consensus:
         staged tx is treated as accepted, with the staged tokens returned
         under ``staged_tokens`` so the caller can discard the whole context
         if any check fails after the async dispatch resolves."""
-        speculative = checker is not None
-        if not speculative:
-            assert self.utxo_position == gd.selected_parent
-        if base is None:
-            base = self.utxo_set
-        mergeset_diff = UtxoDiff()
-        multiset = None
-        if need_multiset:
-            seed = seed_multiset if seed_multiset is not None else self.multisets[gd.selected_parent]
-            multiset = seed.clone()
-        accepted_tx_ids: list[bytes] = []
-        mergeset_rewards: dict[bytes, BlockRewardData] = {}
+        with trace.span("virtual.mergeset_replay", cause=cause, fallback=cause == "fallback") as sp:
+            speculative = checker is not None
+            if not speculative:
+                assert self.utxo_position == gd.selected_parent
+            if base is None:
+                base = self.utxo_set
+            mergeset_diff = UtxoDiff()
+            multiset = None
+            if need_multiset:
+                seed = seed_multiset if seed_multiset is not None else self.multisets[gd.selected_parent]
+                multiset = seed.clone()
+            accepted_tx_ids: list[bytes] = []
+            mergeset_rewards: dict[bytes, BlockRewardData] = {}
 
-        sp_txs = self.storage.block_transactions.get(gd.selected_parent)
-        coinbase = sp_txs[0]
-        coinbase_entries: list = []
-        mergeset_diff.add_transaction(coinbase, coinbase_entries, pov_daa_score)
-        accepted_tx_ids.append(coinbase.id())
-        # multiset updates accumulate across the whole mergeset and reduce in
-        # one batch below (the product is commutative) — this is what routes
-        # the muhash work through the device tree-product kernel
-        multiset_items: list = [(coinbase, coinbase_entries, pov_daa_score)]
-        # per-merged-block acceptance (KIP-21 lane activity source):
-        # (merged_block, coinbase payload, [accepted txs in block order])
-        mergeset_acceptance: list = []
-        staged_tokens: list = []
+            sp_txs = self.storage.block_transactions.get(gd.selected_parent)
+            coinbase = sp_txs[0]
+            coinbase_entries: list = []
+            mergeset_diff.add_transaction(coinbase, coinbase_entries, pov_daa_score)
+            accepted_tx_ids.append(coinbase.id())
+            # multiset updates accumulate across the whole mergeset and reduce in
+            # one batch below (the product is commutative) — this is what routes
+            # the muhash work through the device tree-product kernel
+            multiset_items: list = [(coinbase, coinbase_entries, pov_daa_score)]
+            # per-merged-block acceptance (KIP-21 lane activity source):
+            # (merged_block, coinbase payload, [accepted txs in block order])
+            mergeset_acceptance: list = []
+            staged_tokens: list = []
 
-        ordered = [(gd.selected_parent, sp_txs)] + [
-            (b, self.storage.block_transactions.get(b)) for b in gd.ascending_mergeset_without_selected_parent(self.storage.ghostdag)
-        ]
-        for i, (merged_block, txs) in enumerate(ordered):
-            composed = UtxoView(base, mergeset_diff)
-            is_selected_parent = i == 0
-            flags = FLAG_SKIP_SCRIPTS if is_selected_parent else FLAG_FULL
+            ordered = [(gd.selected_parent, sp_txs)] + [
+                (b, self.storage.block_transactions.get(b)) for b in gd.ascending_mergeset_without_selected_parent(self.storage.ghostdag)
+            ]
+            for i, (merged_block, txs) in enumerate(ordered):
+                composed = UtxoView(base, mergeset_diff)
+                is_selected_parent = i == 0
+                flags = FLAG_SKIP_SCRIPTS if is_selected_parent else FLAG_FULL
+                if speculative:
+                    # token_ns keeps tokens collision-free when several blocks
+                    # share one checker (the in-cycle chain precompute)
+                    staged = self._validate_transactions(
+                        txs, composed, pov_daa_score, flags,
+                        checker=checker,
+                        token_tag=("ms", i) if token_ns is None else ("ms", token_ns, i),
+                        position_anchor=gd.selected_parent,
+                    )
+                    staged_tokens.extend(t for t, _tx, _e, _f in staged)
+                    validated = [(tx, entries, fee) for _t, tx, entries, fee in staged]
+                else:
+                    validated = self._validate_transactions(txs, composed, pov_daa_score, flags)
+                block_fee = 0
+                accepted_here = [coinbase] if is_selected_parent else []
+                for tx, entries, fee in validated:
+                    mergeset_diff.add_transaction(tx, entries, pov_daa_score)
+                    multiset_items.append((tx, entries, pov_daa_score))
+                    accepted_tx_ids.append(tx.id())
+                    accepted_here.append(tx)
+                    block_fee += fee
+                cb_data = self.coinbase_manager.deserialize_coinbase_payload(txs[0].payload)
+                mergeset_rewards[merged_block] = BlockRewardData(cb_data.subsidy, block_fee, cb_data.miner_data.script_public_key)
+                mergeset_acceptance.append((merged_block, txs[0].payload, accepted_here))
+            if need_multiset and not speculative:
+                multiset.add_transactions_batch(multiset_items)
+
+            ctx = {
+                "mergeset_diff": mergeset_diff,
+                "multiset": multiset,
+                "accepted_tx_ids": accepted_tx_ids,
+                "mergeset_rewards": mergeset_rewards,
+                "mergeset_acceptance": mergeset_acceptance,
+            }
             if speculative:
-                # token_ns keeps tokens collision-free when several blocks
-                # share one checker (the in-cycle chain precompute)
-                staged = self._validate_transactions(
-                    txs, composed, pov_daa_score, flags,
-                    checker=checker,
-                    token_tag=("ms", i) if token_ns is None else ("ms", token_ns, i),
-                    position_anchor=gd.selected_parent,
-                )
-                staged_tokens.extend(t for t, _tx, _e, _f in staged)
-                validated = [(tx, entries, fee) for _t, tx, entries, fee in staged]
-            else:
-                validated = self._validate_transactions(txs, composed, pov_daa_score, flags)
-            block_fee = 0
-            accepted_here = [coinbase] if is_selected_parent else []
-            for tx, entries, fee in validated:
-                mergeset_diff.add_transaction(tx, entries, pov_daa_score)
-                multiset_items.append((tx, entries, pov_daa_score))
-                accepted_tx_ids.append(tx.id())
-                accepted_here.append(tx)
-                block_fee += fee
-            cb_data = self.coinbase_manager.deserialize_coinbase_payload(txs[0].payload)
-            mergeset_rewards[merged_block] = BlockRewardData(cb_data.subsidy, block_fee, cb_data.miner_data.script_public_key)
-            mergeset_acceptance.append((merged_block, txs[0].payload, accepted_here))
-        if need_multiset and not speculative:
-            multiset.add_transactions_batch(multiset_items)
-
-        ctx = {
-            "mergeset_diff": mergeset_diff,
-            "multiset": multiset,
-            "accepted_tx_ids": accepted_tx_ids,
-            "mergeset_rewards": mergeset_rewards,
-            "mergeset_acceptance": mergeset_acceptance,
-        }
-        if speculative:
-            ctx["multiset_items"] = multiset_items
-            ctx["staged_tokens"] = staged_tokens
+                ctx["multiset_items"] = multiset_items
+                ctx["staged_tokens"] = staged_tokens
+            sp.set(blocks=len(ordered), txs=len(accepted_tx_ids) - 1)
         return ctx
 
     def _validate_transactions(
@@ -1361,9 +1403,13 @@ class Consensus:
                 self.params.finality_depth,
             )
         staged = []
+        collected = len(txs) - 1  # the coinbase is not collected
+        _COLLECTED_TXS.inc(collected)
+        if not shared:
+            _COLLECTED_TXS_SYNC.inc(collected)
         # UTXO population, sighash and job staging of one merged block: what
         # the virtual stage does for its scripts before the round trip
-        with trace.span("txscript.collect", txs=len(txs) - 1, speculative=shared) as sp:
+        with trace.span("txscript.collect", txs=collected, speculative=shared) as sp:
             jobs0, multisig0, memo0 = checker.queued_jobs(), checker.queued_multisig_inputs(), checker.memo_hits()
             for i, tx in enumerate(txs):
                 if i == 0:
@@ -1431,7 +1477,7 @@ class Consensus:
         bits = self.window_manager.calculate_difficulty_bits(gd, daa_window)
         pmt, _ = self.window_manager.calc_past_median_time(gd)
         self._move_utxo_position(gd.selected_parent)
-        ctx = self._calculate_utxo_state(gd, daa_window.daa_score)
+        ctx = self._calculate_utxo_state(gd, daa_window.daa_score, cause="build")
         if tx_selector is not None:
             assert txs is None
             txs = tx_selector(UtxoView(self.utxo_set, ctx["mergeset_diff"]), daa_window.daa_score)

@@ -53,6 +53,10 @@ class ProcessingCounters:
     def inc_chain_disqualified(self, n=1):
         self._s.chain_disqualified_counts += n
 
+    def chain_verified(self) -> int:
+        """Chain candidates verified so far: those committed and those disqualified."""
+        return self._s.chain_block_counts + self._s.chain_disqualified_counts
+
     def snapshot(self) -> ProcessingCountersSnapshot:
         return ProcessingCountersSnapshot(**asdict(self._s))
 

@@ -264,13 +264,12 @@ class ConsensusPipeline:
                 # GIL-releasing precompute outside the commit lock: header
                 # hash + merkle leaves hash concurrently across workers
                 blk = task.block
-                with trace.span("pipeline.precompute"):
-                    _ = blk.hash
-                    if not task.header_only:
-                        for tx in blk.transactions:
-                            tx.id()
+                _ = blk.hash
+                if not task.header_only:
+                    for tx in blk.transactions:
+                        tx.id()
                 t_lock = perf_counter_ns()
-                with self._lock:
+                with self._lock.locked_for("stage"):
                     _LOCK_WAIT.observe((perf_counter_ns() - t_lock) * 1e-9)
                     with trace.span("pipeline.commit"):
                         existing = consensus.storage.statuses.get(blk.hash)
@@ -344,7 +343,7 @@ class ConsensusPipeline:
                 _Q_WAIT.observe("virtual", (now - task.enqueue_ns) * 1e-9)
                 trace.record_span("wait.virtual", task.ctx, task.enqueue_ns, now)
             t_lock = perf_counter_ns()
-            with self._lock:
+            with self._lock.locked_for("virtual", parent=batch[0].ctx):
                 _LOCK_WAIT.observe((perf_counter_ns() - t_lock) * 1e-9)
                 try:
                     # the TLS span parents on the first task's trace: muhash /
@@ -352,7 +351,8 @@ class ConsensusPipeline:
                     # task in the batch gets a synthetic same-interval span so
                     # its trace still owns the shared virtual-cycle time
                     t_v0 = perf_counter_ns()
-                    with trace.span("pipeline.virtual", parent=batch[0].ctx, batch=len(batch)):
+                    with trace.span("pipeline.virtual", parent=batch[0].ctx, batch=len(batch)) as sp:
+                        verified0 = consensus.counters.chain_verified()
                         for task in batch:
                             consensus.notification_root.notify_block_added(task.block, task.ctx)
                             consensus._update_tips(task.block.hash)
@@ -361,6 +361,8 @@ class ConsensusPipeline:
                         # graftlint: allow(blocking-under-lock) -- the virtual cycle's device work runs under the pipeline lock by design: the pipeline thread is the sole consumer and the watchdog monitors progress
                         consensus._resolve_virtual()
                         consensus.storage.flush()
+                        # chain blocks the cycle verified (committed or disqualified)
+                        sp.set(candidates=consensus.counters.chain_verified() - verified0)
                     t_v1 = perf_counter_ns()
                     for task in batch[1:]:
                         trace.record_span(

@@ -75,6 +75,14 @@ _INVALIDATIONS = REGISTRY.counter_family(
 _PRECOMPUTES = REGISTRY.counter(
     "speculative_precomputes", help="speculative chain-state contexts computed by stage workers"
 )
+_CHAIN_COMPUTED = REGISTRY.counter(
+    "speculative_chain_blocks_computed",
+    help="chain-state contexts precompute_chain computed inside a cycle (one increment a segment)",
+)
+_CHAIN_DISCARDED = REGISTRY.counter(
+    "speculative_chain_blocks_discarded",
+    help="of speculative_chain_blocks_computed, contexts not published: the block whose staged spend failed and every block behind it, computed again synchronously",
+)
 _INELIGIBLE = REGISTRY.counter_family(
     "speculative_ineligible", "reason",
     help="blocks that skipped speculation at begin time (position unreachable, toccata, dup)",
@@ -151,66 +159,65 @@ class SpeculativeVerifier:
         """Collect phase, under the commit lock: frozen-state reads, the
         optimistic mergeset replay, async script submission."""
         c = self.consensus
-        with trace.span("speculative.begin"):
-            with self._commit_lock:
-                if c.storage.statuses.get(block_hash) != StatusesStore.STATUS_UTXO_PENDING_VERIFICATION:
-                    _INELIGIBLE.inc("status")
+        with self._commit_lock.locked_for("speculate"):
+            if c.storage.statuses.get(block_hash) != StatusesStore.STATUS_UTXO_PENDING_VERIFICATION:
+                _INELIGIBLE.inc("status")
+                return None
+            gd = c.storage.ghostdag.get(block_hash)
+            sp = gd.selected_parent
+            header = c.storage.headers.get(block_hash)
+            if c.params.toccata_active(header.daa_score):
+                _INELIGIBLE.inc("toccata")
+                return None
+            with self._mu:
+                if (block_hash, sp) in self._entries:
+                    _INELIGIBLE.inc("duplicate")
                     return None
-                gd = c.storage.ghostdag.get(block_hash)
-                sp = gd.selected_parent
-                header = c.storage.headers.get(block_hash)
-                if c.params.toccata_active(header.daa_score):
-                    _INELIGIBLE.inc("toccata")
-                    return None
-                with self._mu:
-                    if (block_hash, sp) in self._entries:
-                        _INELIGIBLE.inc("duplicate")
+                parent_entry = None if sp == c.utxo_position else self._by_block.get(sp)
+            if sp == c.utxo_position:
+                base = c.utxo_set
+                seed = c.multisets[sp]
+                base_position = sp
+            elif parent_entry is not None:
+                # the chain of views bottoms out on the live utxo_set; the
+                # composed reads stay correct while the live position sits
+                # anywhere ON that chain (base, or a committed prefix block
+                # — applying an entry's own diff to the base leaves reads
+                # through its view unchanged), and diverge the moment it
+                # reorgs onto a different branch
+                depth, cur, on_chain = 1, parent_entry, {parent_entry.block}
+                while cur.parent_entry is not None:
+                    cur = cur.parent_entry
+                    on_chain.add(cur.block)
+                    depth += 1
+                    if depth > self.MAX_CHAIN_DEPTH:
+                        _INELIGIBLE.inc("depth")
                         return None
-                    parent_entry = None if sp == c.utxo_position else self._by_block.get(sp)
-                if sp == c.utxo_position:
-                    base = c.utxo_set
-                    seed = c.multisets[sp]
-                    base_position = sp
-                elif parent_entry is not None:
-                    # the chain of views bottoms out on the live utxo_set; the
-                    # composed reads stay correct while the live position sits
-                    # anywhere ON that chain (base, or a committed prefix block
-                    # — applying an entry's own diff to the base leaves reads
-                    # through its view unchanged), and diverge the moment it
-                    # reorgs onto a different branch
-                    depth, cur, on_chain = 1, parent_entry, {parent_entry.block}
-                    while cur.parent_entry is not None:
-                        cur = cur.parent_entry
-                        on_chain.add(cur.block)
-                        depth += 1
-                        if depth > self.MAX_CHAIN_DEPTH:
-                            _INELIGIBLE.inc("depth")
-                            return None
-                    on_chain.add(cur.base_position)
-                    if c.utxo_position not in on_chain:
-                        _INELIGIBLE.inc("position")
-                        return None
-                    base = parent_entry.view
-                    seed = parent_entry.ctx["multiset"]
-                    base_position = cur.base_position
-                else:
+                on_chain.add(cur.base_position)
+                if c.utxo_position not in on_chain:
                     _INELIGIBLE.inc("position")
                     return None
+                base = parent_entry.view
+                seed = parent_entry.ctx["multiset"]
+                base_position = cur.base_position
+            else:
+                _INELIGIBLE.inc("position")
+                return None
 
-                checker = c.transaction_validator.new_checker()
-                # graftlint: allow(blocking-under-lock) -- unreachable sync branch: checker is supplied, so _validate_transactions inside never takes its synchronous dispatch() path here
-                ctx = c._calculate_utxo_state(
-                    gd, header.daa_score, base=base, seed_multiset=seed, checker=checker
-                )
-                # check-5 staging (own txs over the block's own view): same
-                # checker, so one async submission covers the whole block
-                txs = c.storage.block_transactions.get(block_hash)
-                own_view = UtxoView(base, ctx["mergeset_diff"])
-                own_staged = c._validate_transactions(  # graftlint: allow(blocking-under-lock) -- unreachable sync branch: _begin passes checker=dispatch_async, _validate_transactions only calls dispatch() when no async checker is supplied
-                    txs, own_view, header.daa_score, FLAG_FULL,
-                    checker=checker, token_tag=("own",), position_anchor=sp,
-                )
-                handle = checker.dispatch_async()
+            checker = c.transaction_validator.new_checker()
+            # graftlint: allow(blocking-under-lock) -- unreachable sync branch: checker is supplied, so _validate_transactions inside never takes its synchronous dispatch() path here
+            ctx = c._calculate_utxo_state(
+                gd, header.daa_score, base=base, seed_multiset=seed, checker=checker, cause="stage"
+            )
+            # check-5 staging (own txs over the block's own view): same
+            # checker, so one async submission covers the whole block
+            txs = c.storage.block_transactions.get(block_hash)
+            own_view = UtxoView(base, ctx["mergeset_diff"])
+            own_staged = c._validate_transactions(  # graftlint: allow(blocking-under-lock) -- unreachable sync branch: _begin passes checker=dispatch_async, _validate_transactions only calls dispatch() when no async checker is supplied
+                txs, own_view, header.daa_score, FLAG_FULL,
+                checker=checker, token_tag=("own",), position_anchor=sp,
+            )
+            handle = checker.dispatch_async()
         return _Pending(
             block=block_hash, selected_parent=sp, gd=gd, ctx=ctx, base=base,
             parent_entry=parent_entry, base_position=base_position,
@@ -293,6 +300,7 @@ class SpeculativeVerifier:
         fall back to the synchronous path (which reaches the honest
         disqualify verdict)."""
         c = self.consensus
+        pendings, published = [], 0
         try:
             gd0 = c.storage.ghostdag.get(chain[0])
             # identical to what _verify_chain_block(chain[0]) does first;
@@ -302,8 +310,8 @@ class SpeculativeVerifier:
             prev_block = gd0.selected_parent
             prev_view = None
             prev_seed = None
-            pendings = []
-            with trace.span("speculative.chain_precompute", blocks=len(chain)):
+            reused = 0  # stage-time entries the segment chained on
+            with trace.span("speculative.chain_precompute", blocks=len(chain)) as span:
                 for b in chain:
                     gd = c.storage.ghostdag.get(b)
                     sp = gd.selected_parent
@@ -319,12 +327,13 @@ class SpeculativeVerifier:
                     if existing is not None:
                         # stage-time hit: chain the rest of the segment on it
                         prev_block, prev_view, prev_seed = b, existing.view, existing.ctx["multiset"]
+                        reused += 1
                         continue
                     base = prev_view if prev_view is not None else c.utxo_set
                     seed = prev_seed if prev_seed is not None else c.multisets[sp]
                     ctx = c._calculate_utxo_state(
                         gd, header.daa_score, base=base, seed_multiset=seed,
-                        checker=checker, token_ns=b,
+                        checker=checker, token_ns=b, cause="segment",
                     )
                     # muhash finalized eagerly: the next block's seed must
                     # already contain this mergeset
@@ -337,29 +346,38 @@ class SpeculativeVerifier:
                     )
                     pendings.append((b, sp, ctx, view, txs, own_staged))
                     prev_block, prev_view, prev_seed = b, view, ctx["multiset"]
+                span.set(computed=len(pendings), reused=reused, jobs=checker.queued_jobs(), published=0)
                 if not pendings:
                     return
                 results = checker.dispatch_async().result()
-            for b, sp, ctx, view, txs, own_staged in pendings:
-                failed = (
-                    any(results.get(t) is not None for t in ctx["staged_tokens"])
-                    or any(results.get(t) is not None for t, _tx, _e, _f in own_staged)
-                    or len(own_staged) < len(txs) - 1
-                )
-                if failed:
-                    _INVALIDATIONS.inc("script")
-                    break
-                ctx.pop("staged_tokens", None)
-                # parent_entry=None / base_position=sp is the conservative
-                # encoding: later chaining onto this entry requires the live
-                # position to be the entry's block or its selected parent —
-                # both idempotent read positions for its view stack
-                self._publish(_Entry(
-                    block=b, selected_parent=sp, ctx=ctx, view=view,
-                    parent_entry=None, base_position=sp,
-                ))
+                for b, sp, ctx, view, txs, own_staged in pendings:
+                    failed = (
+                        any(results.get(t) is not None for t in ctx["staged_tokens"])
+                        or any(results.get(t) is not None for t, _tx, _e, _f in own_staged)
+                        or len(own_staged) < len(txs) - 1
+                    )
+                    if failed:
+                        _INVALIDATIONS.inc("script")
+                        break
+                    ctx.pop("staged_tokens", None)
+                    # parent_entry=None / base_position=sp is the conservative
+                    # encoding: later chaining onto this entry requires the live
+                    # position to be the entry's block or its selected parent —
+                    # both idempotent read positions for its view stack
+                    self._publish(_Entry(
+                        block=b, selected_parent=sp, ctx=ctx, view=view,
+                        parent_entry=None, base_position=sp,
+                    ))
+                    published += 1
+                span.set(published=published)
         except Exception:  # noqa: BLE001 - precompute is an optimization only
             _INVALIDATIONS.inc("error")
+        finally:
+            # what was computed and not published is computed again by the
+            # synchronous path: the failed block, those behind it, a segment
+            # that ended in an error
+            _CHAIN_COMPUTED.inc(len(pendings))
+            _CHAIN_DISCARDED.inc(len(pendings) - published)
 
     # ------------------------------------------------------------------
     # consumer side (virtual worker, inside _verify_chain_block)

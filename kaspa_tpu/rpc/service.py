@@ -339,6 +339,10 @@ class RpcCoreService:
             # the script-verdict memo over it: transactions asked, and those not collected again
             "tx_memo_hits": memo.hits,
             "tx_memo_lookups": memo.hits + memo.misses,
+            # what the memo is asked from: transactions handed to script collection
+            # (process-wide), and those of them collected for a blocking dispatch
+            "collected_txs": obs["counters"]["txscript_collected_txs"],
+            "collected_txs_sync": obs["counters"]["txscript_sync_collected_txs"],
             "process_counters": asdict(self.consensus.counters.snapshot()),
             "process_metrics": asdict(self.perf_monitor.sample()),
             # per-lock acquisition/hold aggregates when KASPA_TPU_LOCK_DEBUG

@@ -221,11 +221,13 @@ class LockCtx:
         """A Condition bound to this lock; use inside ``with ctx:``."""
         return threading.Condition(self._lock)
 
-    def locked_for(self, who: str) -> "_LockedFor":
+    def locked_for(self, who: str, parent=None) -> "_LockedFor":
         """``with lock.locked_for(who):`` is ``with lock:`` whose wait for
         the lock is a ``wait.<name>_lock`` span carrying ``who`` (a block
-        behind an admission wave, a wave behind a block)."""
-        return _LockedFor(self, who)
+        behind an admission wave, a wave behind a block).  ``parent`` (a
+        TraceContext) is the span's parent where the thread has no span
+        open, as ``trace.span`` takes it."""
+        return _LockedFor(self, who, parent)
 
     def __enter__(self):
         tracked = _LOCK_DEBUG
@@ -265,18 +267,19 @@ class _LockedFor:
     """What ``LockCtx.locked_for`` hands back: the lock's own enter and exit,
     with the wait for the lock timed as a span."""
 
-    __slots__ = ("_ctx", "_who")
+    __slots__ = ("_ctx", "_who", "_parent")
 
-    def __init__(self, ctx: LockCtx, who: str):
+    def __init__(self, ctx: LockCtx, who: str, parent=None):
         self._ctx = ctx
         self._who = who
+        self._parent = parent
 
     def __enter__(self) -> LockCtx:
         global _spans
         if _spans is None:
             from kaspa_tpu.observability import trace as _spans
         ctx = self._ctx
-        with _spans.span(ctx._wait_span, who=self._who):
+        with _spans.span(ctx._wait_span, parent=self._parent, who=self._who):
             ctx.__enter__()
         return ctx
 

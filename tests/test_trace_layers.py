@@ -495,3 +495,241 @@ def test_tree_product_lowers_under_its_named_scope():
     x = jax.ShapeDtypeStruct((2, muhash_ops.F.W), np.int32)
     text = muhash_ops._tree_product.lower(x, levels=1).as_text(debug_info=True)
     assert "muhash_tree_product" in text
+
+
+# --- PR 37: a multi-block virtual cycle, measured from inside -----------------------
+
+
+def _replay_in_one_cycle(dag, traced: bool):
+    """Every block of ``dag`` queued while the commit lock is held, so that one
+    cycle absorbs them all; with capture on, or with tracing disabled.  Beside
+    the consensus and the statuses: the spans, the counters' movement, and for
+    each ``precompute_chain`` call the blocks it computed and those it published."""
+    from kaspa_tpu.consensus.consensus import Consensus
+    from kaspa_tpu.pipeline.pipeline import ConsensusPipeline
+
+    consensus = Consensus(dag.params)
+    pipe = ConsensusPipeline(consensus, workers=2)
+    verifier, segments = consensus.speculative, []
+    replay, publish, precompute = consensus._calculate_utxo_state, verifier._publish, verifier.precompute_chain
+
+    def spy_replay(*args, **kwargs):
+        if kwargs.get("cause") == "segment":
+            segments[-1][0].append(kwargs["token_ns"])
+        return replay(*args, **kwargs)
+
+    def spy_publish(entry):
+        if threading.current_thread().name == "kaspa-virtual":
+            segments[-1][1].append(entry.block)
+        return publish(entry)
+
+    def spy_precompute(chain):
+        segments.append(([], []))
+        return precompute(chain)
+
+    consensus._calculate_utxo_state, verifier._publish, verifier.precompute_chain = spy_replay, spy_publish, spy_precompute
+    coalesce.configure(64)
+    if traced:
+        trace.set_capture(1 << 16)
+        trace.drain()
+    else:
+        trace.disable()
+    before = _counters()
+    try:
+        with pipe._lock:  # nothing commits until every block is queued
+            futures = [pipe.submit(b) for b in dag.blocks]
+        statuses = [f.result(timeout=300) for f in futures]
+        after = _counters()
+        spans = trace.drain()
+    finally:
+        pipe.shutdown()
+        trace.set_capture(0)
+        trace.enable()
+        coalesce.configure(0)
+    return consensus, statuses, spans, lambda name: _moved(after, before, name), segments
+
+
+@pytest.fixture(scope="module")
+def cycle():
+    """A toy DAG of simpa's shape (about four blocks wide, two late siblings
+    with a failed spend, 64 blocks) resolved in one virtual cycle."""
+    from benchmarks import harness
+
+    network = {"bps": 4, "delay_s": 1.0, "miners": 1, "own_blocks_delayed": True, "ghostdag_k": 55}
+    workload = {"config": "toy", "mode": "catchup", "tx_shape": "fanout-then-1to1", "window_blocks": 24, "sig_samples": 0,
+                "tx_per_block": 3, "spoiled_blocks": 2, "pool_factor": 8, "gap_stratum_blocks": 4}
+    assert not flight.enabled()
+    coalesce.configure(0)  # the build is the in-order run
+    dag = harness.build_dag(workload, {"name": "toy", "network": network, "pipeline": {"coalesce": 64, "stage_workers": 2}}, 36, lambda _m: None)
+    assert len(dag.blocks) <= 64 and len(dag.spoiled) == 2
+    return dag, _replay_in_one_cycle(dag, traced=True)
+
+
+def test_one_cycle_absorbed_every_block_and_counted_its_candidates(cycle):
+    dag, (_, _, spans, moved, _) = cycle
+    assert moved("pipeline_virtual_cycles") == 1 and moved("pipeline_virtual_cycle_blocks") == len(dag.blocks)
+    (virtual,) = [s for s in _named(spans, "pipeline.virtual") if not s["attrs"].get("shared")]
+    assert virtual["attrs"]["batch"] == len(dag.blocks)
+    assert 0 < virtual["attrs"]["candidates"] == moved("virtual_chain_blocks_verified") < len(dag.blocks)
+
+
+def test_chain_verifications_are_hits_plus_misses(cycle):
+    _, (_, _, spans, moved, _) = cycle
+    hits, misses = moved("speculative_hits"), moved("speculative_misses")
+    assert hits > 0 and misses > 0 and hits + misses == moved("virtual_chain_blocks_verified")
+    verifies = _named(spans, "virtual.chain_verify")
+    assert sum(v["attrs"]["hits"] for v in verifies) == hits and sum(v["attrs"]["misses"] for v in verifies) == misses
+    for v in verifies:  # nothing is disqualified on a tip's chain here: the failed spends sit in merged siblings
+        assert v["attrs"]["qualified"] == v["attrs"]["hits"] + v["attrs"]["misses"] == v["attrs"]["blocks"]
+
+
+def test_one_chain_commit_span_a_verified_block_with_its_source(cycle):
+    _, (_, _, spans, moved, _) = cycle
+    commits = _named(spans, "virtual.chain_commit")
+    assert len(commits) == moved("virtual_chain_blocks_verified")
+    by_source = collections.Counter(c["attrs"]["source"] for c in commits)
+    assert by_source == {"cache": moved("speculative_hits"), "sync": moved("speculative_misses")}
+    assert all(c["attrs"]["ok"] is True for c in commits)
+    verifies = _named(spans, "virtual.chain_verify")
+    assert all(any(_inside(c, v) for v in verifies) for c in commits)
+
+
+def test_one_mergeset_replay_span_a_call_with_its_cause(cycle):
+    _, (_, _, spans, moved, segments) = cycle
+    replays = _named(spans, "virtual.mergeset_replay")
+    by_cause = collections.Counter(r["attrs"]["cause"] for r in replays)
+    assert set(by_cause) == {"stage", "segment", "fallback", "virtual"}
+    assert by_cause["fallback"] == moved("speculative_misses")  # a miss is a replay made again
+    assert by_cause["segment"] == sum(len(computed) for computed, _ in segments) == moved("speculative_chain_blocks_computed")
+    assert by_cause["virtual"] == 1  # the virtual's own mergeset, once a cycle
+    for r in replays:
+        assert r["attrs"]["fallback"] is (r["attrs"]["cause"] == "fallback")
+        assert r["attrs"]["blocks"] >= 1 and r["attrs"]["txs"] >= 0
+    # where each is opened: the stage workers' under their own span, a segment's under precompute_chain's
+    outer = {"stage": "speculative.precompute", "segment": "speculative.chain_precompute", "fallback": "virtual.chain_verify", "virtual": "virtual.commit"}
+    for r in replays:
+        assert outer[r["attrs"]["cause"]] in r["path"].split("/"), r["path"]
+
+
+def test_a_segment_publishes_a_prefix_and_discards_from_the_failed_block_on(cycle):
+    dag, (consensus, _, spans, moved, segments) = cycle
+    computed, discarded = moved("speculative_chain_blocks_computed"), moved("speculative_chain_blocks_discarded")
+    published = sum(len(p) for _, p in segments)
+    assert computed == published + discarded and 0 < discarded < computed
+    precomputes = _named(spans, "speculative.chain_precompute")
+    assert len(precomputes) == len(segments)
+    for span, (seg_computed, seg_published) in zip(precomputes, segments):
+        attrs = span["attrs"]
+        assert (attrs["computed"], attrs["published"]) == (len(seg_computed), len(seg_published))
+        assert attrs["computed"] + attrs["reused"] <= attrs["blocks"] and attrs["jobs"] >= 0
+        assert seg_published == seg_computed[: len(seg_published)]  # a prefix, in chain order
+        if len(seg_published) < len(seg_computed):
+            failed = seg_computed[len(seg_published)]  # the first discarded: its mergeset holds a failed spend
+            assert set(consensus.storage.ghostdag.get(failed).unordered_mergeset()) & set(dag.spoiled)
+    assert moved("speculative_invalidations").get("script", 0) == sum(len(p) < len(c) for c, p in segments)
+    # what was discarded was computed again: every discarded block is a miss
+    assert discarded <= moved("speculative_misses")
+
+
+def test_collected_transactions_are_the_collect_spans(cycle):
+    _, (_, _, spans, moved, _) = cycle
+    collects = _named(spans, "txscript.collect")
+    assert moved("txscript_collected_txs") == sum(c["attrs"]["txs"] for c in collects) > 0
+    assert moved("txscript_sync_collected_txs") == sum(c["attrs"]["txs"] for c in collects if not c["attrs"]["speculative"]) > 0
+    # the memo is asked in collect_tx only: a selected parent's transactions and one with a missing input never reach it
+    assert moved("txscript_collected_txs") >= moved("txscript_tx_memo_lookups") > 0
+
+
+def test_commit_lock_wait_says_who_waited(cycle):
+    dag, (_, _, spans, _, _) = cycle
+    waits = _named(spans, "wait.consensus-commit_lock")
+    by_who = collections.Counter(w["attrs"]["who"] for w in waits)
+    assert by_who == {"stage": len(dag.blocks), "speculate": len(dag.blocks), "virtual": 1}
+    hashes = {b.hash.hex() for b in dag.blocks}
+    assert all(w["trace"] in hashes for w in waits)
+    # the stage workers queued behind the test's hold of the lock: their wait is time, not a stamp
+    assert max(w["dur_us"] for w in waits if w["attrs"]["who"] == "stage") > 0
+
+
+def test_removed_spans_are_gone(cycle):
+    names = {s["name"] for s in cycle[1][2]}
+    assert "pipeline.precompute" not in names and "speculative.begin" not in names
+    assert {"pipeline.stage", "speculative.precompute", "speculative.wait"} <= names
+
+
+def test_tracing_disabled_leaves_the_same_state(cycle):
+    dag, (traced, statuses, _, _, _) = cycle
+    plain, plain_statuses, spans, moved, _ = _replay_in_one_cycle(dag, traced=False)
+    assert spans == [] and plain_statuses == statuses
+    assert moved("speculative_hits") + moved("speculative_misses") == moved("virtual_chain_blocks_verified") > 0  # counters need no tracer
+    sink = traced.sink()
+    assert plain.sink() == sink == dag.sinks[-1]
+    assert plain.multisets[sink].finalize() == traced.multisets[sink].finalize()
+    assert plain.get_virtual_daa_score() == traced.get_virtual_daa_score()
+    assert plain.virtual_state.parents == traced.virtual_state.parents
+    assert plain.virtual_state.accepted_tx_ids == traced.virtual_state.accepted_tx_ids
+    for b in dag.blocks:
+        assert plain.storage.statuses.get(b.hash) == traced.storage.statuses.get(b.hash)
+        assert plain.acceptance_data.get(b.hash) == traced.acceptance_data.get(b.hash)
+
+
+def _reader_span(name, start_us, end_us, **attrs):
+    return {"name": name, "start_ns": start_us * 1000, "end_ns": end_us * 1000, "dur_us": float(end_us - start_us), "attrs": attrs}
+
+
+@pytest.mark.parametrize(
+    "spans, expected",
+    [
+        # the longest of the named spans, in the metric's unit; the shared copy of a cycle and other names left out
+        ([_reader_span("cycle", 0, 12_000), _reader_span("cycle", 20_000, 27_500), _reader_span("cycle", 0, 90_000, shared=True),
+          _reader_span("other", 0, 10**6)], 12.0),
+        ([_reader_span("cycle", 5, 10)], 0.005),
+        ([_reader_span("cycle", 0, 90_000, shared=True)], None),  # every one excluded: nothing to read
+        ([_reader_span("other", 0, 10)], None),  # the parent's program has no such span
+        ([], None),
+    ],
+)
+def test_span_max_reads_the_longest_of_the_named_spans(spans, expected):
+    from benchmarks.readers import span_max
+
+    source = {"reader": "span_max", "span": "cycle", "exclude_attrs": {"shared": True}, "unit_scale": 0.001}
+    got = span_max.read(source, {"spans": spans, "window": {"blocks": 3}})
+    assert got == expected if expected is None else abs(got - expected) < 1e-12
+
+
+PR37_METRICS = [
+    "chain_candidates_per_block", "chain_verify_fallback_pct", "chain_precompute_discarded_pct", "script_collected_txs_per_block",
+    "script_collected_sync_pct", "chain_recompute_ms_per_block.catchup", "mergeset_replay_uncovered_ms_per_block.catchup",
+    "chain_commit_ms_per_block.catchup", "virtual_sink_search_uncovered_ms_per_block.catchup", "virtual_cycle_max_ms.catchup",
+    "commit_lock_wait_ms_per_block.catchup", "verify_calls_per_block",
+]
+
+
+@pytest.mark.parametrize("metric", PR37_METRICS)
+def test_each_new_metric_reads_the_captured_cycle_and_nothing_from_a_bare_program(metric, cycle):
+    """``benchmarks/tests/test_benchmark_files.py``'s checks for the twelve new
+    files, and each metric read through its reader from the captured cycle; from
+    a window with neither the spans nor the counters it reads nothing and does
+    not raise (what the parent commit gives)."""
+    import importlib
+    import os
+
+    from benchmarks import harness
+
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    spec = harness.load_json(os.path.join(harness.HERE, "metrics", f"{metric}.json"))
+    assert spec["name"] == metric and spec["bench_source"] == entry["source"] and spec["note"]
+    assert all(spec[k] == entry[k] for k in ("layer", "unit", "better", "moves"))
+    assert entry["moves"] == "catchup_blocks_per_s" and len(entry["workloads"]) == 4 and all("catchup" in c for c in entry["workloads"])
+    assert entry["layer"] in {m["layer"] for m in bench["per_layer"] if m["name"] not in PR37_METRICS}  # a layer the benchmark already names
+    reader = importlib.import_module(f"benchmarks.readers.{spec['source']['reader']}")
+    dag, (_, _, spans, moved, _) = cycle
+    names = ("virtual_chain_blocks_verified", "pipeline_virtual_cycle_blocks", "speculative_misses", "speculative_chain_blocks_computed",
+             "speculative_chain_blocks_discarded", "txscript_collected_txs", "txscript_sync_collected_txs", "secp_device_dispatches")
+    ctx = {"spans": spans, "counters": {n: moved(n) for n in names}, "window": {"blocks": len(dag.blocks)}}
+    value = reader.read(spec["source"], ctx)
+    assert value is not None and value > 0
+    if spec["unit"] == "%":
+        assert value <= 100.0
+    assert reader.read(spec["source"], {"spans": [], "counters": {}, "window": {"blocks": len(dag.blocks)}}) is None
