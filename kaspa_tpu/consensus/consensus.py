@@ -906,6 +906,15 @@ class Consensus:
     # ------------------------------------------------------------------
 
     def _resolve_virtual(self) -> None:
+        try:
+            self._resolve_virtual_state()
+        finally:
+            # every chain this resolution qualified has asked what it will ask,
+            # and a resolution that raised asks nothing more: the script
+            # verdicts its blocks collected may be evicted again
+            self.transaction_validator.tx_memo.close_epoch()
+
+    def _resolve_virtual_state(self) -> None:
         # sink search: max blue-work candidate whose chain UTXO-verifies,
         # descending into parents of disqualified candidates
         # (virtual_processor/processor.rs sink_search_algorithm)
@@ -1390,7 +1399,7 @@ class Consensus:
         (it calls ``_move_utxo_position`` first; speculation does not)."""
         shared = checker is not None
         if not shared:
-            checker = self.transaction_validator.new_checker()
+            checker = self.transaction_validator.new_checker(block_path=True)
         accessor = None
         if self.params.toccata_active(pov_daa_score):
             from kaspa_tpu.consensus.smt_processor import ConsensusSeqCommitAccessor

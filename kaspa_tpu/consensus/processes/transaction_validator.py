@@ -19,7 +19,7 @@ from kaspa_tpu.consensus.mass import MassCalculator
 from kaspa_tpu.consensus.model import SUBNETWORK_ID_NATIVE, Transaction
 from kaspa_tpu.consensus.params import Params
 from kaspa_tpu.txscript.batch import BatchScriptChecker
-from kaspa_tpu.txscript.caches import SigCache
+from kaspa_tpu.txscript.caches import SigCache, VerdictMemo
 
 MAX_SOMPI = 29_000_000_000 * 100_000_000  # constants.rs MAX_SOMPI
 SEQUENCE_LOCK_TIME_MASK = 0x00000000FFFFFFFF
@@ -42,8 +42,9 @@ class TransactionValidator:
         self.coinbase_maturity = params.coinbase_maturity
         self.sig_cache = sig_cache if sig_cache is not None else SigCache()
         # the script-verdict memo over it (txscript/batch.py): the same bounded
-        # map at the same size, keyed by a transaction over its spent outputs
-        self.tx_memo = SigCache()
+        # map at the same size, keyed by a transaction over its spent outputs,
+        # whose running epoch `Consensus._resolve_virtual` closes
+        self.tx_memo = VerdictMemo()
         self.mass_calculator = MassCalculator.from_params(params)
         if vm_fallback is None:
             # nonstandard scripts run through the host VM with the shared
@@ -73,8 +74,12 @@ class TransactionValidator:
 
         self.vm_fallback = vm_fallback
 
-    def new_checker(self, traffic_class: str | None = None) -> BatchScriptChecker:
-        return BatchScriptChecker(self.sig_cache, self.vm_fallback, traffic_class=traffic_class, tx_memo=self.tx_memo)
+    def new_checker(self, traffic_class: str | None = None, block_path: bool = False) -> BatchScriptChecker:
+        """``block_path``: consensus collecting the transactions of blocks,
+        whose memo entries the running epoch holds (``BatchScriptChecker``)."""
+        return BatchScriptChecker(
+            self.sig_cache, self.vm_fallback, traffic_class=traffic_class, tx_memo=self.tx_memo, block_path=block_path
+        )
 
     # --- in isolation (tx_validation_in_isolation.rs) ---
 

@@ -204,7 +204,7 @@ class SpeculativeVerifier:
                 _INELIGIBLE.inc("position")
                 return None
 
-            checker = c.transaction_validator.new_checker()
+            checker = c.transaction_validator.new_checker(block_path=True)
             # graftlint: allow(blocking-under-lock) -- unreachable sync branch: checker is supplied, so _validate_transactions inside never takes its synchronous dispatch() path here
             ctx = c._calculate_utxo_state(
                 gd, header.daa_score, base=base, seed_multiset=seed, checker=checker, cause="stage"
@@ -306,7 +306,7 @@ class SpeculativeVerifier:
             # identical to what _verify_chain_block(chain[0]) does first;
             # doing it here freezes the base the whole segment chains on
             c._move_utxo_position(gd0.selected_parent)
-            checker = c.transaction_validator.new_checker()
+            checker = c.transaction_validator.new_checker(block_path=True)
             prev_block = gd0.selected_parent
             prev_view = None
             prev_seed = None

@@ -65,7 +65,7 @@ from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY, SIZE_BUCKETS
 from kaspa_tpu.resilience.faults import FAULTS, FaultInjected
 from kaspa_tpu.txscript import standard
-from kaspa_tpu.txscript.caches import SigCache
+from kaspa_tpu.txscript.caches import SigCache, VerdictMemo
 from kaspa_tpu.txscript.vm import MAX_SCRIPT_ELEMENT_SIZE
 
 # fast-path vs fallback mix: a fallback-heavy workload starves the device
@@ -292,9 +292,15 @@ class BatchScriptChecker:
     admission batches).  Class-qualified kinds get their own coalesce
     target/age and counters in ops/dispatch; results are bit-identical.
 
-    ``tx_memo``: the script-verdict memo, a second ``SigCache`` keyed by
+    ``tx_memo``: the script-verdict memo, a ``VerdictMemo`` keyed by
     ``_memo_key``; its value is the number of signature checks the verdict
     stood for.  A checker made without one remembers nothing past itself.
+
+    ``block_path``: a checker of the consensus block path
+    (``_validate_transactions`` and the speculation over blocks): its memo
+    inserts and hits join the memo's running epoch, which the end of a
+    virtual resolution closes.  Mempool admission, the ingest waves and RPC
+    make theirs without it, so what they insert is evicted at the bound.
     """
 
     def __init__(
@@ -303,21 +309,24 @@ class BatchScriptChecker:
         vm_fallback=None,
         fallback_workers: int | None = None,
         traffic_class: str | None = None,
-        tx_memo: SigCache | None = None,
+        tx_memo: VerdictMemo | None = None,
+        block_path: bool = False,
     ):
         self.sig_cache = sig_cache if sig_cache is not None else SigCache()
-        self.tx_memo = tx_memo if tx_memo is not None else SigCache()
+        self.tx_memo = tx_memo if tx_memo is not None else VerdictMemo()
         # contract: fn(tx, entries, input_index, reused, pov_daa_score) — the
         # daa score drives fork-activation gating inside the engine
         self.vm_fallback = vm_fallback
         self.fallback_workers = fallback_workers
         self.traffic_class = traffic_class
+        self.block_path = block_path
         self._jobs: list[_Job] = []
         self._fallbacks: list[_FallbackJob] = []
         self._multisigs: list[_MultisigInput] = []
         self._results: dict[int, Exception | None] = {}
         self._cache_lookups = self._cache_hits = 0  # since the last dispatch
         self._memo_lookups = self._memo_hits = 0  # since the last dispatch
+        self._memo_hit_keys: list[tuple] = []  # a block-path checker's hits since the last dispatch
         # (token, memo key, signature checks) of each transaction collected
         # wholly into the batch lanes: the memo's entries if all answer valid
         self._memo_candidates: list[tuple] = []
@@ -335,6 +344,8 @@ class BatchScriptChecker:
             # checks, every one was answered valid, and no lane read the
             # caller's context (``pov_daa_score``, the seq-commit accessor)
             self._memo_hits += 1
+            if self.block_path:
+                self._memo_hit_keys.append(key)
             self._cache_lookups += checks
             self._cache_hits += checks
             return
@@ -508,6 +519,9 @@ class BatchScriptChecker:
         jobs, self._jobs = self._jobs, []
         results, self._results = self._results, {}
         memo_candidates, self._memo_candidates = self._memo_candidates, []
+        if self._memo_hit_keys:
+            self.tx_memo.protect(self._memo_hit_keys)
+            self._memo_hit_keys = []
         if self._memo_lookups:
             _MEMO_LOOKUPS.inc(self._memo_lookups)
             _MEMO_HITS.inc(self._memo_hits)
@@ -549,17 +563,21 @@ class BatchScriptChecker:
                     f"{prefix}ecdsa", [(j.pubkey, j.msg, j.sig) for j in ecdsa]
                 )
         return DispatchHandle(
-            self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, self.tx_memo, memo_candidates
+            self.sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, self.tx_memo, memo_candidates,
+            self.block_path,
         )
 
 
 class DispatchHandle:
     """In-flight dispatch: owns the detached jobs/results of one round."""
 
-    def __init__(self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, tx_memo, memo_candidates):
+    def __init__(
+        self, sig_cache, fallbacks, pending, schnorr, ecdsa, tickets, results, multisigs, tx_memo, memo_candidates, block_path
+    ):
         self.sig_cache = sig_cache
         self.tx_memo = tx_memo
         self._memo_candidates = memo_candidates
+        self._block_path = block_path
         self._fallbacks = fallbacks
         self._multisigs = multisigs
         self._pending = pending
@@ -653,5 +671,5 @@ class DispatchHandle:
         rerun_tokens = {job.token for job in reruns}
         for token, key, checks in self._memo_candidates:
             if self._results[token] is None and token not in rerun_tokens:
-                self.tx_memo.insert(key, checks)
+                self.tx_memo.insert(key, checks, protect=self._block_path)
         return self._results

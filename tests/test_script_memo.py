@@ -5,12 +5,14 @@ remembered, with its signature scripts, over the amounts and scripts of the
 outputs it spent, and is not collected again.  These tests pin that a replay
 with the memo leaves the state of a replay without it, what the key holds and
 what it leaves out, which transactions are never remembered, that the checks
-which read the caller's context run on a hit, who owns the memo, and what its
-hits do to the cache counters.  CPU, XLA ladder at bucket 8; nothing here is a
-device number.
+which read the caller's context run on a hit, who owns the memo, what its
+hits do to the cache counters, and how its running epoch outlives the bound
+until a virtual resolution closes it.  CPU, XLA ladder at bucket 8; nothing
+here is a device number.
 """
 
 import dataclasses
+import random
 
 import pytest
 
@@ -22,12 +24,15 @@ from kaspa_tpu.consensus.processes.transaction_validator import (
     TransactionValidator,
     TxRuleError,
 )
+from kaspa_tpu.mempool import MiningManager
+from kaspa_tpu.mempool.mempool import MempoolError
 from kaspa_tpu.observability import trace
 from kaspa_tpu.ops import dispatch as coalesce
 from kaspa_tpu.pipeline.pipeline import ConsensusPipeline
-from kaspa_tpu.sim.simulator import SimConfig, simulate
+from kaspa_tpu.sim.simulator import Miner, SimConfig, simulate
 from kaspa_tpu.txscript import batch, standard
-from kaspa_tpu.txscript.caches import SigCache
+from kaspa_tpu.txscript.caches import SigCache, VerdictMemo
+from tests.test_mempool import _signed_spend, chain  # noqa: F401 - chain is that file's fixture
 from tests.test_txscript_multisig_batch import (  # noqa: F401 - _sync_device_lane is that file's autouse fixture
     KEYS,
     PARAMS,
@@ -43,9 +48,9 @@ from tests.test_txscript_multisig_batch import (  # noqa: F401 - _sync_device_la
 POV = 10
 
 
-def _verdicts(tv, txs, pov=POV, traffic_class=None):
+def _verdicts(tv, txs, pov=POV, traffic_class=None, block_path=False):
     """token -> None | message, through one checker of ``tv`` and one dispatch."""
-    checker = tv.new_checker(traffic_class)
+    checker = tv.new_checker(traffic_class, block_path=block_path)
     for token, (tx, entries) in enumerate(txs):
         checker.collect_tx(token, tx, entries, pov_daa_score=pov)
     return {t: None if e is None else str(e) for t, e in checker.dispatch().items()}
@@ -301,15 +306,20 @@ def test_a_fresh_consensus_over_the_same_transactions_starts_cold(wide_dag, in_o
     assert fields == {"version", "inputs", "outputs", "lock_time", "subnetwork_id", "gas", "payload", "storage_mass", "_id_cache"}
 
 
+SPENDS = (["schnorr"], ["ecdsa"], ["multisig"], ["schnorr", "ecdsa"])  # four transactions, four memo keys
+
+
 def test_the_memo_is_the_bounded_map_the_signature_cache_is():
     tv = TransactionValidator(PARAMS)
-    assert type(tv.tx_memo) is type(tv.sig_cache) is SigCache and tv.tx_memo.size == tv.sig_cache.size == 10_000
+    assert type(tv.sig_cache) is SigCache and type(tv.tx_memo) is VerdictMemo and isinstance(tv.tx_memo, SigCache)
+    assert tv.tx_memo.size == tv.sig_cache.size == 10_000
     checker = tv.new_checker()
     assert checker.tx_memo is tv.tx_memo and checker.sig_cache is tv.sig_cache
-    tv.tx_memo = SigCache(size=2, seed=1)
-    spends = [_mixed_tx(classes) for classes in (["schnorr"], ["ecdsa"], ["multisig"], ["schnorr", "ecdsa"])]
+    assert not checker.block_path and tv.new_checker(block_path=True).block_path
+    tv.tx_memo = VerdictMemo(size=2, seed=1)
+    spends = [_mixed_tx(classes) for classes in SPENDS]
     assert _verdicts(tv, spends) == dict.fromkeys(range(4))
-    assert _held(tv) == 2  # the cache's random eviction, at the cache's bound
+    assert _held(tv) == 2 and tv.tx_memo.close_epoch() == 0  # the cache's random eviction, at the cache's bound
 
 
 # --------------------------------------------------------------------------
@@ -434,3 +444,179 @@ def test_checkers_on_many_threads_share_the_memo():
     lookups, hits = _moved(before, "txscript_tx_memo_lookups"), _moved(before, "txscript_tx_memo_hits")
     assert lookups == 4 * threads * rounds and hits == tv.tx_memo.hits and lookups == tv.tx_memo.hits + tv.tx_memo.misses
     assert hits >= 2 * threads * (rounds - 1)  # from a thread's second round on, both are answered
+
+
+# --------------------------------------------------------------------------
+# (g) the running epoch: the block path's entries outlive the bound until a
+# virtual resolution ends
+# --------------------------------------------------------------------------
+
+
+def _keys(spends):
+    return [batch._memo_key(tx, entries) for tx, entries in spends]
+
+
+# a checker's (traffic class, block path): consensus over blocks, an ingest wave, a mempool admission or RPC check
+CHECKERS = {"block_path": (None, True), "ingest_wave": ("standalone_tx", False), "mempool_admission": (None, False)}
+
+
+@pytest.mark.parametrize("checker, held", [("block_path", 4), ("ingest_wave", 2), ("mempool_admission", 2)], ids=list(CHECKERS))
+def test_past_the_bound_only_the_block_path_is_held_until_the_epoch_closes(checker, held):
+    traffic_class, block_path = CHECKERS[checker]
+    tv = TransactionValidator(PARAMS)
+    tv.tx_memo = VerdictMemo(size=2, seed=1)
+    spends = [_mixed_tx(classes) for classes in SPENDS]
+    assert _verdicts(tv, spends, traffic_class=traffic_class, block_path=block_path) == dict.fromkeys(range(4))
+    assert _held(tv) == held
+    before = _counters()
+    assert tv.tx_memo.close_epoch() == held - 2
+    assert _held(tv) == 2 and _moved(before, "txscript_tx_memo_over_bound") == held - 2
+
+
+@pytest.mark.parametrize("checker, kept", [("block_path", True), ("ingest_wave", False), ("mempool_admission", False)], ids=list(CHECKERS))
+def test_a_hit_moves_an_entry_of_an_earlier_epoch_into_the_running_one(checker, kept):
+    traffic_class, block_path = CHECKERS[checker]
+    tv = TransactionValidator(PARAMS)
+    tv.tx_memo = VerdictMemo(size=2, seed=1)
+    spends = [_mixed_tx(classes) for classes in SPENDS]
+    assert _verdicts(tv, spends[:2], block_path=True) == {0: None, 1: None}
+    tv.tx_memo.close_epoch()  # the first two are an earlier epoch's now
+    before = _counters()
+    assert _verdicts(tv, spends[:1], traffic_class=traffic_class, block_path=block_path) == {0: None}
+    assert _moved(before, "txscript_tx_memo_hits") == 1
+    # two inserts: each evicts an earlier epoch's entry while one is left
+    assert _verdicts(tv, spends[2:], block_path=True) == {0: None, 1: None}
+    held = set(tv.tx_memo._map)
+    hit, other, *fresh = _keys(spends)
+    assert (hit in held) is kept and other not in held and set(fresh) <= held
+    assert _held(tv) == (3 if kept else 2)
+
+
+def test_mempool_admission_with_no_resolution_stays_at_the_bound(chain):
+    """RPC admission where no p2p node is wired and the orphans' re-validation
+    after a block both go through ``validate_and_insert_transaction``, which
+    is not the block path.  One output signed many ways is enough to try it:
+    each variant is another transaction, and its verdict is remembered before
+    the mempool turns the double spend away.  With no virtual resolution in
+    between, the memo holds no more than its bound."""
+    consensus, _ = chain
+    memo = consensus.transaction_validator.tx_memo = VerdictMemo(size=2, seed=1)
+    inserted, insert = [], memo.insert
+    memo.insert = lambda key, value, protect=False: (inserted.append(protect), insert(key, value, protect))
+    sim_rng = random.Random(17)  # the simulator's miners, in its order
+    miner = [Miner(i, sim_rng) for i in range(2)][0]
+    mining, rng = MiningManager(consensus), random.Random(40)
+    for fee in range(1_000, 1_008):
+        tx, _outpoint, _entry = _signed_spend(consensus, miner, rng, fee=fee)
+        try:
+            mining.validate_and_insert_transaction(tx)
+        except MempoolError:
+            pass  # a double spend the pool refused: remembered all the same
+        assert len(memo._map) <= 2
+    assert inserted == [False] * 8 and len(memo._map) == 2
+    assert memo.close_epoch() == 0
+
+
+def test_a_resolution_that_raises_still_closes_the_epoch(monkeypatch):
+    consensus = Consensus(PARAMS)
+    memo = consensus.transaction_validator.tx_memo = VerdictMemo(size=2, seed=1)
+
+    def resolve_and_fail():
+        for k in "abc":
+            memo.insert((k,), 1, protect=True)
+        raise RuntimeError("the device was lost mid-cycle")
+
+    monkeypatch.setattr(consensus, "_resolve_virtual_state", resolve_and_fail)
+    before = _counters()
+    with pytest.raises(RuntimeError, match="device was lost"):
+        consensus._resolve_virtual()
+    assert len(memo._map) == 2 and memo._n_old == 2 and _moved(before, "txscript_tx_memo_over_bound") == 1
+
+
+def test_the_close_trims_to_the_bound_earlier_epochs_and_waves_first():
+    memo = VerdictMemo(size=4, seed=3)
+    for k in ("a", "b"):
+        memo.insert((k,), 1, protect=True)
+    assert memo.close_epoch() == 0 and len(memo._map) == 2
+    for k in "cdefg":  # c and d fill the bound; e and f evict a and b; g goes past the bound
+        memo.insert((k,), 1, protect=True)
+    assert set(memo._map) == {(k,) for k in "cdefg"}
+    memo.insert(("wave",), 1)  # a traffic class's entry finds nothing to evict and waits at the front of the queue
+    before = _counters()
+    assert memo.close_epoch() == 2 and _moved(before, "txscript_tx_memo_over_bound") == 2
+    assert len(memo._map) == 4 and set(memo._map) < {(k,) for k in "cdefg"}
+    assert memo.close_epoch() == 0 and len(memo._map) == 4  # a close at the bound trims nothing
+
+
+@pytest.mark.parametrize("memo_type", [SigCache, VerdictMemo])
+def test_hits_and_misses_count_as_the_signature_cache_counts_them(memo_type):
+    memo = memo_type(size=2, seed=1)
+    assert memo.get(("a",)) is None
+    memo.insert(("a",), 3)
+    assert memo.get(("a",)) == 3 and memo.get(("b",)) is None
+    memo.insert(("b",), 1)
+    memo.insert(("c",), 2)  # past the bound: one of the three goes (an earlier epoch's for the memo: none is protected)
+    answered = [memo.get((k,)) for k in "abc"]
+    assert answered.count(None) == 1 and len(memo._map) == 2
+    assert (memo.hits, memo.misses) == (3, 3)
+
+
+def test_epochs_on_many_threads_keep_the_map_whole():
+    """Block-path and wave inserts, hits and closes from many threads at a
+    small bound: every swap and trim is under the one lock, so the key list,
+    the positions and the map stay one map, and the last close trims to the bound."""
+    import sys
+    import threading
+
+    memo = VerdictMemo(size=16, seed=5)
+    threads, rounds, errors = 12, 400, []
+
+    def work(t):
+        try:
+            for i in range(rounds):
+                key = ((t * 7 + i) % 97,)
+                memo.insert(key, i, protect=(t + i) % 3 != 0)
+                if memo.get(((i * 5) % 97,)) is not None:
+                    memo.protect([((i * 5) % 97,)])
+                if t == 0 and i % 50 == 49:
+                    memo.close_epoch()
+        except Exception as e:  # noqa: BLE001 - reported by the assertion below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [threading.Thread(target=work, args=(t,)) for t in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(w.is_alive() for w in workers)
+    assert len(memo._keys) == len(memo._map) == len(memo._pos) == len(set(memo._keys))
+    assert all(memo._pos[key] == i for i, key in enumerate(memo._keys)) and 0 <= memo._n_old <= len(memo._keys)
+    memo.close_epoch()
+    assert len(memo._map) == 16 and memo._n_old == 16
+
+
+@pytest.mark.parametrize("over_bound, cycles, reads", [(26_192, 3, 26_192 / 3), (0, 3, None), (None, None, None)])
+def test_the_over_bound_metric_reads_the_counter_and_lists_the_wide_cell_alone(over_bound, cycles, reads):
+    """``script_memo_over_bound_per_cycle``: its file agrees with its entry,
+    the reader divides the counter by the cycles, and a 0 (every cell but the
+    wide one, where no cycle asks more keys than the bound) or a program
+    without the counter reads nothing, which is why only the wide cell lists it."""
+    import importlib
+    import os
+
+    from benchmarks import harness
+
+    bench = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    entry = next(m for m in bench["per_layer"] if m["name"] == "script_memo_over_bound_per_cycle")
+    spec = harness.load_json(os.path.join(harness.HERE, "metrics", "script_memo_over_bound_per_cycle.json"))
+    assert spec["bench_source"] == entry["source"] == "program_counter" and spec["note"]
+    assert all(spec[k] == entry[k] for k in ("layer", "unit", "better", "moves"))
+    assert entry["workloads"] == ["simpa-8bps.catchup-200tpb"]
+    reader = importlib.import_module(f"benchmarks.readers.{spec['source']['reader']}")
+    counters = {} if over_bound is None else {"txscript_tx_memo_over_bound": over_bound, "pipeline_virtual_cycles": cycles}
+    assert reader.read(spec["source"], {"spans": [], "counters": counters, "window": {"blocks": 164}}) == reads

@@ -14,6 +14,7 @@ import pytest
 from kaspa_tpu.observability import flight, trace
 from kaspa_tpu.observability.core import REGISTRY
 from kaspa_tpu.ops import dispatch as coalesce
+from kaspa_tpu.txscript.caches import VerdictMemo
 
 FLUSH_REASONS = {"nudge", "age", "size", "drain"}
 
@@ -500,15 +501,18 @@ def test_tree_product_lowers_under_its_named_scope():
 # --- PR 37: a multi-block virtual cycle, measured from inside -----------------------
 
 
-def _replay_in_one_cycle(dag, traced: bool):
+def _replay_in_one_cycle(dag, traced: bool, memo=None):
     """Every block of ``dag`` queued while the commit lock is held, so that one
-    cycle absorbs them all; with capture on, or with tracing disabled.  Beside
+    cycle absorbs them all; with capture on, or with tracing disabled, and
+    over ``memo`` as the script-verdict memo where one is given.  Beside
     the consensus and the statuses: the spans, the counters' movement, and for
     each ``precompute_chain`` call the blocks it computed and those it published."""
     from kaspa_tpu.consensus.consensus import Consensus
     from kaspa_tpu.pipeline.pipeline import ConsensusPipeline
 
     consensus = Consensus(dag.params)
+    if memo is not None:
+        consensus.transaction_validator.tx_memo = memo
     pipe = ConsensusPipeline(consensus, workers=2)
     verifier, segments = consensus.speculative, []
     replay, publish, precompute = consensus._calculate_utxo_state, verifier._publish, verifier.precompute_chain
@@ -549,10 +553,9 @@ def _replay_in_one_cycle(dag, traced: bool):
     return consensus, statuses, spans, lambda name: _moved(after, before, name), segments
 
 
-@pytest.fixture(scope="module")
-def cycle():
-    """A toy DAG of simpa's shape (about four blocks wide, two late siblings
-    with a failed spend, 64 blocks) resolved in one virtual cycle."""
+def _simpa_toy_dag():
+    """A toy DAG of simpa's shape: about four blocks wide, two late siblings
+    with a failed spend, 64 blocks."""
     from benchmarks import harness
 
     network = {"bps": 4, "delay_s": 1.0, "miners": 1, "own_blocks_delayed": True, "ghostdag_k": 55}
@@ -562,6 +565,13 @@ def cycle():
     coalesce.configure(0)  # the build is the in-order run
     dag = harness.build_dag(workload, {"name": "toy", "network": network, "pipeline": {"coalesce": 64, "stage_workers": 2}}, 36, lambda _m: None)
     assert len(dag.blocks) <= 64 and len(dag.spoiled) == 2
+    return dag
+
+
+@pytest.fixture(scope="module")
+def cycle():
+    """The toy DAG of simpa's shape resolved in one virtual cycle."""
+    dag = _simpa_toy_dag()
     return dag, _replay_in_one_cycle(dag, traced=True)
 
 
@@ -733,3 +743,46 @@ def test_each_new_metric_reads_the_captured_cycle_and_nothing_from_a_bare_progra
     if spec["unit"] == "%":
         assert value <= 100.0
     assert reader.read(spec["source"], {"spans": [], "counters": {}, "window": {"blocks": len(dag.blocks)}}) is None
+
+
+# --- the script-verdict memo's running epoch over the same cycle ---------------------
+
+
+class _RandomEvictionMemo(VerdictMemo):
+    """The memo without epochs: every insert evicts at random at the bound, as the signature cache does."""
+
+    def insert(self, key, value, protect=False):
+        super().insert(key, value)
+
+    def protect(self, keys):
+        pass
+
+
+def test_a_cycle_past_the_memo_bound_leaves_the_state_of_a_memo_that_never_evicts(cycle):
+    """The toy cycle asks ≈ 100 memo keys ≈ 300 times; at a bound of 32 the
+    running epoch holds them all until the cycle ends, and random eviction
+    loses most of them between two asks."""
+    dag = cycle[0]
+    runs = {}
+    for name, memo in (
+        ("never_evicts", VerdictMemo(size=1 << 30)),
+        ("epochs", VerdictMemo(size=32, seed=40)),
+        ("random_eviction", _RandomEvictionMemo(size=32, seed=40)),
+    ):
+        consensus, statuses, _, moved, _ = _replay_in_one_cycle(dag, traced=False, memo=memo)
+        assert moved("pipeline_virtual_cycles") == 1
+        sink = consensus.sink()
+        consensus._move_utxo_position(sink)
+        state = {
+            "statuses": statuses,
+            "final_statuses": [consensus.storage.statuses.get(b.hash) for b in dag.blocks],
+            "sink": sink,
+            "utxo_set": {(op.transaction_id, op.index): e for op, e in consensus.utxo_set.items()},
+            "commitment": consensus.multisets[sink].finalize(),
+        }
+        runs[name] = state, memo.hits / (memo.hits + memo.misses), moved("txscript_tx_memo_over_bound"), len(memo._map)
+    never, epochs, random_eviction = runs["never_evicts"], runs["epochs"], runs["random_eviction"]
+    assert epochs[0] == never[0] and random_eviction[0] == never[0] and never[0]["sink"] == dag.sinks[-1]
+    assert epochs[1] > random_eviction[1] + 0.1
+    assert epochs[2] > 0 and epochs[3] == 32  # past the bound while the cycle ran, trimmed to it at its end
+    assert never[2] == random_eviction[2] == 0 and random_eviction[3] == 32

@@ -31,7 +31,7 @@ from kaspa_tpu.observability import trace
 from kaspa_tpu.observability.core import REGISTRY
 from kaspa_tpu.ops import dispatch as coalesce
 from kaspa_tpu.txscript import standard
-from kaspa_tpu.txscript.caches import SigCache
+from kaspa_tpu.txscript.caches import VerdictMemo
 from kaspa_tpu.txscript.script_builder import ScriptBuilder
 
 PARAMS = simnet_params()
@@ -319,7 +319,7 @@ def test_cache_answers_are_not_sent_again():
     assert _moved(before, "txscript_batch_jobs") == 0 and _moved(before, "secp_device_jobs") == 0
     assert _moved(before, "txscript_tx_memo_hits") == 1 and _moved(before, "txscript_multisig_inputs") == 0
     assert _moved(before, "txscript_sig_cache_block_lookups") == _moved(before, "txscript_sig_cache_block_hits") == 4
-    tv.tx_memo = SigCache()  # the memo evicted it
+    tv.tx_memo = VerdictMemo()  # the memo evicted it
     before = _counters()
     assert _batch([spend], tv) == {0: None}
     assert _moved(before, "txscript_batch_jobs") == 0 and _moved(before, "secp_device_jobs") == 0
